@@ -19,7 +19,7 @@ from repro.core.distributed_sce import (  # noqa: E402
     sce_loss_sharded_ref,
 )
 from repro.core.sce import SCEConfig  # noqa: E402
-from repro.dist import make_mesh, set_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():
@@ -36,7 +36,7 @@ def main():
           f"b_y={cfg.bucket_size_y} (per data shard)")
 
     for mode in ("exact", "union"):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             loss = jax.jit(
                 lambda x, y: sce_loss_sharded(
                     x, y, t, key=key, cfg=cfg, mesh=mesh, mode=mode

@@ -55,7 +55,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.core.sce import NEG_INF, SCEConfig, apply_softcap, make_bucket_centers
-from repro.dist import shard_map
 from repro.dist.collectives import distributed_topk_from_local
 from repro.dist.sharding import batch_spec, catalog_spec, data_axes, replicated_spec
 
@@ -282,7 +281,7 @@ def sce_loss_sharded(
         _sce_inner, cfg=cfg, dp=dp, tp=tp,
         bucket_chunks=bucket_chunks or m, exact=(mode == "exact"),
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(
@@ -295,6 +294,90 @@ def sce_loss_sharded(
         out_specs=replicated_spec(),
     )
     return fn(key, x, y, targets, valid_mask)
+
+
+def sce_ref_shards(
+    x: jax.Array,
+    y: jax.Array,
+    targets: jax.Array,
+    *,
+    key: jax.Array,
+    cfg: SCEConfig,
+    dp_size: int,
+    valid_mask: Optional[jax.Array] = None,
+    mode: str = "exact",
+    tp_size: int = 1,
+):
+    """The terms of :func:`sce_loss_sharded_ref`, one dict per data shard.
+
+    Keys: ``xp`` ``(n_b, N/dp)`` position selection scores (``NEG_INF``
+    where masked) and ``b_x``; ``y_scores``, a list of ``(scores, k,
+    offset)`` — the ``(n_b, C_j)`` candidate scores of each catalog block
+    that is ranked on its own (the whole catalog in exact mode, each
+    model shard's slice in union mode), how many it selects, and its
+    first global id; ``idx_x`` ``(n_b, b_x)`` local position ids;
+    ``idx_y`` ``(n_b, k)`` global candidate ids; ``losses`` ``(n_b,
+    b_x)`` per-(bucket, position) CE; ``targets`` and ``valid`` of the
+    shard. Scores are computed at the ambient matmul precision."""
+    if cfg.n_buckets % tp_size != 0:  # same rounding as the sharded path
+        cfg = dataclasses.replace(
+            cfg, n_buckets=round_up(cfg.n_buckets, tp_size)
+        )
+    n = x.shape[0]
+    assert n % dp_size == 0
+    n_l = n // dp_size
+    c = y.shape[0]
+    if valid_mask is None:
+        valid_mask = jnp.ones((n,), bool)
+
+    shards = []
+    for i in range(dp_size):
+        x_i = x[i * n_l : (i + 1) * n_l]
+        t_i = targets[i * n_l : (i + 1) * n_l]
+        vm_i = valid_mask[i * n_l : (i + 1) * n_l]
+        key_i = jax.random.fold_in(key, i)
+        b = make_bucket_centers(
+            key_i, x_i, cfg.n_buckets, use_mix=cfg.use_mix, valid_mask=vm_i
+        )
+        xs = jax.lax.stop_gradient(x_i)
+        ys = jax.lax.stop_gradient(y)
+        xp = jnp.where(vm_i[None, :], b @ xs.T, NEG_INF)
+        b_x = min(cfg.bucket_size_x, n_l)
+        _, idx_x = jax.lax.top_k(xp, b_x)
+
+        if mode == "exact":
+            # same clip as the sharded path: at most the full catalog
+            y_scores = [(b @ ys.T, min(cfg.bucket_size_y, c), 0)]
+        else:  # union of per-shard top-(b_y/m) over catalog slices
+            c_l = c // tp_size
+            k_local = max(1, min(cfg.bucket_size_y // tp_size, c_l))
+            y_scores = [
+                (b @ ys[j * c_l : (j + 1) * c_l].T, k_local, j * c_l)
+                for j in range(tp_size)
+            ]
+        idx_y = jnp.concatenate(
+            [jax.lax.top_k(s, k)[1] + off for s, k, off in y_scores], axis=-1
+        )
+
+        x_b = jnp.take(x_i, idx_x, axis=0)
+        y_b = jnp.take(y, idx_y, axis=0)
+        tgt_b = jnp.take(t_i, idx_x, axis=0)
+        pos_logit = apply_softcap(
+            jnp.einsum("nxd,nxd->nx", x_b, jnp.take(y, tgt_b, axis=0)),
+            cfg.logit_softcap,
+        )
+        neg = apply_softcap(
+            jnp.einsum("nxd,nyd->nxy", x_b, y_b), cfg.logit_softcap
+        )
+        collide = idx_y[:, None, :] == tgt_b[:, :, None]
+        neg = jnp.where(collide, NEG_INF, neg)
+        all_logits = jnp.concatenate([pos_logit[..., None], neg], axis=-1)
+        losses = jax.nn.logsumexp(all_logits, axis=-1) - pos_logit
+        shards.append(dict(
+            xp=xp, b_x=b_x, y_scores=y_scores, idx_x=idx_x, idx_y=idx_y,
+            losses=losses, targets=t_i, valid=vm_i,
+        ))
+    return shards
 
 
 def sce_loss_sharded_ref(
@@ -316,70 +399,22 @@ def sce_loss_sharded_ref(
     ``mode="union"``: per-model-shard top-(b_y/m) over each catalog slice,
     concatenated — bit-matches the union mode's candidate set.
     """
-    if cfg.n_buckets % tp_size != 0:  # same rounding as the sharded path
-        cfg = dataclasses.replace(
-            cfg, n_buckets=round_up(cfg.n_buckets, tp_size)
-        )
-    n = x.shape[0]
-    assert n % dp_size == 0
-    n_l = n // dp_size
-    c = y.shape[0]
-    if valid_mask is None:
-        valid_mask = jnp.ones((n,), bool)
-
     num = jnp.zeros((), jnp.float32)
     den = jnp.zeros((), jnp.float32)
-    for i in range(dp_size):
-        x_i = x[i * n_l : (i + 1) * n_l]
-        t_i = targets[i * n_l : (i + 1) * n_l]
-        vm_i = valid_mask[i * n_l : (i + 1) * n_l]
-        key_i = jax.random.fold_in(key, i)
-        b = make_bucket_centers(
-            key_i, x_i, cfg.n_buckets, use_mix=cfg.use_mix, valid_mask=vm_i
-        )
-        xs = jax.lax.stop_gradient(x_i)
-        ys = jax.lax.stop_gradient(y)
-        xp = jnp.where(vm_i[None, :], b @ xs.T, NEG_INF)
-        b_x = min(cfg.bucket_size_x, n_l)
-        _, idx_x = jax.lax.top_k(xp, b_x)
-
-        if mode == "exact":
-            # same clip as the sharded path: at most the full catalog
-            _, idx_y = jax.lax.top_k(b @ ys.T, min(cfg.bucket_size_y, c))
-        else:  # union of per-shard top-(b_y/m) over catalog slices
-            c_l = c // tp_size
-            k_local = max(1, min(cfg.bucket_size_y // tp_size, c_l))
-            parts = []
-            for j in range(tp_size):
-                y_j = ys[j * c_l : (j + 1) * c_l]
-                _, idx_j = jax.lax.top_k(b @ y_j.T, k_local)
-                parts.append(idx_j + j * c_l)
-            idx_y = jnp.concatenate(parts, axis=-1)
-
-        x_b = jnp.take(x_i, idx_x, axis=0)
-        y_b = jnp.take(y, idx_y, axis=0)
-        tgt_b = jnp.take(t_i, idx_x, axis=0)
-        pos_logit = apply_softcap(
-            jnp.einsum("nxd,nxd->nx", x_b, jnp.take(y, tgt_b, axis=0)),
-            cfg.logit_softcap,
-        )
-        neg = apply_softcap(
-            jnp.einsum("nxd,nyd->nxy", x_b, y_b), cfg.logit_softcap
-        )
-        collide = idx_y[:, None, :] == tgt_b[:, :, None]
-        neg = jnp.where(collide, NEG_INF, neg)
-        all_logits = jnp.concatenate([pos_logit[..., None], neg], axis=-1)
-        losses = jax.nn.logsumexp(all_logits, axis=-1) - pos_logit
-
+    for s in sce_ref_shards(
+        x, y, targets, key=key, cfg=cfg, dp_size=dp_size,
+        valid_mask=valid_mask, mode=mode, tp_size=tp_size,
+    ):
+        n_l, idx_x = s["xp"].shape[1], s["idx_x"]
         per_pos = jax.ops.segment_max(
-            losses.reshape(-1), idx_x.reshape(-1), num_segments=n_l
+            s["losses"].reshape(-1), idx_x.reshape(-1), num_segments=n_l
         )
         hit = jax.ops.segment_max(
             jnp.ones((idx_x.size,), jnp.float32),
             idx_x.reshape(-1),
             num_segments=n_l,
         )
-        covered = (hit > 0) & vm_i
+        covered = (hit > 0) & s["valid"]
         num = num + jnp.sum(jnp.where(covered, per_pos, 0.0))
         den = den + jnp.sum(covered.astype(jnp.float32))
     return num / jnp.maximum(den, 1.0)

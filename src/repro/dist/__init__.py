@@ -28,11 +28,11 @@ Modules
       (exact-mode candidate all_to_all, two-stage serve top-k), with
       single-device fallbacks and trace-time payload-bytes accounting
       consumed by ``launch/dryrun.py``.
-  ``compat``      — bridges modern distribution spellings
-      (``jax.shard_map`` / ``jax.set_mesh`` / typed ``make_mesh``) onto
-      older installed jaxlibs so the stack is written once.
+
+Meshes, ``shard_map`` and the ambient mesh use JAX's own spellings
+(``jax.make_mesh`` with ``AxisType.Auto`` axes, ``jax.shard_map``,
+``jax.set_mesh``).
 """
-from repro.dist.compat import AxisType, make_mesh, set_mesh, shard_map
 from repro.dist.collectives import (
     all_to_all_bucket_shuffle,
     distributed_topk,
@@ -61,7 +61,6 @@ from repro.dist.sharding import (
 )
 
 __all__ = [
-    "AxisType",
     "MODEL_AXIS",
     "all_to_all_bucket_shuffle",
     "batch_spec",
@@ -71,7 +70,6 @@ __all__ = [
     "distributed_topk_from_local",
     "lm_logits_spec",
     "lm_tokens_spec",
-    "make_mesh",
     "named_sharding_tree",
     "opt_state_specs",
     "payload_log",
@@ -83,8 +81,6 @@ __all__ = [
     "reset_payload_log",
     "residual_act_spec",
     "seqrec_param_specs",
-    "set_mesh",
-    "shard_map",
     "transformer_cache_specs",
     "transformer_param_specs",
 ]

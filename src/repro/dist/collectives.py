@@ -42,6 +42,10 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+# The all-gather whose result is typed as replicated over the axis, so
+# ``jax.shard_map``'s replication check accepts it with no extra
+# collective. JAX exports it only from its private module.
+from jax._src.lax.parallel import all_gather_invariant
 
 _PAYLOAD_LOG: List[Dict[str, Any]] = []
 
@@ -185,8 +189,10 @@ def distributed_topk_from_local(
 
     _record("all-gather", axis_name, (m,) + vals_l.shape, vals_l.dtype, m)
     _record("all-gather", axis_name, (m,) + gids_l.shape, gids_l.dtype, m)
-    vals_g = jax.lax.all_gather(vals_l, axis_name, axis=0)  # (m, ..., k_l)
-    gids_g = jax.lax.all_gather(gids_l, axis_name, axis=0)
+    # Invariant gathers: every shard merges the same union, so the
+    # result is replicated over ``axis_name`` and typed so.
+    vals_g = all_gather_invariant(vals_l, axis_name, axis=0)  # (m, ..., k_l)
+    gids_g = all_gather_invariant(gids_l, axis_name, axis=0)
 
     union_shape = vals_l.shape[:-1] + (m * k_local,)
     vals_u = jnp.moveaxis(vals_g, 0, -2).reshape(union_shape)

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist import set_mesh, shard_map
 from repro.dist.collectives import (
     distributed_lse_from_local,
     distributed_topk_from_local,
@@ -243,7 +242,7 @@ def _sharded_eval_fn(
         return vals, gids, gt, eq, tgt
 
     n_row_outs = 4 if with_lse else 3  # gt, eq, tgt (+ lse)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(
@@ -286,7 +285,7 @@ def _rank_topk_sharded(
     fn = _sharded_eval_fn(
         mesh, k, block_c, c_lo, c_hi, with_lse, logit_softcap
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         outs = fn(states, catalog, jnp.asarray(targets, jnp.int32))
     if pad:
         outs = tuple(o[:b] for o in outs)

@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import dot, pad_to, sds
 from repro.kernels.topk_merge import ID_PAD as _ID_PAD
 from repro.kernels.topk_merge import merge_topk_tile
 
@@ -80,40 +81,36 @@ def _softcap(logits, cap):
 
 
 def _tgt_gather_kernel(
-    tid_ref,  # (block_b,) i32 — local target row, -1 if not owned
+    tid_ref,  # (block_b, 1) i32 — local target row, -1 if not owned
     x_ref,  # (block_b, d)
     yg_ref,  # (block_c, d) — row r holds row r's target embedding
-    out_ref,  # (block_b,) f32 out
+    out_ref,  # (block_b, 1) f32 out
     *,
     block_b: int,
 ):
     # The SAME dot the sweep kernel runs — same (block_b, d, block_c)
     # shape ⇒ same per-element reduction ⇒ bitwise-identical scores.
-    logits = jnp.dot(
-        x_ref[...], yg_ref[...].T, preferred_element_type=jnp.float32
-    )
+    logits = dot(x_ref[...], yg_ref[...].T)
     row = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     hit = row == col  # row r's target sits in gather-tile row r
-    owned = (tid_ref[...] >= 0)[:, None]
+    owned = tid_ref[...] >= 0
     out_ref[...] = jnp.sum(
-        jnp.where(jnp.logical_and(hit, owned), logits, 0.0), axis=-1
+        jnp.where(jnp.logical_and(hit, owned), logits, 0.0),
+        axis=-1, keepdims=True,
     )
 
 
 def _fused_kernel(
-    tgt_ref,  # (block_b,) f32 target scores (the comparison threshold)
-    tid_ref,  # (block_b,) i32 global target ids (self-column rule)
+    tgt_ref,  # (block_b, 1) f32 target scores (the comparison threshold)
+    tid_ref,  # (block_b, 1) i32 local target ids (self-column rule)
+    valid_ref,  # (1, block_c) i32 — 1 inside the id window, 0 elsewhere
     x_ref,  # (block_b, d)
     y_ref,  # (block_c, d)
     *refs,  # outputs then scratch — see `with_lse` unpacking below
     k: int,
     n_c_tiles: int,
     block_c: int,
-    c_actual: int,
-    c_lo: int,
-    c_hi: int,
-    id_offset: int,
     logit_softcap,
     with_lse: bool,
 ):
@@ -136,37 +133,31 @@ def _fused_kernel(
             s_scr[...] = jnp.zeros_like(s_scr)
 
     # THE one matmul per tile — every accumulator below reads it.
-    logits = jnp.dot(
-        x_ref[...], y_ref[...].T, preferred_element_type=jnp.float32
-    )
-    idx = j * block_c + jax.lax.broadcasted_iota(
+    logits = dot(x_ref[...], y_ref[...].T)
+    col = j * block_c + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1
     )
-    col = id_offset + idx
-    # Mask padded-tail columns (idx ≥ C — their global ids may alias the
-    # next catalog shard's range) and ids outside [c_lo, c_hi).
-    valid = jnp.logical_and(
-        idx < c_actual, jnp.logical_and(col >= c_lo, col < c_hi)
-    )
+    # Columns outside the id window and the padded tail are masked.
+    valid = valid_ref[...] > 0
     s = jnp.where(valid, logits, NEG_INF)
 
     # Rank counts vs the (bitwise-exact) threshold. The target's own
     # column is excluded from gt and force-counted into eq structurally
     # — a no-op vs plain (>, ==) when the threshold is exact, but it
     # pins eq ≥ 1 independent of any backend's gemm determinism.
-    tgt = tgt_ref[...][:, None]  # (block_b, 1)
-    self_col = col == tid_ref[...][:, None]
+    tgt = tgt_ref[...]  # (block_b, 1)
+    self_col = col == tid_ref[...]
     gt_scr[...] += jnp.sum(
         jnp.logical_and(s > tgt, jnp.logical_not(self_col)).astype(
             jnp.int32
         ),
-        axis=-1,
+        axis=-1, keepdims=True,
     )
     eq_scr[...] += jnp.sum(
         jnp.logical_or(
             s == tgt, jnp.logical_and(self_col, valid)
         ).astype(jnp.int32),
-        axis=-1,
+        axis=-1, keepdims=True,
     )
 
     # Shared first-occurrence-argmax merge — raw logits, dense tie rule.
@@ -180,9 +171,12 @@ def _fused_kernel(
         # columns contribute exactly 0 via the explicit where — never
         # relying on exp(NEG_INF − NEG_INF) when a whole tile is masked.
         lv = jnp.where(valid, _softcap(logits, logit_softcap), NEG_INF)
-        m_new = jnp.maximum(m_scr[...], jnp.max(lv, axis=-1))
+        m_new = jnp.maximum(
+            m_scr[...], jnp.max(lv, axis=-1, keepdims=True)
+        )
         s_scr[...] = s_scr[...] * jnp.exp(m_scr[...] - m_new) + jnp.sum(
-            jnp.where(valid, jnp.exp(lv - m_new[:, None]), 0.0), axis=-1
+            jnp.where(valid, jnp.exp(lv - m_new), 0.0),
+            axis=-1, keepdims=True,
         )
         m_scr[...] = m_new
 
@@ -195,15 +189,6 @@ def _fused_kernel(
         if with_lse:
             m_ref[...] = m_scr[...]
             s_ref[...] = s_scr[...]
-
-
-def _pad_to(arr, axis, multiple, value=0):
-    pad = (-arr.shape[axis]) % multiple
-    if pad == 0:
-        return arr
-    widths = [(0, 0)] * arr.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(arr, widths, constant_values=value)
 
 
 def eval_tgt_gather(
@@ -252,31 +237,34 @@ def eval_tgt_gather(
         owned[:, None], jnp.take(y, jnp.clip(local, 0, c - 1), axis=0), 0
     )  # (B, d) — unowned rows zeroed (x · 0 ≡ 0 exactly)
 
-    xp = _pad_to(x, 0, block_b)
-    tidp = _pad_to(
-        jnp.where(owned, local, -1).astype(jnp.int32), 0, block_b,
+    xp = pad_to(x, 0, block_b)
+    # Per-row vectors travel as (B, 1) columns: their (block_b, 1) blocks
+    # meet the TPU tiling rule and broadcast against the logit tile.
+    tidp = pad_to(
+        jnp.where(owned, local, -1).astype(jnp.int32)[:, None], 0, block_b,
         value=-1,
     )
     n_p = xp.shape[0]
     n_b = n_p // block_b
     # (n_b, block_b, d) → column-pad each row block to a full
     # (block_c, d) gather tile.
-    rows_p = _pad_to(rows, 0, block_b).reshape(n_b, block_b, d)
-    rows_p = _pad_to(rows_p, 1, block_c).reshape(n_b * block_c, d)
+    rows_p = pad_to(rows, 0, block_b).reshape(n_b, block_b, d)
+    rows_p = pad_to(rows_p, 1, block_c).reshape(n_b * block_c, d)
 
     out = pl.pallas_call(
         functools.partial(_tgt_gather_kernel, block_b=block_b),
         grid=(n_b,),
         in_specs=[
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_b, d), lambda i: (i, 0)),
             pl.BlockSpec((block_c, d), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_p,), jnp.float32),
+        out_specs=pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
+        out_shape=sds((n_p, 1), jnp.float32, tidp, xp, rows_p),
         interpret=interpret,
+        name="eval_tgt_gather",
     )(tidp, xp, rows_p)
-    return out[:n]
+    return out[:n, 0]
 
 
 def eval_fused(
@@ -354,10 +342,19 @@ def eval_fused(
             id_offset=id_offset, interpret=interpret,
         )
 
-    xp = _pad_to(x, 0, block_b)
-    yp = _pad_to(y, 0, block_c)
-    tp = _pad_to(tgt_scores.astype(jnp.float32), 0, block_b)
-    tidp = _pad_to(targets.astype(jnp.int32), 0, block_b, value=-1)
+    # The kernel works in local ids: the window becomes a (1, C) validity
+    # row (0 on the padded tail), targets shift by the offset, and real
+    # result ids shift back afterwards. The offset may be traced.
+    gids = id_offset + jnp.arange(c, dtype=jnp.int32)
+    valid = jnp.logical_and(gids >= c_lo, gids < c_hi).astype(jnp.int32)
+    xp = pad_to(x, 0, block_b)
+    yp = pad_to(y, 0, block_c)
+    vp = pad_to(valid[None, :], 1, block_c)
+    tp = pad_to(tgt_scores.astype(jnp.float32)[:, None], 0, block_b)
+    tidp = pad_to(
+        (targets.astype(jnp.int32) - id_offset)[:, None], 0, block_b,
+        value=-1,
+    )
     n_p, c_p = xp.shape[0], yp.shape[0]
     n_b, n_c = n_p // block_b, c_p // block_c
 
@@ -366,51 +363,32 @@ def eval_fused(
         k=k,
         n_c_tiles=n_c,
         block_c=block_c,
-        c_actual=c,
-        c_lo=c_lo,
-        c_hi=c_hi,
-        id_offset=id_offset,
         logit_softcap=logit_softcap,
         with_lse=with_lse,
     )
+    operands = (tp, tidp, vp, xp, yp)
+    col_spec = pl.BlockSpec((block_b, 1), lambda i, j: (i, 0))
+    row_dtypes = [jnp.int32, jnp.int32] + [jnp.float32] * (2 * with_lse)
     out_specs = [
         pl.BlockSpec((block_b, k), lambda i, j: (i, 0)),
         pl.BlockSpec((block_b, k), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_b,), lambda i, j: (i,)),
-        pl.BlockSpec((block_b,), lambda i, j: (i,)),
-    ]
+    ] + [col_spec] * len(row_dtypes)
     out_shape = [
-        jax.ShapeDtypeStruct((n_p, k), jnp.float32),
-        jax.ShapeDtypeStruct((n_p, k), jnp.int32),
-        jax.ShapeDtypeStruct((n_p,), jnp.int32),
-        jax.ShapeDtypeStruct((n_p,), jnp.int32),
-    ]
+        sds((n_p, k), jnp.float32, *operands),
+        sds((n_p, k), jnp.int32, *operands),
+    ] + [sds((n_p, 1), dt, *operands) for dt in row_dtypes]
     scratch = [
         pltpu.VMEM((block_b, k), jnp.float32),
         pltpu.VMEM((block_b, k), jnp.int32),
-        pltpu.VMEM((block_b,), jnp.int32),
-        pltpu.VMEM((block_b,), jnp.int32),
-    ]
-    if with_lse:
-        out_specs += [
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-        ]
-        out_shape += [
-            jax.ShapeDtypeStruct((n_p,), jnp.float32),
-            jax.ShapeDtypeStruct((n_p,), jnp.float32),
-        ]
-        scratch += [
-            pltpu.VMEM((block_b,), jnp.float32),
-            pltpu.VMEM((block_b,), jnp.float32),
-        ]
+    ] + [pltpu.VMEM((block_b, 1), dt) for dt in row_dtypes]
 
     outs = pl.pallas_call(
         kernel,
         grid=(n_b, n_c),
         in_specs=[
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
+            col_spec,
+            col_spec,
+            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
             pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
         ],
@@ -418,8 +396,11 @@ def eval_fused(
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-    )(tp, tidp, xp, yp)
-    vals, ids, gt, eq = (o[:n] for o in outs[:4])
-    m = outs[4][:n] if with_lse else None
-    s = outs[5][:n] if with_lse else None
+        name="eval_fused",
+    )(*operands)
+    vals, ids = outs[0][:n], outs[1][:n]
+    ids = jnp.where(ids == _ID_PAD, ids, ids + id_offset)
+    gt, eq = outs[2][:n, 0], outs[3][:n, 0]
+    m = outs[4][:n, 0] if with_lse else None
+    s = outs[5][:n, 0] if with_lse else None
     return vals, ids, gt, eq, tgt_scores, m, s

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import dot, pad_to, sds
 from repro.kernels.topk_merge import ID_PAD as _ID_PAD
 from repro.kernels.topk_merge import merge_topk_tile
 
@@ -84,9 +85,7 @@ def _eval_kernel(
         gt_scr[...] = jnp.zeros_like(gt_scr)
         eq_scr[...] = jnp.zeros_like(eq_scr)
 
-    logits = jnp.dot(
-        x_ref[...], y_ref[...].T, preferred_element_type=jnp.float32
-    )
+    logits = dot(x_ref[...], y_ref[...].T)
     idx = j * block_c + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1
     )
@@ -135,9 +134,7 @@ def _tgt_kernel(
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    logits = jnp.dot(
-        x_ref[...], y_ref[...].T, preferred_element_type=jnp.float32
-    )
+    logits = dot(x_ref[...], y_ref[...].T)
     col = (
         id_offset
         + j * block_c
@@ -149,15 +146,6 @@ def _tgt_kernel(
     @pl.when(j == n_c_tiles - 1)
     def _finalize():
         out_ref[...] = acc_scr[...]
-
-
-def _pad_to(arr, axis, multiple, value=0):
-    pad = (-arr.shape[axis]) % multiple
-    if pad == 0:
-        return arr
-    widths = [(0, 0)] * arr.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(arr, widths, constant_values=value)
 
 
 def eval_topk(
@@ -214,9 +202,9 @@ def eval_topk(
     block_b = min(block_b, n)
     block_c = min(block_c, c)
 
-    xp = _pad_to(x, 0, block_b)
-    yp = _pad_to(y, 0, block_c)
-    tp = _pad_to(tgt_scores.astype(jnp.float32), 0, block_b)
+    xp = pad_to(x, 0, block_b)
+    yp = pad_to(y, 0, block_c)
+    tp = pad_to(tgt_scores.astype(jnp.float32), 0, block_b)
     n_p, c_p = xp.shape[0], yp.shape[0]
     n_b, n_c = n_p // block_b, c_p // block_c
 
@@ -245,10 +233,10 @@ def eval_topk(
             pl.BlockSpec((block_b,), lambda i, j: (i,)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_p, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_p, k), jnp.int32),
-            jax.ShapeDtypeStruct((n_p,), jnp.int32),
-            jax.ShapeDtypeStruct((n_p,), jnp.int32),
+            sds((n_p, k), jnp.float32, tp, xp, yp),
+            sds((n_p, k), jnp.int32, tp, xp, yp),
+            sds((n_p,), jnp.int32, tp, xp, yp),
+            sds((n_p,), jnp.int32, tp, xp, yp),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_b, k), jnp.float32),
@@ -294,9 +282,9 @@ def eval_tgt_scores(
         return jnp.zeros((0,), jnp.float32)
     block_b = min(block_b, n)
     block_c = min(block_c, c)
-    xp = _pad_to(x, 0, block_b)
-    yp = _pad_to(y, 0, block_c)
-    tp = _pad_to(targets.astype(jnp.int32), 0, block_b, value=-1)
+    xp = pad_to(x, 0, block_b)
+    yp = pad_to(y, 0, block_c)
+    tp = pad_to(targets.astype(jnp.int32), 0, block_b, value=-1)
     n_p, c_p = xp.shape[0], yp.shape[0]
     n_b, n_c = n_p // block_b, c_p // block_c
 
@@ -312,7 +300,7 @@ def eval_tgt_scores(
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((block_b,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_p,), jnp.float32),
+        out_shape=sds((n_p,), jnp.float32, tp, xp, yp),
         scratch_shapes=[pltpu.VMEM((block_b,), jnp.float32)],
         interpret=interpret,
     )(tp, xp, yp)

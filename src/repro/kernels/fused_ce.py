@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import dot, pad_to, psum_to, sds
+
 NEG_INF = -1e30
 
 
@@ -49,7 +51,7 @@ def _lse_kernel(
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    logits = jnp.dot(x_ref[...], y_ref[...].T, preferred_element_type=jnp.float32)
+    logits = dot(x_ref[...], y_ref[...].T)
     col_ids = j * block_c + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     logits = jnp.where(col_ids >= c_actual, NEG_INF, logits)
 
@@ -84,15 +86,13 @@ def _bwd_dx_kernel(
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    logits = jnp.dot(x_ref[...], y_ref[...].T, preferred_element_type=jnp.float32)
+    logits = dot(x_ref[...], y_ref[...].T)
     col_ids = j * block_c + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     p = jnp.where(
         col_ids >= c_actual, 0.0, jnp.exp(logits - lse_ref[...][:, None])
     )
     gw = p * g_ref[...][:, None].astype(jnp.float32)
-    acc_scr[...] += jnp.dot(
-        gw.astype(y_ref.dtype), y_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_scr[...] += dot(gw.astype(y_ref.dtype), y_ref[...])
 
     @pl.when(j == n_c_tiles - 1)
     def _finalize():
@@ -120,42 +120,17 @@ def _bwd_dy_kernel(
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    logits = jnp.dot(x_ref[...], y_ref[...].T, preferred_element_type=jnp.float32)
+    logits = dot(x_ref[...], y_ref[...].T)
     col_ids = jc * block_c + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     p = jnp.where(
         col_ids >= c_actual, 0.0, jnp.exp(logits - lse_ref[...][:, None])
     )
     gw = p * g_ref[...][:, None].astype(jnp.float32)
-    acc_scr[...] += jnp.dot(
-        gw.T.astype(x_ref.dtype), x_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_scr[...] += dot(gw.T.astype(x_ref.dtype), x_ref[...])
 
     @pl.when(i == n_n_tiles - 1)
     def _finalize():
         dy_ref[...] = acc_scr[...].astype(dy_ref.dtype)
-
-
-def _pad_to(arr, axis, multiple, value=0):
-    pad = (-arr.shape[axis]) % multiple
-    if pad == 0:
-        return arr
-    widths = [(0, 0)] * arr.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(arr, widths, constant_values=value)
-
-
-def _sds(shape, dtype, *operands):
-    """ShapeDtypeStruct with the union of operand ``vma`` sets (needed for
-    pallas_call under ``jax.shard_map``)."""
-    vma = frozenset()
-    for op in operands:
-        try:
-            vma = vma | jax.typeof(op).vma
-        except (AttributeError, TypeError):
-            pass
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def _fwd(x, y, *, block_n, block_c, interpret):
@@ -163,8 +138,8 @@ def _fwd(x, y, *, block_n, block_c, interpret):
     c = y.shape[0]
     block_n = min(block_n, n)
     block_c = min(block_c, c)
-    xp = _pad_to(x, 0, block_n)
-    yp = _pad_to(y, 0, block_c)
+    xp = pad_to(x, 0, block_n)
+    yp = pad_to(y, 0, block_c)
     n_p, c_p = xp.shape[0], yp.shape[0]
     n_n, n_c = n_p // block_n, c_p // block_c
 
@@ -178,7 +153,7 @@ def _fwd(x, y, *, block_n, block_c, interpret):
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((block_n,), lambda i, j: (i,)),
-        out_shape=_sds((n_p,), jnp.float32, xp, yp),
+        out_shape=sds((n_p,), jnp.float32, xp, yp),
         scratch_shapes=[
             pltpu.VMEM((block_n,), jnp.float32),
             pltpu.VMEM((block_n,), jnp.float32),
@@ -193,10 +168,10 @@ def _bwd(x, y, lse, g, *, block_n, block_c, interpret):
     c = y.shape[0]
     block_n = min(block_n, n)
     block_c = min(block_c, c)
-    xp = _pad_to(x, 0, block_n)
-    yp = _pad_to(y, 0, block_c)
-    lp = _pad_to(lse, 0, block_n)
-    gp = _pad_to(g, 0, block_n)  # zero cotangent on padded rows
+    xp = pad_to(x, 0, block_n)
+    yp = pad_to(y, 0, block_c)
+    lp = pad_to(lse, 0, block_n)
+    gp = pad_to(g, 0, block_n)  # zero cotangent on padded rows
     n_p, c_p = xp.shape[0], yp.shape[0]
     n_n, n_c = n_p // block_n, c_p // block_c
 
@@ -212,7 +187,7 @@ def _bwd(x, y, lse, g, *, block_n, block_c, interpret):
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-        out_shape=_sds((n_p, d), x.dtype, xp, yp, lp, gp),
+        out_shape=sds((n_p, d), x.dtype, xp, yp, lp, gp),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         interpret=interpret,
     )(lp, gp, xp, yp)
@@ -229,7 +204,7 @@ def _bwd(x, y, lse, g, *, block_n, block_c, interpret):
             pl.BlockSpec((block_c, d), lambda j, i: (j, 0)),
         ],
         out_specs=pl.BlockSpec((block_c, d), lambda j, i: (j, 0)),
-        out_shape=_sds((c_p, d), y.dtype, xp, yp, lp, gp),
+        out_shape=sds((c_p, d), y.dtype, xp, yp, lp, gp),
         scratch_shapes=[pltpu.VMEM((block_c, d), jnp.float32)],
         interpret=interpret,
     )(lp, gp, xp, yp)
@@ -252,7 +227,10 @@ def _vjp_fwd(x, y, block_n, block_c, interpret):
 
 def _vjp_bwd(block_n, block_c, interpret, res, g):
     x, y, lse = res
-    return _bwd(x, y, lse, g, block_n=block_n, block_c=block_c, interpret=interpret)
+    dx, dy = _bwd(
+        x, y, lse, g, block_n=block_n, block_c=block_c, interpret=interpret
+    )
+    return psum_to(dx, x), psum_to(dy, y)
 
 
 fused_lse.defvjp(_vjp_fwd, _vjp_bwd)

@@ -6,8 +6,9 @@
      auto-repaired or raise a structured :class:`KernelPreflightError`.
   2. **Conformance** (:mod:`.conformance`) — adversarial differential
      canaries per kernel, executed against the ``ref.py`` oracles on
-     the actual backend; ``ops.py`` consults the memoized verdicts and
-     degrades a failing kernel to its ref path with a loud warning.
+     the actual backend; ``ops.py`` consults the memoized verdicts. On
+     TPU a failing kernel raises; elsewhere it degrades to its ref path
+     with a loud warning.
   3. **Sentinels** (:mod:`.sentinels`) — on-device NaN/Inf/degenerate-
      LSE counters threaded from the loss kernels into the train loop's
      divergence guard, so a strike names the kernel that went bad.
@@ -21,7 +22,9 @@ Policy knob (``REPRO_GUARD`` env / :func:`set_policy` /
   off       legacy dispatch — no preflight, no verdicts, no
             sentinels
   warn      (default) repair + degrade with a loud warning; train
-            and serve keep running on the exact ref paths
+            and serve keep running on the exact ref paths — except on
+            TPU, where a failed canary always raises: the device path
+            never silently becomes the XLA reference
   strict    unrepairable configs and failed conformance RAISE
             (:class:`KernelPreflightError` /
             :class:`KernelConformanceError`); serve refuses
@@ -132,10 +135,10 @@ def kernel_enabled(kernel: str, *, interpret: Optional[bool] = None) -> bool:
     """Conformance gate for one dispatch.
 
     ``True`` → run the Pallas kernel. ``False`` → the canaries failed
-    on this backend and policy is ``warn``: the caller must degrade to
-    its ref path (a loud ``RuntimeWarning`` has been emitted). Under
-    ``strict`` a failing verdict raises
-    :class:`KernelConformanceError` instead.
+    on a non-TPU backend and policy is ``warn``: the caller must degrade
+    to its ref path (a loud ``RuntimeWarning`` has been emitted). Under
+    ``strict``, and on TPU under any policy but ``off``, a failing
+    verdict raises :class:`KernelConformanceError` instead.
     """
     pol = policy()
     if pol == "off":
@@ -143,7 +146,7 @@ def kernel_enabled(kernel: str, *, interpret: Optional[bool] = None) -> bool:
     v = verdict_for(kernel, interpret=interpret)
     if v.passed:
         return True
-    if pol == "strict":
+    if pol == "strict" or v.backend == "tpu":
         raise KernelConformanceError(
             kernel, (v.backend, v.interpret), v.failures
         )

@@ -11,16 +11,21 @@ Mosaic-validation item worries about:
   * starvation ``C < k`` (merge buffers larger than the catalog),
   * duplicate-row gather-indexed dY RMW (the ``sce_prefetch``
     ``input_output_aliases`` accumulation revisit),
-  * softcap-active logit scales (the in-tile ``cap·tanh`` path).
+  * softcap-active logit scales (the in-tile ``cap·tanh`` path),
+  * continuous float32 data, within a tolerance that a bfloat16
+    (single MXU pass) dot fails.
+
+Every reference runs at HIGHEST matmul precision.
 
 Each canary executes the REAL kernel entry point on the current
-backend (Mosaic on TPU, interpret elsewhere) and compares against the
-pure-jnp ``kernels/ref.py`` oracle. A kernel that raises (a Mosaic
-miscompile surfacing as an exception) or diverges numerically FAILS
-its canary; the per-``(backend, interpret)`` verdict is memoized and
-consulted by every ``kernels/ops.py`` dispatch, which degrades that
-kernel to its ref path with a loud warning instead of crashing or
-silently miscomputing.
+backend (Mosaic on TPU, interpret elsewhere) at Mosaic-legal block
+shapes and compares against the pure-jnp ``kernels/ref.py`` oracle —
+or, for the bitwise same-shape-gemm contract, against the sibling
+kernel. A kernel that raises (a Mosaic miscompile surfacing as an
+exception) or diverges numerically FAILS its canary; the
+per-``(backend, interpret)`` verdict is memoized and consulted by every
+``kernels/ops.py`` dispatch, which raises on TPU and elsewhere degrades
+that kernel to its ref path with a loud warning.
 
 Canaries resolve the kernel entry point by MODULE ATTRIBUTE at call
 time (``_mod().fn(...)``), so a monkeypatched/broken kernel — the
@@ -211,8 +216,59 @@ def verdict_table() -> List[Dict]:
 # ---------------------------------------------------------------------------
 # Canary input builders
 # ---------------------------------------------------------------------------
+# Canary shapes are Mosaic-legal: row blocks of 8, column blocks of 128,
+# d = 64 (sasrec-sce's width). The tie-order cases use small integers,
+# so every dot product is exact at any matmul precision and the kernel
+# and the XLA reference see identical scores — tie order is then a pure
+# property of the kernel. The ``continuous_f32`` cases use normal data
+# instead, and see the kernel's arithmetic. On a TPU v5e the kernels'
+# float32 gradients sit ~3e-5 from float64 (sce_gather at sasrec-sce's
+# widths; XLA's HIGHEST-precision reference ~1e-6), one-pass bfloat16
+# dots ~2e-3; on the CPU, one bfloat16 dot in sce_gather's forward alone
+# moves its dX by 1.5e-4. References run at HIGHEST precision: XLA's
+# default on a TPU is one bfloat16 pass as well.
+_D = 64
+_BR, _BC = 8, 128  # row block, column block
+_F32_TOL = 1e-4  # relative error allowed on continuous data
+
+
 def _rng(salt: int) -> np.random.Generator:
     return np.random.default_rng(_SEED + salt)
+
+
+def _ints(r, shape, scale=1.0):
+    import jax.numpy as jnp
+
+    return jnp.asarray(r.integers(-2, 3, size=shape) * scale, jnp.float32)
+
+
+def _normals(r, shape, scale=1.0):
+    import jax.numpy as jnp
+
+    return jnp.asarray(r.standard_normal(shape) * scale, jnp.float32)
+
+
+def _highest():
+    import jax
+
+    return jax.default_matmul_precision("highest")
+
+
+def _assert_rel(name: str, got, want, tol=_F32_TOL):
+    """Relative Frobenius error ``|got - want| / |want|`` within ``tol``
+    (elementwise tolerances mislead on gradients, whose entries cancel)."""
+    got = np.asarray(got, np.float64)
+    want = np.asarray(want, np.float64)
+    if got.shape != want.shape:
+        raise AssertionError(
+            f"{name}: shape {got.shape} != oracle {want.shape}"
+        )
+    err = float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
+    if not err <= tol:
+        raise AssertionError(
+            f"{name}: relative error {err:.3e} vs the HIGHEST-precision "
+            f"oracle (tol={tol})"
+        )
 
 
 def _assert_close(name: str, got, want, atol=_ATOL, rtol=_RTOL):
@@ -234,30 +290,40 @@ def _assert_ids(name: str, got, want):
         raise AssertionError(f"{name}: id/tie-order mismatch vs oracle")
 
 
-def _sce_inputs(salt: int, n_b=2, b_x=5, b_y=7, d=8, c=16, softcap=None):
-    """Adversarial SCE bucket inputs: non-multiple b_x/b_y (block
-    tails), padding slots (cand_id −1), target-collision candidates."""
+def _sce_inputs(salt: int, n_b=2, b_x=12, b_y=300, c=700, softcap=None,
+                continuous=False):
+    """Adversarial SCE bucket inputs: block tails on both bucket axes
+    (b_x % 8, b_y % 128), padding slots (cand_id −1), a target-collision
+    candidate, and duplicate gather rows inside one bucket and across
+    buckets (the dY read-modify-write revisit). Small integers, or
+    normals with logits of order one when ``continuous``."""
     import jax.numpy as jnp
 
     r = _rng(salt)
     scale = 4.0 if softcap else 1.0  # softcap-active logit magnitudes
-    x_b = jnp.asarray(r.normal(size=(n_b, b_x, d)) * scale, jnp.float32)
-    y = jnp.asarray(r.normal(size=(c, d)), jnp.float32)
+    if continuous:
+        x_b = _normals(r, (n_b, b_x, _D), scale / 4)
+        y = _normals(r, (c, _D), 0.25)
+    else:
+        x_b = _ints(r, (n_b, b_x, _D), scale)
+        y = _ints(r, (c, _D))
     tgt_b = jnp.asarray(r.integers(0, c, size=(n_b, b_x)), jnp.int32)
-    idx = r.integers(0, c, size=(n_b, b_y))
-    idx[:, 1] = idx[:, 0]  # duplicate-row revisit inside one bucket
+    idx = np.stack([r.permutation(c)[:b_y] for _ in range(n_b)])
+    idx[:, 1] = idx[:, 0]  # duplicate row inside one bucket
+    idx[1, 2:6] = idx[0, 2:6]  # rows shared by adjacent buckets
     cand_ids = idx.astype(np.int32)
     cand_ids[:, -1] = -1  # padding slot
-    cand_ids[0, 2] = int(tgt_b[0, 0])  # forced target collision
+    cand_ids[0, 7] = int(tgt_b[0, 0])  # forced target collision
     cand_ids = jnp.asarray(cand_ids)
     idx_y = jnp.asarray(np.maximum(np.asarray(cand_ids), 0), jnp.int32)
-    pos = jnp.einsum(
-        "nxd,nxd->nx", x_b,
-        jnp.take(y, tgt_b.reshape(-1), axis=0).reshape(n_b, b_x, d),
-    ).astype(jnp.float32)
+    with _highest():
+        pos = jnp.einsum(
+            "nxd,nxd->nx", x_b,
+            jnp.take(y, tgt_b.reshape(-1), axis=0).reshape(n_b, b_x, _D),
+        ).astype(jnp.float32)
     if softcap:
         pos = softcap * jnp.tanh(pos / softcap)
-    y_b = jnp.take(y, idx_y.reshape(-1), axis=0).reshape(n_b, b_y, d)
+    y_b = jnp.take(y, idx_y.reshape(-1), axis=0).reshape(n_b, b_y, _D)
     return x_b, y, y_b, idx_y, tgt_b, cand_ids, pos
 
 
@@ -278,11 +344,12 @@ def _sce_bucket_loss_canary(interpret: bool):
             1, softcap=softcap
         )
         got = _mod("sce_bucket").sce_bucket_loss(
-            x_b, y_b, tgt_b, cand_ids, pos, 4, 4, interpret, softcap
+            x_b, y_b, tgt_b, cand_ids, pos, _BR, _BC, interpret, softcap
         )
-        want = ref.sce_bucket_loss_ref(
-            x_b, y_b, tgt_b, cand_ids, pos, softcap
-        )
+        with _highest():
+            want = ref.sce_bucket_loss_ref(
+                x_b, y_b, tgt_b, cand_ids, pos, softcap
+            )
         _assert_close(f"loss(softcap={softcap})", got, want)
 
 
@@ -294,16 +361,17 @@ def _sce_bucket_plse_canary(interpret: bool):
 
     x_b, _, y_b, _, tgt_b, cand_ids, _ = _sce_inputs(2)
     got = _mod("sce_bucket").sce_bucket_plse(
-        x_b, y_b, tgt_b, cand_ids, 4, 4, interpret, None
+        x_b, y_b, tgt_b, cand_ids, _BR, _BC, interpret, None
     )
-    want = ref.sce_bucket_plse_ref(x_b, y_b, tgt_b, cand_ids, None)
+    with _highest():
+        want = ref.sce_bucket_plse_ref(x_b, y_b, tgt_b, cand_ids, None)
     _assert_close("plse", got, want)
 
     def k_loss(xb):
         return _mod("sce_bucket").sce_bucket_loss(
             xb, y_b, tgt_b, cand_ids,
             jax.numpy.zeros(tgt_b.shape, jax.numpy.float32),
-            4, 4, interpret, None,
+            _BR, _BC, interpret, None,
         ).sum()
 
     def r_loss(xb):
@@ -312,59 +380,59 @@ def _sce_bucket_plse_canary(interpret: bool):
             jax.numpy.zeros(tgt_b.shape, jax.numpy.float32), None,
         ).sum()
 
-    _assert_close("dX", jax.grad(k_loss)(x_b), jax.grad(r_loss)(x_b),
-                  atol=1e-3, rtol=1e-3)
+    with _highest():
+        want = jax.grad(r_loss)(x_b)
+    _assert_close("dX", jax.grad(k_loss)(x_b), want, atol=1e-3, rtol=1e-3)
 
 
-# -- sce_gather (scalar-prefetch candidate gather + dY RMW) ------------------
+# -- sce_gather (manual-DMA candidate gather + dY RMW) -----------------------
 @_canary("sce_gather", "duplicate_row_rmw")
 def _sce_gather_canary(interpret: bool):
     import jax
 
     from repro.kernels import ref
 
-    x_b, y, _, idx_y, tgt_b, cand_ids, pos = _sce_inputs(3)
+    x_b, y, y_b, idx_y, tgt_b, cand_ids, pos = _sce_inputs(3)
     got = _mod("sce_prefetch").sce_gather_loss(
-        x_b, y, idx_y, tgt_b, cand_ids, pos, 4, 4, interpret, None
+        x_b, y, idx_y, tgt_b, cand_ids, pos, _BR, _BC, interpret, None
     )
-    want = ref.sce_bucket_loss_ref(
-        x_b,
-        jax.numpy.take(y, idx_y.reshape(-1), axis=0).reshape(
-            idx_y.shape + (y.shape[-1],)
-        ),
-        tgt_b, cand_ids, pos, None,
-    )
+    with _highest():
+        want = ref.sce_bucket_loss_ref(x_b, y_b, tgt_b, cand_ids, pos, None)
     _assert_close("gather_loss", got, want)
 
     # The RMW revisit: dY accumulated straight into (C, d) through
     # duplicated gather indices must equal the materialized-gather
-    # oracle's scatter-add.
-    def k_loss(yy):
+    # oracle's scatter-add; dX rides the same backward kernel.
+    def k_loss(xb, yy):
         return _mod("sce_prefetch").sce_gather_loss(
-            x_b, yy, idx_y, tgt_b, cand_ids, pos, 4, 4, interpret, None
+            xb, yy, idx_y, tgt_b, cand_ids, pos, _BR, _BC, interpret, None
         ).sum()
 
-    def r_loss(yy):
+    def r_loss(xb, yy):
         y_b = jax.numpy.take(yy, idx_y.reshape(-1), axis=0).reshape(
             idx_y.shape + (yy.shape[-1],)
         )
         return ref.sce_bucket_loss_ref(
-            x_b, y_b, tgt_b, cand_ids, pos, None
+            xb, y_b, tgt_b, cand_ids, pos, None
         ).sum()
 
-    _assert_close("dY_rmw", jax.grad(k_loss)(y), jax.grad(r_loss)(y),
-                  atol=1e-3, rtol=1e-3)
+    gk = jax.grad(k_loss, argnums=(0, 1))(x_b, y)
+    with _highest():
+        gr = jax.grad(r_loss, argnums=(0, 1))(x_b, y)
+    _assert_close("dX", gk[0], gr[0], atol=1e-3, rtol=1e-3)
+    _assert_close("dY_rmw", gk[1], gr[1], atol=1e-3, rtol=1e-3)
 
 
 @_canary("sce_gather", "plse_tail")
 def _sce_gather_plse_canary(interpret: bool):
     from repro.kernels import ref
 
-    x_b, y, y_b, idx_y, tgt_b, cand_ids, _ = _sce_inputs(4, b_y=9)
+    x_b, y, y_b, idx_y, tgt_b, cand_ids, _ = _sce_inputs(4, b_y=260)
     got = _mod("sce_prefetch").sce_gather_plse(
-        x_b, y, idx_y, tgt_b, cand_ids, 4, 4, interpret, None
+        x_b, y, idx_y, tgt_b, cand_ids, _BR, _BC, interpret, None
     )
-    want = ref.sce_bucket_plse_ref(x_b, y_b, tgt_b, cand_ids, None)
+    with _highest():
+        want = ref.sce_bucket_plse_ref(x_b, y_b, tgt_b, cand_ids, None)
     _assert_close("gather_plse", got, want)
 
 
@@ -376,15 +444,15 @@ def _mips_ties_canary(interpret: bool):
     from repro.kernels import ref
 
     r = _rng(10)
-    base = r.normal(size=(5, 8)).astype(np.float32)
-    # Tie-heavy catalog: every row duplicated, C=10 with block 4 → tail
-    # of 2; ties must resolve toward the LOWER global id in both paths.
-    y = jnp.asarray(np.repeat(base, 2, axis=0))
-    q = jnp.asarray(r.normal(size=(6, 8)).astype(np.float32))
+    # Tie-heavy catalog: every row duplicated, C=300 with block 128 →
+    # tail of 44; ties must resolve toward the LOWER global id.
+    y = jnp.repeat(_ints(r, (150, _D)), 2, axis=0)
+    q = _ints(r, (12, _D))
     got_v, got_i = _mod("mips_topk").mips_topk(
-        q, y, 4, block_q=4, block_c=4, interpret=interpret
+        q, y, 16, block_q=_BR, block_c=_BC, interpret=interpret
     )
-    want_v, want_i = ref.mips_topk_ref(q, y, 4, chunk=4)
+    with _highest():
+        want_v, want_i = ref.mips_topk_ref(q, y, 16, chunk=_BC)
     _assert_ids("topk_ids", got_i, want_i)
     _assert_close("topk_vals", got_v, want_v)
 
@@ -396,17 +464,18 @@ def _mips_starved_canary(interpret: bool):
     from repro.kernels import ref
 
     r = _rng(11)
-    q = jnp.asarray(r.normal(size=(3, 8)).astype(np.float32))
-    y = jnp.asarray(r.normal(size=(3, 8)).astype(np.float32))
+    q = _ints(r, (3, _D))
+    y = _ints(r, (3, _D))
     valid = jnp.asarray([True, False, True])
     # k=8 > C=3 (starved merge buffer) + masked row + nonzero id base.
     got_v, got_i = _mod("mips_topk").mips_topk(
-        q, y, 8, valid=valid, block_q=2, block_c=2, id_offset=7,
+        q, y, 8, valid=valid, block_q=_BR, block_c=_BC, id_offset=7,
         interpret=interpret,
     )
-    want_v, want_i = ref.mips_topk_ref(
-        q, y, 8, valid=valid, chunk=2, id_offset=7
-    )
+    with _highest():
+        want_v, want_i = ref.mips_topk_ref(
+            q, y, 8, valid=valid, chunk=_BC, id_offset=7
+        )
     _assert_ids("starved_ids", got_i, want_i)
     _assert_close("starved_vals", got_v, want_v)
 
@@ -419,13 +488,15 @@ def _fused_ce_canary(interpret: bool):
     from repro.kernels import ref
 
     r = _rng(20)
-    x = jnp.asarray(r.normal(size=(6, 8)).astype(np.float32))
-    y = jnp.asarray(r.normal(size=(11, 8)).astype(np.float32))  # C%4=3
-    tgt = jnp.asarray(r.integers(0, 11, size=(6,)), jnp.int32)
-    got = _mod("fused_ce").fused_lse(x, y, 4, 4, interpret)
-    _assert_close("fused_lse", got, ref.fused_lse_ref(x, y))
-    got = _mod("fused_ce").fused_ce_loss(x, y, tgt, 4, 4, interpret)
-    _assert_close("fused_ce_loss", got, ref.fused_ce_loss_ref(x, y, tgt))
+    x = _ints(r, (12, _D))
+    y = _ints(r, (300, _D))  # C % 128 = 44
+    tgt = jnp.asarray(r.integers(0, 300, size=(12,)), jnp.int32)
+    got = _mod("fused_ce").fused_lse(x, y, _BR, _BC, interpret)
+    got_loss = _mod("fused_ce").fused_ce_loss(x, y, tgt, _BR, _BC, interpret)
+    with _highest():
+        _assert_close("fused_lse", got, ref.fused_lse_ref(x, y))
+        _assert_close("fused_ce_loss", got_loss,
+                      ref.fused_ce_loss_ref(x, y, tgt))
 
 
 # -- linear_sce --------------------------------------------------------------
@@ -436,24 +507,25 @@ def _linear_sce_canary(interpret: bool):
     from repro.kernels import ref
 
     r = _rng(30)
-    x = jax.numpy.asarray(r.normal(size=(6, 8)).astype(np.float32) * 3)
-    w = jax.numpy.asarray(r.normal(size=(13, 8)).astype(np.float32))
-    tgt = jax.numpy.asarray(r.integers(0, 13, size=(6,)),
+    x = _ints(r, (12, _D), 3.0)
+    w = _ints(r, (300, _D))
+    tgt = jax.numpy.asarray(r.integers(0, 300, size=(12,)),
                             jax.numpy.int32)
     cap = 4.0  # softcap-active scales
 
     def k_loss(xx, ww):
         return _mod("linear_sce").linear_ce_loss(
-            xx, ww, tgt, cap, 4, 4, interpret
+            xx, ww, tgt, cap, _BR, _BC, interpret
         ).sum()
 
     def r_loss(xx, ww):
         return ref.linear_ce_loss_ref(
-            xx, ww, tgt, logit_softcap=cap, chunk=4
+            xx, ww, tgt, logit_softcap=cap, chunk=_BC
         ).sum()
 
     (gl, (gdx, gdw)) = jax.value_and_grad(k_loss, argnums=(0, 1))(x, w)
-    (wl, (wdx, wdw)) = jax.value_and_grad(r_loss, argnums=(0, 1))(x, w)
+    with _highest():
+        (wl, (wdx, wdw)) = jax.value_and_grad(r_loss, argnums=(0, 1))(x, w)
     _assert_close("linear_ce", gl, wl)
     _assert_close("linear_dx", gdx, wdx, atol=1e-3, rtol=1e-3)
     _assert_close("linear_dw", gdw, wdw, atol=1e-3, rtol=1e-3)
@@ -467,16 +539,17 @@ def _eval_fused_canary(interpret: bool):
     from repro.kernels import ref
 
     r = _rng(40)
-    base = r.normal(size=(7, 8)).astype(np.float32)
-    y = jnp.asarray(np.concatenate([base, base[:3]], axis=0))  # C=10 ties
-    x = jnp.asarray(r.normal(size=(5, 8)).astype(np.float32))
-    tgt = jnp.asarray(r.integers(1, 9, size=(5,)), jnp.int32)
-    kw = dict(block_c=4, c_lo=1, c_hi=9, with_lse=True)
+    base = _ints(r, (200, _D))
+    y = jnp.concatenate([base, base[:100]], axis=0)  # C=300, ties, tail
+    x = _ints(r, (12, _D))
+    tgt = jnp.asarray(r.integers(1, 290, size=(12,)), jnp.int32)
+    kw = dict(block_c=_BC, c_lo=1, c_hi=290, with_lse=True)
     got = _mod("eval_fused").eval_fused(
-        x, y, tgt, 4, block_b=4, interpret=interpret, **kw
+        x, y, tgt, 10, block_b=_BR, interpret=interpret, **kw
     )
-    want = ref.eval_fused_ref(x, y, tgt, 4, chunk=4, c_lo=1, c_hi=9,
-                              with_lse=True)
+    with _highest():
+        want = ref.eval_fused_ref(x, y, tgt, 10, chunk=_BC, c_lo=1,
+                                  c_hi=290, with_lse=True)
     for name, g, w in zip(("vals", "gt", "eq", "tgt", "m", "s"),
                           (got[0],) + got[2:], (want[0],) + want[2:]):
         _assert_close(f"eval_{name}", g, w)
@@ -487,19 +560,22 @@ def _eval_fused_canary(interpret: bool):
 def _eval_tgt_gather_canary(interpret: bool):
     import jax.numpy as jnp
 
-    from repro.kernels import ref
-
     r = _rng(41)
-    x = jnp.asarray(r.normal(size=(5, 8)).astype(np.float32))
-    y = jnp.asarray(r.normal(size=(10, 8)).astype(np.float32))
-    tgt = jnp.asarray(r.integers(0, 10, size=(5,)), jnp.int32)
-    got = _mod("eval_fused").eval_tgt_gather(
-        x, y, tgt, block_b=4, block_c=4, interpret=interpret
-    )
-    want = ref.eval_tgt_gather_ref(x, y, tgt, chunk=4)
-    # The same-shape-gemm contract is BITWISE — the one Mosaic
-    # assumption the ROADMAP flags; zero tolerance here is the point.
-    _assert_close("tgt_gather", got, want, atol=0.0, rtol=0.0)
+    # Real-valued scores: the same-shape-gemm contract is that the
+    # target slot of eval_tgt_gather equals, BITWISE, the column the
+    # eval_fused sweep computes. Each row's target is planted as its
+    # clear top-1 so the sweep reports that column's value.
+    x = r.normal(size=(12, _D)).astype(np.float32)
+    y = r.normal(size=(300, _D)).astype(np.float32)
+    tgt = r.permutation(300)[:12].astype(np.int32)
+    y[tgt] = 3.0 * x
+    x, y, tgt = jnp.asarray(x), jnp.asarray(y), jnp.asarray(tgt)
+    kw = dict(block_b=_BR, block_c=_BC, interpret=interpret)
+    slot = _mod("eval_fused").eval_tgt_gather(x, y, tgt, **kw)
+    vals, ids = _mod("eval_fused").eval_fused(x, y, tgt, 4, **kw)[:2]
+    _assert_ids("planted_top1", ids[:, 0], tgt)
+    _assert_close("tgt_gather_vs_sweep", slot, vals[:, 0],
+                  atol=0.0, rtol=0.0)
 
 
 # -- eval_topk (deprecated two-pass oracle entry points) ---------------------
@@ -510,21 +586,185 @@ def _eval_topk_canary(interpret: bool):
     from repro.kernels import ref
 
     r = _rng(50)
-    base = r.normal(size=(6, 8)).astype(np.float32)
-    y = jnp.asarray(np.concatenate([base, base[:2]], axis=0))  # C=8
-    x = jnp.asarray(r.normal(size=(4, 8)).astype(np.float32))
-    tgt = jnp.asarray(r.integers(0, 8, size=(4,)), jnp.int32)
-    ts_got = _mod("eval_topk").eval_tgt_scores(
-        x, y, tgt, block_b=4, block_c=4, interpret=interpret
-    )
-    ts_want = ref.eval_tgt_scores_ref(x, y, tgt, chunk=4)
+    base = _ints(r, (200, _D))
+    y = jnp.concatenate([base, base[:100]], axis=0)  # C=300
+    x = _ints(r, (12, _D))
+    tgt = jnp.asarray(r.integers(0, 300, size=(12,)), jnp.int32)
+    kw = dict(block_b=_BR, block_c=_BC, interpret=interpret)
+    ts_got = _mod("eval_topk").eval_tgt_scores(x, y, tgt, **kw)
+    got = _mod("eval_topk").eval_topk(x, y, ts_got, 10, **kw)
+    with _highest():
+        ts_want = ref.eval_tgt_scores_ref(x, y, tgt, chunk=_BC)
+        want = ref.eval_topk_ref(x, y, ts_want, 10, chunk=_BC)
     _assert_close("tgt_scores", ts_got, ts_want, atol=0.0, rtol=0.0)
-    got = _mod("eval_topk").eval_topk(
-        x, y, ts_got, 3, block_b=4, block_c=4, interpret=interpret
-    )
-    want = ref.eval_topk_ref(x, y, ts_want, 3, chunk=4)
     _assert_ids("two_pass_ids", got[1], want[1])
     for name, g, w in zip(("vals", "gt", "eq"),
                           (got[0], got[2], got[3]),
                           (want[0], want[2], want[3])):
         _assert_close(f"two_pass_{name}", g, w)
+
+
+# -- continuous float32 data: one case per kernel ------------------------------
+@_canary("sce_bucket", "continuous_f32")
+def _sce_bucket_f32_canary(interpret: bool):
+    import jax
+
+    from repro.kernels import ref
+
+    x_b, _, y_b, _, tgt_b, cand_ids, pos = _sce_inputs(60, continuous=True)
+
+    def k_loss(xb, yb):
+        return _mod("sce_bucket").sce_bucket_loss(
+            xb, yb, tgt_b, cand_ids, pos, _BR, _BC, interpret, None
+        ).sum()
+
+    def r_loss(xb, yb):
+        return ref.sce_bucket_loss_ref(xb, yb, tgt_b, cand_ids, pos).sum()
+
+    got = jax.value_and_grad(k_loss, argnums=(0, 1))(x_b, y_b)
+    with _highest():
+        want = jax.value_and_grad(r_loss, argnums=(0, 1))(x_b, y_b)
+    for name, g, w in zip(("loss", "dX", "dY"), (got[0], *got[1]),
+                          (want[0], *want[1])):
+        _assert_rel(f"f32_{name}", g, w)
+
+
+@_canary("sce_gather", "continuous_f32")
+def _sce_gather_f32_canary(interpret: bool):
+    import jax
+
+    from repro.kernels import ref
+
+    x_b, y, _, idx_y, tgt_b, cand_ids, pos = _sce_inputs(61, continuous=True)
+
+    def k_loss(xb, yy):
+        return _mod("sce_prefetch").sce_gather_loss(
+            xb, yy, idx_y, tgt_b, cand_ids, pos, _BR, _BC, interpret, None
+        ).sum()
+
+    def r_loss(xb, yy):
+        y_b = jax.numpy.take(yy, idx_y, axis=0)
+        return ref.sce_bucket_loss_ref(xb, y_b, tgt_b, cand_ids, pos).sum()
+
+    got = jax.value_and_grad(k_loss, argnums=(0, 1))(x_b, y)
+    with _highest():
+        want = jax.value_and_grad(r_loss, argnums=(0, 1))(x_b, y)
+    for name, g, w in zip(("loss", "dX", "dY_rmw"), (got[0], *got[1]),
+                          (want[0], *want[1])):
+        _assert_rel(f"f32_{name}", g, w)
+
+
+@_canary("mips_topk", "continuous_f32")
+def _mips_f32_canary(interpret: bool):
+    from repro.kernels import ref
+
+    r = _rng(62)
+    q, y = _normals(r, (12, _D)), _normals(r, (300, _D))
+    got_v, got_i = _mod("mips_topk").mips_topk(
+        q, y, 16, block_q=_BR, block_c=_BC, interpret=interpret
+    )
+    with _highest():
+        want_v, want_i = ref.mips_topk_ref(q, y, 16, chunk=_BC)
+    _assert_ids("f32_topk_ids", got_i, want_i)
+    _assert_rel("f32_topk_vals", got_v, want_v)
+
+
+@_canary("fused_ce", "continuous_f32")
+def _fused_ce_f32_canary(interpret: bool):
+    import jax.numpy as jnp
+
+    from repro.kernels import ref
+
+    r = _rng(63)
+    x, y = _normals(r, (12, _D)), _normals(r, (300, _D), 0.25)
+    tgt = jnp.asarray(r.integers(0, 300, size=(12,)), jnp.int32)
+    got_lse = _mod("fused_ce").fused_lse(x, y, _BR, _BC, interpret)
+    got_loss = _mod("fused_ce").fused_ce_loss(x, y, tgt, _BR, _BC, interpret)
+    with _highest():
+        _assert_rel("f32_fused_lse", got_lse, ref.fused_lse_ref(x, y))
+        _assert_rel("f32_fused_ce_loss", got_loss,
+                    ref.fused_ce_loss_ref(x, y, tgt))
+
+
+@_canary("linear_sce", "continuous_f32")
+def _linear_sce_f32_canary(interpret: bool):
+    import jax
+
+    from repro.kernels import ref
+
+    r = _rng(64)
+    x, w = _normals(r, (12, _D)), _normals(r, (300, _D), 0.5)
+    tgt = jax.numpy.asarray(r.integers(0, 300, size=(12,)), jax.numpy.int32)
+    cap = 4.0
+
+    def k_loss(xx, ww):
+        return _mod("linear_sce").linear_ce_loss(
+            xx, ww, tgt, cap, _BR, _BC, interpret
+        ).sum()
+
+    def r_loss(xx, ww):
+        return ref.linear_ce_loss_ref(
+            xx, ww, tgt, logit_softcap=cap, chunk=_BC
+        ).sum()
+
+    got = jax.value_and_grad(k_loss, argnums=(0, 1))(x, w)
+    with _highest():
+        want = jax.value_and_grad(r_loss, argnums=(0, 1))(x, w)
+    for name, g, v in zip(("loss", "dx", "dw"), (got[0], *got[1]),
+                          (want[0], *want[1])):
+        _assert_rel(f"f32_linear_{name}", g, v)
+
+
+def _f64_counts(x, y, tgt, c_lo, c_hi):
+    """Float64 gt counts: items in [c_lo, c_hi) scoring strictly above
+    each row's target (continuous data: no exact ties)."""
+    s = np.asarray(x, np.float64) @ np.asarray(y, np.float64).T
+    t = np.asarray(tgt)
+    own = s[np.arange(len(t)), t][:, None]
+    return np.sum(s[:, c_lo:c_hi] > own, axis=1)
+
+
+@_canary("eval_fused", "continuous_f32")
+def _eval_fused_f32_canary(interpret: bool):
+    import jax.numpy as jnp
+
+    from repro.kernels import ref
+
+    r = _rng(65)
+    x, y = _normals(r, (12, _D)), _normals(r, (300, _D))
+    tgt = jnp.asarray(r.integers(1, 290, size=(12,)), jnp.int32)
+    got = _mod("eval_fused").eval_fused(
+        x, y, tgt, 10, block_b=_BR, block_c=_BC, c_lo=1, c_hi=290,
+        with_lse=True, interpret=interpret,
+    )
+    with _highest():
+        want = ref.eval_fused_ref(x, y, tgt, 10, chunk=_BC, c_lo=1,
+                                  c_hi=290, with_lse=True)
+    _assert_ids("f32_eval_ids", got[1], want[1])
+    _assert_ids("f32_eval_gt", got[2], _f64_counts(x, y, tgt, 1, 290))
+    _assert_ids("f32_eval_eq", got[3], np.ones(12, np.int32))
+    for name, g, w in zip(("vals", "tgt", "lse"),
+                          (got[0], got[4], got[5] + np.log(got[6])),
+                          (want[0], want[4], want[5] + np.log(want[6]))):
+        _assert_rel(f"f32_eval_{name}", g, w)
+
+
+@_canary("eval_topk", "continuous_f32")
+def _eval_topk_f32_canary(interpret: bool):
+    import jax.numpy as jnp
+
+    from repro.kernels import ref
+
+    r = _rng(66)
+    x, y = _normals(r, (12, _D)), _normals(r, (300, _D))
+    tgt = jnp.asarray(r.integers(0, 300, size=(12,)), jnp.int32)
+    kw = dict(block_b=_BR, block_c=_BC, interpret=interpret)
+    ts = _mod("eval_topk").eval_tgt_scores(x, y, tgt, **kw)
+    got = _mod("eval_topk").eval_topk(x, y, ts, 10, **kw)
+    with _highest():
+        ts_want = ref.eval_tgt_scores_ref(x, y, tgt, chunk=_BC)
+        want = ref.eval_topk_ref(x, y, ts_want, 10, chunk=_BC)
+    _assert_rel("f32_tgt_scores", ts, ts_want)
+    _assert_ids("f32_two_pass_ids", got[1], want[1])
+    _assert_rel("f32_two_pass_vals", got[0], want[0])
+    _assert_ids("f32_two_pass_gt", got[2], _f64_counts(x, y, tgt, 0, 300))

@@ -114,28 +114,40 @@ class PreflightResult:
         return [r for r in self.repairs if not r.silent]
 
 
+def _merge_elements(block_rows: int, block_cols: int, k: int) -> int:
+    """The streaming top-k merge: (vals, ids) buffers plus the live
+    (value, id, position) working set over the (k + tile)-wide
+    concatenation (``kernels/topk_merge.py``)."""
+    return 2 * block_rows * k + 3 * block_rows * (k + block_cols)
+
+
 def _scratch_elements(kernel: str, block_rows: int, block_cols: int,
-                      k: Optional[int]) -> int:
+                      k: Optional[int], d: int, rows: int) -> int:
     """f32 carry/scratch elements one grid step of ``kernel`` keeps
     live in VMEM (mirrors each wrapper's ``scratch_shapes``)."""
-    kk = min(k, block_cols) if k else 0
-    if kernel in ("sce_bucket", "sce_gather"):
-        # (m, s) online-LSE carries; the gather variant adds the dY
-        # revisit accumulator row.
-        return (2 + (kernel == "sce_gather")) * block_rows
+    kk = max(k or 0, 1)
+    if kernel == "sce_bucket":
+        return 2 * block_rows  # (m, s) online-LSE carries
+    if kernel == "sce_gather":
+        # Rows are DMA'd at whole 128-lane width: the gathered tile, the
+        # dY RMW rows and their accumulator (block_cols each), and the
+        # per-bucket dX accumulator plus its double-buffered output
+        # block (rows each), plus the (m, s) carries.
+        dl = _round_up(d, LANE)
+        return 3 * (block_cols + rows) * dl + 2 * block_rows
     if kernel == "mips_topk":
-        return 2 * block_rows * max(kk, 1)  # vals + ids merge buffers
+        return _merge_elements(block_rows, block_cols, kk)
     if kernel == "fused_ce":
         return 2 * block_rows  # (m, s)
     if kernel == "linear_sce":
         return 3 * block_rows  # (m, s, pos)
     if kernel == "eval_fused":
-        # top-k merge buffers + (gt, eq, tgt, m, s) row carries — the
-        # same O(B·(k + block)) streaming state eval_peak_elements
-        # models at batch scale.
-        return 2 * block_rows * max(kk, 1) + 5 * block_rows
+        # the merge + (gt, eq, tgt, m, s) row carries — the same
+        # O(B·(k + block)) streaming state eval_peak_elements models at
+        # batch scale.
+        return _merge_elements(block_rows, block_cols, kk) + 5 * block_rows
     if kernel == "eval_topk":
-        return 2 * block_rows * max(kk, 1) + 2 * block_rows
+        return _merge_elements(block_rows, block_cols, kk) + 2 * block_rows
     raise KernelPreflightError(kernel, "unknown_kernel",
                                f"no VMEM model registered for {kernel!r}")
 
@@ -161,13 +173,19 @@ def vmem_budget_bytes() -> int:
 
 def modeled_vmem_bytes(kernel: str, *, block_rows: int, block_cols: int,
                        d: int, k: Optional[int] = None,
-                       dtype: str = "float32") -> int:
+                       dtype: str = "float32",
+                       rows: Optional[int] = None) -> int:
     """Double-buffered input tiles (operand dtype) + the f32 logit tile
-    + the kernel's f32 carry scratch, in bytes."""
+    + the kernel's f32 carry scratch, in bytes. ``rows`` (default
+    ``block_rows``) is the whole row axis, which the gather kernel's
+    per-bucket dX accumulator spans."""
     ebytes = _DTYPE_BYTES.get(dtype, 4)
     tiles = (block_rows * d + block_cols * d) * ebytes * 2  # dbl-buffered
     logit = block_rows * block_cols * 4
-    scratch = _scratch_elements(kernel, block_rows, block_cols, k) * 4
+    scratch = _scratch_elements(
+        kernel, block_rows, block_cols, k, d,
+        block_rows if rows is None else rows,
+    ) * 4
     return tiles + logit + scratch
 
 
@@ -273,7 +291,8 @@ def preflight(
 
     budget = vmem_budget_bytes()
     vmem = modeled_vmem_bytes(kernel, block_rows=block_rows,
-                              block_cols=block_cols, d=d, k=k, dtype=dtype)
+                              block_cols=block_cols, d=d, k=k, dtype=dtype,
+                              rows=rows)
     if on_tpu:
         # Shrink the streamed axis first (it only costs more grid
         # steps), then the row axis, keeping tile alignment; a config
@@ -298,7 +317,7 @@ def preflight(
                 )
             vmem = modeled_vmem_bytes(kernel, block_rows=block_rows,
                                       block_cols=block_cols, d=d, k=k,
-                                      dtype=dtype)
+                                      dtype=dtype, rows=rows)
 
     return PreflightResult(
         kernel=kernel,

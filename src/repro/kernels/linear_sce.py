@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fused_ce import _pad_to, _sds
+from repro.kernels.common import dot, pad_to, psum_to, sds
 
 NEG_INF = -1e30
 
@@ -58,14 +58,14 @@ def _cap_deriv(capped, logit_softcap):
 
 
 def _fwd_kernel(
-    tgt_ref,  # (n_t,) i32 — padded rows carry -1 (never matches a column)
+    tgt_ref,  # (n_t, 1) i32 — padded rows carry -1 (never matches a column)
     x_ref,  # (n_t, d)
     w_ref,  # (c_t, d)
-    loss_ref,  # (n_t,) f32 out
-    lse_ref,  # (n_t,) f32 out
-    m_scr,  # (n_t,) f32
-    s_scr,  # (n_t,) f32
-    pos_scr,  # (n_t,) f32
+    loss_ref,  # (n_t, 1) f32 out
+    lse_ref,  # (n_t, 1) f32 out
+    m_scr,  # (n_t, 1) f32
+    s_scr,  # (n_t, 1) f32
+    pos_scr,  # (n_t, 1) f32
     *,
     n_c_tiles: int,
     c_actual: int,
@@ -80,20 +80,21 @@ def _fwd_kernel(
         s_scr[...] = jnp.zeros_like(s_scr)
         pos_scr[...] = jnp.zeros_like(pos_scr)
 
-    logits = jnp.dot(x_ref[...], w_ref[...].T, preferred_element_type=jnp.float32)
+    logits = dot(x_ref[...], w_ref[...].T)
     capped = _capped(logits, logit_softcap)
     col_ids = j * block_c + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     capped = jnp.where(col_ids >= c_actual, NEG_INF, capped)
 
     # The target's (capped) logit streams by exactly once — accumulate it.
     pos_scr[...] += jnp.sum(
-        jnp.where(col_ids == tgt_ref[...][:, None], capped, 0.0), axis=-1
+        jnp.where(col_ids == tgt_ref[...], capped, 0.0),
+        axis=-1, keepdims=True,
     )
 
     m_prev, s_prev = m_scr[...], s_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(capped, axis=-1))
+    m_new = jnp.maximum(m_prev, jnp.max(capped, axis=-1, keepdims=True))
     s_scr[...] = s_prev * jnp.exp(m_prev - m_new) + jnp.sum(
-        jnp.exp(capped - m_new[:, None]), axis=-1
+        jnp.exp(capped - m_new), axis=-1, keepdims=True
     )
     m_scr[...] = m_new
 
@@ -107,15 +108,13 @@ def _fwd_kernel(
 def _softmax_cotangent(x_ref, w_ref, tgt_ref, lse_ref, g_ref, jc, *, c_actual,
                        block_c, logit_softcap):
     """The shared backward tile: ``(p − onehot)·capᕁ·g`` in f32."""
-    logits = jnp.dot(x_ref[...], w_ref[...].T, preferred_element_type=jnp.float32)
+    logits = dot(x_ref[...], w_ref[...].T)
     capped = _capped(logits, logit_softcap)
     col_ids = jc * block_c + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    p = jnp.where(
-        col_ids >= c_actual, 0.0, jnp.exp(capped - lse_ref[...][:, None])
-    )
-    onehot = (col_ids == tgt_ref[...][:, None]).astype(jnp.float32)
+    p = jnp.where(col_ids >= c_actual, 0.0, jnp.exp(capped - lse_ref[...]))
+    onehot = (col_ids == tgt_ref[...]).astype(jnp.float32)
     gl = (p - onehot) * _cap_deriv(capped, logit_softcap)
-    return gl * g_ref[...][:, None].astype(jnp.float32)
+    return gl * g_ref[...].astype(jnp.float32)
 
 
 def _bwd_dx_kernel(
@@ -142,9 +141,7 @@ def _bwd_dx_kernel(
         x_ref, w_ref, tgt_ref, lse_ref, g_ref, j,
         c_actual=c_actual, block_c=block_c, logit_softcap=logit_softcap,
     )
-    acc_scr[...] += jnp.dot(
-        gw.astype(w_ref.dtype), w_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_scr[...] += dot(gw.astype(w_ref.dtype), w_ref[...])
 
     @pl.when(j == n_c_tiles - 1)
     def _finalize():
@@ -178,9 +175,7 @@ def _bwd_dw_kernel(
         x_ref, w_ref, tgt_ref, lse_ref, g_ref, jc,
         c_actual=c_actual, block_c=block_c, logit_softcap=logit_softcap,
     )
-    acc_scr[...] += jnp.dot(
-        gw.T.astype(x_ref.dtype), x_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_scr[...] += dot(gw.T.astype(x_ref.dtype), x_ref[...])
 
     @pl.when(i == n_n_tiles - 1)
     def _finalize():
@@ -192,10 +187,12 @@ def _prep(x, w, targets, block_n, block_c):
     c = w.shape[0]
     block_n = min(block_n, n)
     block_c = min(block_c, c)
-    xp = _pad_to(x, 0, block_n)
-    wp = _pad_to(w, 0, block_c)
+    xp = pad_to(x, 0, block_n)
+    wp = pad_to(w, 0, block_c)
     # Padded positions carry target -1: no column matches, pos stays 0.
-    tp = _pad_to(targets.astype(jnp.int32), 0, block_n, value=-1)
+    # Per-position vectors travel as (N, 1) columns so their
+    # (block_n, 1) blocks meet the TPU tiling rule.
+    tp = pad_to(targets.astype(jnp.int32)[:, None], 0, block_n, value=-1)
     return xp, wp, tp, block_n, block_c
 
 
@@ -213,34 +210,31 @@ def _fwd(x, w, targets, *, logit_softcap, block_n, block_c, interpret):
         ),
         grid=(n_n, n_c),
         in_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            _sds((n_p,), jnp.float32, xp, wp),
-            _sds((n_p,), jnp.float32, xp, wp),
+            sds((n_p, 1), jnp.float32, tp, xp, wp),
+            sds((n_p, 1), jnp.float32, tp, xp, wp),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_n,), jnp.float32),
-            pltpu.VMEM((block_n,), jnp.float32),
-            pltpu.VMEM((block_n,), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((block_n, 1), jnp.float32)] * 3,
         interpret=interpret,
+        name="linear_ce_fwd",
     )(tp, xp, wp)
-    return loss[:n], lse[:n]
+    return loss[:n, 0], lse[:n, 0]
 
 
 def _bwd(x, w, targets, lse, g, *, logit_softcap, block_n, block_c, interpret):
     n, d = x.shape
     c = w.shape[0]
     xp, wp, tp, block_n, block_c = _prep(x, w, targets, block_n, block_c)
-    lp = _pad_to(lse, 0, block_n)
-    gp = _pad_to(g.astype(jnp.float32), 0, block_n)  # zero cotangent on pad
+    lp = pad_to(lse[:, None], 0, block_n)
+    gp = pad_to(g.astype(jnp.float32)[:, None], 0, block_n)  # 0 on pad
     n_p, c_p = xp.shape[0], wp.shape[0]
     n_n, n_c = n_p // block_n, c_p // block_c
 
@@ -251,16 +245,17 @@ def _bwd(x, w, targets, lse, g, *, logit_softcap, block_n, block_c, interpret):
         ),
         grid=(n_n, n_c),
         in_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-        out_shape=_sds((n_p, d), x.dtype, xp, wp, lp, gp),
+        out_shape=sds((n_p, d), x.dtype, xp, wp, lp, gp),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         interpret=interpret,
+        name="linear_ce_bwd_dx",
     )(tp, lp, gp, xp, wp)
 
     dw = pl.pallas_call(
@@ -270,16 +265,17 @@ def _bwd(x, w, targets, lse, g, *, logit_softcap, block_n, block_c, interpret):
         ),
         grid=(n_c, n_n),
         in_specs=[
-            pl.BlockSpec((block_n,), lambda j, i: (i,)),
-            pl.BlockSpec((block_n,), lambda j, i: (i,)),
-            pl.BlockSpec((block_n,), lambda j, i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda j, i: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda j, i: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda j, i: (i, 0)),
             pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
             pl.BlockSpec((block_c, d), lambda j, i: (j, 0)),
         ],
         out_specs=pl.BlockSpec((block_c, d), lambda j, i: (j, 0)),
-        out_shape=_sds((c_p, d), w.dtype, xp, wp, lp, gp),
+        out_shape=sds((c_p, d), w.dtype, xp, wp, lp, gp),
         scratch_shapes=[pltpu.VMEM((block_c, d), jnp.float32)],
         interpret=interpret,
+        name="linear_ce_bwd_dw",
     )(tp, lp, gp, xp, wp)
 
     return dx[:n], dw[:c]
@@ -326,7 +322,7 @@ def _vjp_bwd(logit_softcap, block_n, block_c, interpret, res, g):
         logit_softcap=logit_softcap, block_n=block_n, block_c=block_c,
         interpret=interpret,
     )
-    return dx, dw, None
+    return psum_to(dx, x), psum_to(dw, w), None
 
 
 linear_ce_loss.defvjp(_vjp_fwd, _vjp_bwd)

@@ -39,7 +39,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk_merge import merge_topk_tile, merge_topk_tile_bitonic
+from repro.kernels.common import dot, pad_to, sds
+from repro.kernels.topk_merge import (
+    ID_PAD,
+    merge_topk_tile,
+    merge_topk_tile_bitonic,
+)
 
 NEG_INF = -1e30
 
@@ -52,7 +57,7 @@ _MERGE_IMPLS = {
 def _mips_kernel(
     q_ref,  # (block_q, d)
     y_ref,  # (block_c, d)
-    valid_ref,  # (block_c,) i32 — 1 on selectable rows
+    valid_ref,  # (1, block_c) i32 — 1 on selectable rows, 0 on the tail
     vals_ref,  # (block_q, k) f32 out
     ids_ref,  # (block_q, k) i32 out
     vals_scr,  # (block_q, k) f32
@@ -61,8 +66,6 @@ def _mips_kernel(
     k: int,
     n_c_tiles: int,
     block_c: int,
-    c_actual: int,
-    id_offset: int,
     merge_impl: str,
 ):
     j = pl.program_id(1)
@@ -74,34 +77,22 @@ def _mips_kernel(
             ids_scr, jnp.iinfo(jnp.int32).max
         )
 
-    scores = jnp.dot(
-        q_ref[...], y_ref[...].T, preferred_element_type=jnp.float32
-    )
+    scores = dot(q_ref[...], y_ref[...].T)
     idx = j * block_c + jax.lax.broadcasted_iota(
         jnp.int32, scores.shape, 1
     )
-    # Mask padded-tail columns (idx ≥ C) and caller-invalidated rows
-    # (padding positions on the X side).
-    ok = jnp.logical_and(idx < c_actual, valid_ref[...][None, :] > 0)
-    s = jnp.where(ok, scores, NEG_INF)
+    # Mask caller-invalidated rows (padding positions on the X side) and
+    # the padded tail, whose validity pads with 0.
+    s = jnp.where(valid_ref[...] > 0, scores, NEG_INF)
 
     vals_scr[...], ids_scr[...] = _MERGE_IMPLS[merge_impl](
-        vals_scr[...], ids_scr[...], s, id_offset + idx, k
+        vals_scr[...], ids_scr[...], s, idx, k
     )
 
     @pl.when(j == n_c_tiles - 1)
     def _finalize():
         vals_ref[...] = vals_scr[...]
         ids_ref[...] = ids_scr[...]
-
-
-def _pad_to(arr, axis, multiple, value=0):
-    pad = (-arr.shape[axis]) % multiple
-    if pad == 0:
-        return arr
-    widths = [(0, 0)] * arr.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(arr, widths, constant_values=value)
 
 
 def mips_topk(
@@ -131,7 +122,10 @@ def mips_topk(
         (the X-side ``valid_mask``).
     block_q, block_c : VMEM tile sizes; peak live score elements are
         ``n_q·(block_c + 2k)`` instead of ``n_q·C``.
-    id_offset : global id of ``y``'s first row (for catalog shards).
+    id_offset : global id of ``y``'s first row (for catalog shards); may
+        be traced. The kernel selects local ids and the offset is added
+        to every real id afterwards — a constant shift, so the tie order
+        (lower id wins) is unchanged.
     merge_impl : ``"rounds"`` (default — the shared K-round
         first-occurrence-argmax) or ``"bitonic"`` (the prototype
         partial sort for selection-sized ``K = b_y``; identical
@@ -152,9 +146,11 @@ def mips_topk(
 
     if valid is None:
         valid = jnp.ones((c,), jnp.int32)
-    qp = _pad_to(q, 0, block_q)
-    yp = _pad_to(y, 0, block_c)
-    vp = _pad_to(valid.astype(jnp.int32), 0, block_c)
+    qp = pad_to(q, 0, block_q)
+    yp = pad_to(y, 0, block_c)
+    # The validity vector rides as a (1, C) lane-major row so its
+    # (1, block_c) blocks meet the TPU (8, 128) tiling rule.
+    vp = pad_to(valid.astype(jnp.int32).reshape(1, c), 1, block_c)
     nq_p, c_p = qp.shape[0], yp.shape[0]
     n_i, n_j = nq_p // block_q, c_p // block_c
 
@@ -163,8 +159,6 @@ def mips_topk(
         k=k,
         n_c_tiles=n_j,
         block_c=block_c,
-        c_actual=c,
-        id_offset=id_offset,
         merge_impl=merge_impl,
     )
     vals, ids = pl.pallas_call(
@@ -173,20 +167,22 @@ def mips_topk(
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_c,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
             pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq_p, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq_p, k), jnp.int32),
+            sds((nq_p, k), jnp.float32, qp, yp, vp),
+            sds((nq_p, k), jnp.int32, qp, yp, vp),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, k), jnp.float32),
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
         interpret=interpret,
+        name="mips_topk",
     )(qp, yp, vp)
-    return vals[:n_q], ids[:n_q]
+    ids = ids[:n_q]
+    return vals[:n_q], jnp.where(ids == ID_PAD, ids, ids + id_offset)

@@ -11,10 +11,14 @@ Every production dispatch here is guarded (``kernels/guard``, policy
     legality + VMEM preflight with auto-repair, or a structured
     ``KernelPreflightError`` instead of a deep Mosaic failure;
   * the kernel branch consults ``guard.kernel_enabled`` — the memoized
-    per-(backend, kernel) conformance-canary verdict; a kernel that
-    fails its canaries on this backend DEGRADES to the chunked
-    ``ref.py`` path with a loud warning instead of crashing or
-    silently miscomputing.
+    per-(backend, kernel) conformance-canary verdict. On TPU a failed
+    verdict raises ``KernelConformanceError``; elsewhere (interpret
+    mode) a failing kernel DEGRADES to the chunked ``ref.py`` path with
+    a loud warning under policy ``warn``.
+
+A traced ``id_offset`` (``axis_index * C_local`` inside ``shard_map``)
+runs the kernel like a static one: the kernels select local ids and
+shift the real ones by the offset afterwards.
 
 ``REPRO_FORCE_INTERPRET=1`` forces interpret mode on any backend (the
 kernel-body debugging escape hatch).
@@ -180,9 +184,9 @@ def mips_topk(
 ):
     """Streaming per-row MIPS top-k of ``q @ yᵀ`` →
     ``(vals (n_q, k), ids (n_q, k))`` without the ``(n_q, C)`` score
-    matrix. See kernels/mips_topk.py; inside ``shard_map`` (or with a
-    traced ``id_offset``) the chunked pure-jnp reference runs instead —
-    same outputs and ``lax.top_k`` tie rule. ``merge_impl`` selects the
+    matrix. See kernels/mips_topk.py; inside ``shard_map`` in interpret
+    mode the chunked pure-jnp reference runs instead — same outputs and
+    ``lax.top_k`` tie rule. ``merge_impl`` selects the
     per-tile merge: ``"rounds"`` (default, the K-round
     first-occurrence-argmax) or ``"bitonic"`` (the prototype partial
     sort for selection-sized ``K = b_y`` — see
@@ -190,8 +194,7 @@ def mips_topk(
     no default flip)."""
     if interpret is None:
         interpret = _interpret_default()
-    traced_offset = not isinstance(id_offset, int)
-    if traced_offset or (interpret and _inside_shard_map(q, y)):
+    if interpret and _inside_shard_map(q, y):
         return _ref.mips_topk_ref(
             q, y, k, valid=valid, chunk=block_c, id_offset=id_offset
         )
@@ -396,9 +399,9 @@ def eval_fused(
     ``eval_tgt_scores`` → ``eval_topk`` chain — bit-identical ranks,
     ids, tie order and target scores at half the catalog FLOPs/traffic
     (a third, for the LM path, whose separate NLL sweep the LSE carry
-    absorbs). See kernels/eval_fused.py; inside ``shard_map`` (or with
-    a traced ``id_offset``) the chunked pure-jnp reference runs
-    instead — same outputs and tie rule. Sharded callers precompute
+    absorbs). See kernels/eval_fused.py; inside ``shard_map`` in
+    interpret mode the chunked pure-jnp reference runs instead — same
+    outputs and tie rule. Sharded callers precompute
     the threshold (``psum`` of per-shard :func:`eval_tgt_gather`) and
     pass it via ``tgt_scores``."""
     if interpret is None:
@@ -412,8 +415,7 @@ def eval_fused(
             with_lse=with_lse,
         )
 
-    traced_offset = not isinstance(id_offset, int)
-    if traced_offset or (interpret and _inside_shard_map(x, y)):
+    if interpret and _inside_shard_map(x, y):
         return _ref_path()
     block_b, block_c = _guard.checked_blocks(
         "eval_fused", rows=x.shape[0], cols=y.shape[0], d=x.shape[-1],
@@ -449,8 +451,7 @@ def eval_tgt_gather(
     exact value. Call with the SAME ``block_c`` as the sweep."""
     if interpret is None:
         interpret = _interpret_default()
-    traced_offset = not isinstance(id_offset, int)
-    if traced_offset or (interpret and _inside_shard_map(x, y)):
+    if interpret and _inside_shard_map(x, y):
         return _ref.eval_tgt_gather_ref(
             x, y, targets, chunk=block_c, id_offset=id_offset
         )

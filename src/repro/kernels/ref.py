@@ -9,6 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import vary_as
+
 NEG_INF = -1e30
 
 
@@ -121,9 +123,8 @@ def mips_topk_ref(
         i = jnp.take_along_axis(cat_i, sel, axis=-1)
         return (v, i), None
 
-    (vals, ids), _ = jax.lax.scan(
-        body, (vals0, ids0), jnp.arange(n_chunks)
-    )
+    carry0 = vary_as((vals0, ids0), q, y, valid, id_offset)
+    (vals, ids), _ = jax.lax.scan(body, carry0, jnp.arange(n_chunks))
     return vals, ids
 
 
@@ -189,9 +190,8 @@ def eval_topk_ref(
         i = jnp.take_along_axis(cat_i, sel, axis=-1)
         return (v, i, gt, eq), None
 
-    (vals, ids, gt, eq), _ = jax.lax.scan(
-        body, (vals0, ids0, cnt0, cnt0), jnp.arange(n_chunks)
-    )
+    carry0 = vary_as((vals0, ids0, cnt0, cnt0), x, y, tgt_scores, id_offset)
+    (vals, ids, gt, eq), _ = jax.lax.scan(body, carry0, jnp.arange(n_chunks))
     return vals, ids, gt, eq
 
 
@@ -225,9 +225,8 @@ def eval_tgt_scores_ref(
         hit = jnp.broadcast_to(col[None, :], s.shape) == tid
         return acc + jnp.sum(jnp.where(hit, s, 0.0), axis=-1), None
 
-    acc, _ = jax.lax.scan(
-        body, jnp.zeros((b,), f32), jnp.arange(n_chunks)
-    )
+    acc0 = vary_as(jnp.zeros((b,), f32), x, y, targets, id_offset)
+    acc, _ = jax.lax.scan(body, acc0, jnp.arange(n_chunks))
     return acc
 
 
@@ -379,6 +378,7 @@ def eval_fused_ref(
         )
         return (v, i, gt, eq, m_new, se), None
 
+    carry0 = vary_as(carry0, x, y, targets, tgt_scores, id_offset)
     carry, _ = jax.lax.scan(body, carry0, jnp.arange(n_chunks))
     if with_lse:
         vals, ids, gt, eq, m, se = carry
@@ -436,11 +436,12 @@ def linear_ce_loss_ref(
         )
         return (m_new, s, pos), None
 
-    (m, s, pos), _ = jax.lax.scan(
-        body,
-        (jnp.full((n,), NEG_INF, f32), jnp.zeros((n,), f32), jnp.zeros((n,), f32)),
-        jnp.arange(n_chunks),
+    carry0 = vary_as(
+        (jnp.full((n,), NEG_INF, f32), jnp.zeros((n,), f32),
+         jnp.zeros((n,), f32)),
+        x, w, targets,
     )
+    (m, s, pos), _ = jax.lax.scan(body, carry0, jnp.arange(n_chunks))
     return (m + jnp.log(s) - pos).astype(x.dtype)
 
 

@@ -17,7 +17,8 @@ innermost/sequential on TPU, so the VMEM scratch accumulators carry across
 ``b_y`` tiles. Backward = two streaming kernels (one per operand) that
 recompute tile logits from the saved per-position logsumexp.
 
-All matmuls run on the MXU via ``jnp.dot(..., preferred_element_type=f32)``;
+All matmuls run on the MXU via ``common.dot`` (float32 result, full
+float32 precision for float32 operands);
 block sizes default to multiples of 128 (MXU lane alignment).
 """
 from __future__ import annotations
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import dot, pad_to, psum_to, sds
 from repro.kernels.linear_sce import _cap_deriv, _capped
 
 NEG_INF = -1e30
@@ -64,7 +66,7 @@ def _fwd_kernel(
 
     x = x_ref[0]
     y = y_ref[0]
-    logits = jnp.dot(x, y.T, preferred_element_type=jnp.float32)
+    logits = dot(x, y.T)
     # Softcap INSIDE the tile, before the invalid mask (CE is not
     # cap-invariant); the folded positive arrives pre-capped.
     logits = _capped(logits, logit_softcap)
@@ -128,7 +130,7 @@ def _fwd_plse_kernel(
 
     x = x_ref[0]
     y = y_ref[0]
-    logits = jnp.dot(x, y.T, preferred_element_type=jnp.float32)
+    logits = dot(x, y.T)
     logits = _capped(logits, logit_softcap)
     col_ids = j * block_by + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1
@@ -180,7 +182,7 @@ def _bwd_dx_kernel(
 
     x = x_ref[0]
     y = y_ref[0]
-    logits = jnp.dot(x, y.T, preferred_element_type=jnp.float32)
+    logits = dot(x, y.T)
     capped = _capped(logits, logit_softcap)
     col_ids = j * block_by + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1
@@ -193,9 +195,7 @@ def _bwd_dx_kernel(
     p = jnp.where(invalid, 0.0, jnp.exp(capped - lse_ref[0][:, None]))
     gw = p * _cap_deriv(capped, logit_softcap)  # dL/dlogit tile
     gw = gw * g_ref[0][:, None].astype(jnp.float32)
-    acc_scr[...] += jnp.dot(
-        gw.astype(y.dtype), y, preferred_element_type=jnp.float32
-    )
+    acc_scr[...] += dot(gw.astype(y.dtype), y)
 
     @pl.when(j == n_by_tiles - 1)
     def _finalize():
@@ -232,7 +232,7 @@ def _bwd_dy_kernel(
 
     x = x_ref[0]
     y = y_ref[0]
-    logits = jnp.dot(x, y.T, preferred_element_type=jnp.float32)
+    logits = dot(x, y.T)
     capped = _capped(logits, logit_softcap)
     col_ids = jy * block_by + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1
@@ -245,9 +245,7 @@ def _bwd_dy_kernel(
     p = jnp.where(invalid, 0.0, jnp.exp(capped - lse_ref[0][:, None]))
     gw = p * _cap_deriv(capped, logit_softcap)
     gw = gw * g_ref[0][:, None].astype(jnp.float32)
-    acc_scr[...] += jnp.dot(
-        gw.T.astype(x.dtype), x, preferred_element_type=jnp.float32
-    )
+    acc_scr[...] += dot(gw.T.astype(x.dtype), x)
 
     @pl.when(ix == n_bx_tiles - 1)
     def _finalize():
@@ -257,30 +255,6 @@ def _bwd_dy_kernel(
 # ---------------------------------------------------------------------------
 # pallas_call wrappers
 # ---------------------------------------------------------------------------
-def _pad_to(arr, axis, multiple, value=0):
-    size = arr.shape[axis]
-    pad = (-size) % multiple
-    if pad == 0:
-        return arr
-    widths = [(0, 0)] * arr.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(arr, widths, constant_values=value)
-
-
-def _sds(shape, dtype, *operands):
-    """ShapeDtypeStruct whose ``vma`` (varying-manual-axes) is the union of
-    the operands' — required for pallas_call under ``jax.shard_map``."""
-    vma = frozenset()
-    for op in operands:
-        try:
-            vma = vma | jax.typeof(op).vma
-        except (AttributeError, TypeError):
-            pass
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
 def _fwd(x_b, y_b, tgt_b, cand_ids, pos_logit, *, block_bx, block_by,
          interpret, logit_softcap=None):
     n_b, b_x, d = x_b.shape
@@ -288,12 +262,12 @@ def _fwd(x_b, y_b, tgt_b, cand_ids, pos_logit, *, block_bx, block_by,
     block_bx = min(block_bx, b_x)
     block_by = min(block_by, b_y)
 
-    xp = _pad_to(x_b, 1, block_bx)
-    yp = _pad_to(y_b, 1, block_by)
+    xp = pad_to(x_b, 1, block_bx)
+    yp = pad_to(y_b, 1, block_by)
     # Padded targets = -2 and padded candidates = -1 never collide.
-    tp = _pad_to(tgt_b, 1, block_bx, value=-2)
-    cp = _pad_to(cand_ids, 1, block_by, value=-1)
-    pp = _pad_to(pos_logit, 1, block_bx)
+    tp = pad_to(tgt_b, 1, block_bx, value=-2)
+    cp = pad_to(cand_ids, 1, block_by, value=-1)
+    pp = pad_to(pos_logit, 1, block_bx)
     bx_p, by_p = xp.shape[1], yp.shape[1]
     n_bx, n_by = bx_p // block_bx, by_p // block_by
 
@@ -316,8 +290,8 @@ def _fwd(x_b, y_b, tgt_b, cand_ids, pos_logit, *, block_bx, block_by,
             pl.BlockSpec((1, block_bx), lambda b, i, j: (b, i)),
         ],
         out_shape=[
-            _sds((n_b, bx_p), pos_logit.dtype, xp, yp, tp, cp, pp),
-            _sds((n_b, bx_p), jnp.float32, xp, yp, tp, cp, pp),
+            sds((n_b, bx_p), pos_logit.dtype, xp, yp, tp, cp, pp),
+            sds((n_b, bx_p), jnp.float32, xp, yp, tp, cp, pp),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_bx,), jnp.float32),
@@ -335,12 +309,12 @@ def _bwd(x_b, y_b, tgt_b, cand_ids, lse, g, *, block_bx, block_by,
     block_bx = min(block_bx, b_x)
     block_by = min(block_by, b_y)
 
-    xp = _pad_to(x_b, 1, block_bx)
-    yp = _pad_to(y_b, 1, block_by)
-    tp = _pad_to(tgt_b, 1, block_bx, value=-2)
-    cp = _pad_to(cand_ids, 1, block_by, value=-1)
-    lp = _pad_to(lse, 1, block_bx)
-    gp = _pad_to(g, 1, block_bx)  # zero cotangent on padded rows
+    xp = pad_to(x_b, 1, block_bx)
+    yp = pad_to(y_b, 1, block_by)
+    tp = pad_to(tgt_b, 1, block_bx, value=-2)
+    cp = pad_to(cand_ids, 1, block_by, value=-1)
+    lp = pad_to(lse, 1, block_bx)
+    gp = pad_to(g, 1, block_bx)  # zero cotangent on padded rows
     bx_p, by_p = xp.shape[1], yp.shape[1]
     n_bx, n_by = bx_p // block_bx, by_p // block_by
 
@@ -359,7 +333,7 @@ def _bwd(x_b, y_b, tgt_b, cand_ids, lse, g, *, block_bx, block_by,
             pl.BlockSpec((1, block_by, d), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_bx, d), lambda b, i, j: (b, i, 0)),
-        out_shape=_sds((n_b, bx_p, d), x_b.dtype, xp, yp, tp, cp, lp, gp),
+        out_shape=sds((n_b, bx_p, d), x_b.dtype, xp, yp, tp, cp, lp, gp),
         scratch_shapes=[pltpu.VMEM((block_bx, d), jnp.float32)],
         interpret=interpret,
     )(tp, cp, lp, gp, xp, yp)
@@ -379,7 +353,7 @@ def _bwd(x_b, y_b, tgt_b, cand_ids, lse, g, *, block_bx, block_by,
             pl.BlockSpec((1, block_by, d), lambda b, j, i: (b, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_by, d), lambda b, j, i: (b, j, 0)),
-        out_shape=_sds((n_b, by_p, d), y_b.dtype, xp, yp, tp, cp, lp, gp),
+        out_shape=sds((n_b, by_p, d), y_b.dtype, xp, yp, tp, cp, lp, gp),
         scratch_shapes=[pltpu.VMEM((block_by, d), jnp.float32)],
         interpret=interpret,
     )(tp, cp, lp, gp, xp, yp)
@@ -437,7 +411,8 @@ def _vjp_bwd(block_bx, block_by, interpret, logit_softcap, res, g):
     # d loss / d pos = (softmax prob of the positive) - 1, times upstream g.
     p_pos = jnp.exp(pos_logit.astype(jnp.float32) - lse)
     d_pos = ((p_pos - 1.0) * g.astype(jnp.float32)).astype(pos_logit.dtype)
-    return dx, dy, None, None, d_pos
+    return (psum_to(dx, x_b), psum_to(dy, y_b), None, None,
+            psum_to(d_pos, pos_logit))
 
 
 sce_bucket_loss.defvjp(_vjp_fwd, _vjp_bwd)
@@ -454,10 +429,10 @@ def _fwd_plse(x_b, y_b, tgt_b, cand_ids, *, block_bx, block_by, interpret,
     b_y = y_b.shape[1]
     block_bx = min(block_bx, b_x)
     block_by = min(block_by, b_y)
-    xp = _pad_to(x_b, 1, block_bx)
-    yp = _pad_to(y_b, 1, block_by)
-    tp = _pad_to(tgt_b, 1, block_bx, value=-2)
-    cp = _pad_to(cand_ids, 1, block_by, value=-1)
+    xp = pad_to(x_b, 1, block_bx)
+    yp = pad_to(y_b, 1, block_by)
+    tp = pad_to(tgt_b, 1, block_bx, value=-2)
+    cp = pad_to(cand_ids, 1, block_by, value=-1)
     bx_p, by_p = xp.shape[1], yp.shape[1]
     n_bx, n_by = bx_p // block_bx, by_p // block_by
 
@@ -474,7 +449,7 @@ def _fwd_plse(x_b, y_b, tgt_b, cand_ids, *, block_bx, block_by, interpret,
             pl.BlockSpec((1, block_by, d), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_bx), lambda b, i, j: (b, i)),
-        out_shape=_sds((n_b, bx_p), jnp.float32, xp, yp, tp, cp),
+        out_shape=sds((n_b, bx_p), jnp.float32, xp, yp, tp, cp),
         scratch_shapes=[
             pltpu.VMEM((block_bx,), jnp.float32),
             pltpu.VMEM((block_bx,), jnp.float32),
@@ -523,7 +498,7 @@ def _plse_vjp_bwd(block_bx, block_by, interpret, logit_softcap, res, g):
         block_bx=block_bx, block_by=block_by, interpret=interpret,
         logit_softcap=logit_softcap,
     )
-    return dx, dy, None, None
+    return psum_to(dx, x_b), psum_to(dy, y_b), None, None
 
 
 sce_bucket_plse.defvjp(_plse_vjp_fwd, _plse_vjp_bwd)

@@ -5,7 +5,7 @@ single-pass evaluation scorer), ``kernels/mips_topk.py`` (SCE candidate
 selection) and the deprecated ``kernels/eval_topk.py`` oracle —
 carries a ``(rows, K)`` running buffer across catalog tiles and merges
 each tile's scores into it. Mosaic has no general sort, so the merge is ``K``
-unrolled rounds of *first-occurrence argmax* built from
+rounds (a rolled loop) of *first-occurrence argmax* built from
 max/min/where/iota only: find the row max over the ``(K + tile)``-wide
 concatenation of buffer and tile, locate its earliest position, emit
 ``(val, id)``, knock the position out with ``NEG_INF``, repeat.
@@ -24,10 +24,9 @@ so far) emit the ``ID_PAD`` placeholder instead of a duplicate real id,
 matching what ``lax.top_k`` leaves in the id-padded buffer slots.
 
 Cost note: the merge is ``O(K·(K + tile))`` VPU work per tile per row
-block and unrolls ``K`` rounds into the program — cheap for eval-sized
-``K`` (≤ ~100), noticeable program growth for selection-sized
-``K = b_y`` (256+). The matmul producing the tile still dominates on
-TPU for ``d ≳ K``; :func:`merge_topk_tile_bitonic` is the
+block — cheap for eval-sized ``K`` (≤ ~100), a VPU cost that can
+exceed the tile's matmul for selection-sized ``K = b_y`` (256+);
+:func:`merge_topk_tile_bitonic` is the
 output-identical ``O(log²)`` partial-sort prototype for that regime
 (gated behind ``mips_topk(merge_impl="bitonic")``, no default flip
 pending a real-TPU profile).
@@ -66,19 +65,30 @@ def merge_topk_tile(vals, ids, tile_vals, tile_ids, k: int):
     cat_i = jnp.concatenate([ids, tile_ids], axis=-1)
     width = k + tile_vals.shape[-1]
     pos = jax.lax.broadcasted_iota(jnp.int32, cat_v.shape, 1)
-    new_v, new_i = [], []
-    for _ in range(k):
+    slot = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+
+    # Every per-row quantity stays a (rows, 1) column (keepdims) and each
+    # round lands in its output slot by a lane-iota select: Mosaic cannot
+    # reshape a 1-D row vector into a column, so no stack/reshape occurs.
+    # A rolled loop keeps the program size independent of k.
+    def round_(r, carry):
+        cat_v, out_v, out_i = carry
         m = jnp.max(cat_v, axis=-1, keepdims=True)
         first = jnp.min(
             jnp.where(cat_v == m, pos, width), axis=-1, keepdims=True
         )
         sel = pos == first
-        sel_id = jnp.sum(jnp.where(sel, cat_i, 0), axis=-1)
-        exhausted = m[:, 0] == NEG_INF
-        new_v.append(jnp.max(jnp.where(sel, cat_v, NEG_INF), axis=-1))
-        new_i.append(jnp.where(exhausted, ID_PAD, sel_id))
-        cat_v = jnp.where(sel, NEG_INF, cat_v)
-    return jnp.stack(new_v, axis=-1), jnp.stack(new_i, axis=-1)
+        sel_id = jnp.sum(jnp.where(sel, cat_i, 0), axis=-1, keepdims=True)
+        sel_id = jnp.where(m == NEG_INF, ID_PAD, sel_id)
+        out_v = jnp.where(slot == r, m, out_v)
+        out_i = jnp.where(slot == r, sel_id, out_i)
+        return jnp.where(sel, NEG_INF, cat_v), out_v, out_i
+
+    _, out_v, out_i = jax.lax.fori_loop(
+        0, k, round_,
+        (cat_v, jnp.full_like(vals, NEG_INF), jnp.full_like(ids, ID_PAD)),
+    )
+    return out_v, out_i
 
 
 def _precedes(va, ia, vb, ib):
@@ -95,15 +105,15 @@ def merge_topk_tile_bitonic(vals, ids, tile_vals, tile_ids, k: int):
     identical outputs (values, ids, tie order, ``ID_PAD`` exhausted
     slots), different cost shape.
 
-    The K-round merge unrolls ``K`` first-occurrence-argmax rounds of
-    ``O(K + tile)`` VPU work — ``O(K·(K + tile))`` per tile and ``K``
-    rounds of program text, which is the named scaling concern at
+    The K-round merge runs ``K`` first-occurrence-argmax rounds of
+    ``O(K + tile)`` VPU work — ``O(K·(K + tile))`` per tile, the named
+    scaling concern at
     selection-sized ``K = b_y`` (KERNELS.md §mips_topk). This variant
     instead bitonic-sorts the ``(K + tile)``-wide concatenation on the
     composite key (value desc, id asc) and keeps the first ``K``
     lanes: ``O(log² W)`` compare-exchange stages of ``O(W)`` work each
     (``W`` = ``K + tile`` padded to a power of two) — ~55 stages at
-    ``K = 256, tile = 512`` vs 256 unrolled rounds. Built from
+    ``K = 256, tile = 512`` vs 256 rounds. Built from
     reshape/flip partner exchanges + max/min/where/iota only (no
     general sort, no gathers — see the closing paragraph), so it
     stays Mosaic-expressible; it is a PROTOTYPE gated behind

@@ -18,7 +18,6 @@ from jax.sharding import Mesh
 
 from repro.configs import get_arch
 from repro.configs.common import ArchSpec, ShapeSpec
-from repro.dist import set_mesh
 from repro.dist.sharding import (
     batch_spec,
     lm_logits_spec,
@@ -62,7 +61,7 @@ class Cell:
             out_shardings=self.out_shardings,
             donate_argnums=self.donate_argnums,
         )
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return jitted.lower(*self.args)
 
 

@@ -10,14 +10,22 @@ slice of gradient reductions (DCI), everything bandwidth-hungry stays on
 the in-pod ICI axes. The same axis names scale to 1000+ nodes by growing
 ``pod`` — no code changes, only the mesh shape.
 
-Axis names and construction live in ``repro.dist`` (compat-bridged
-``make_mesh``); this module only chooses shapes.
+Axis names live in ``repro.dist``; this module only chooses shapes.
+Every axis is ``AxisType.Auto``: layouts are stated by the specs in
+``repro.dist.sharding`` and partitioned by GSPMD or ``shard_map``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.dist import make_mesh
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

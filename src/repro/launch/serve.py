@@ -43,7 +43,8 @@ serve.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch sasrec-sce \
-      --requests 64 --buckets 8,32 [--ckpt-dir results/ckpt]
+      --requests 64 --buckets 8,32 [--ckpt-dir results/ckpt] \
+      [--shape serve_p99]   # the full config instead of the smoke one
 """
 from __future__ import annotations
 
@@ -64,9 +65,9 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch
 from repro.data import Cursor, SeqDataConfig, SequenceDataset
-from repro.dist import set_mesh
 from repro.dist.sharding import batch_spec, seqrec_serve_shardings
 from repro.launch import steps as steps_lib
+from repro.launch.cache import enable_compile_cache
 from repro.launch.train import _init_params
 
 
@@ -238,6 +239,8 @@ class RetrievalServer:
         random-init smoke path.
     mesh : optional ``Mesh`` — catalog on ``"model"``, requests on the
         data axes. ``None`` = single device.
+    shape : optional registered ShapeSpec name: serve the arch's full
+        config (``make_config``) instead of the smoke config.
     defer_readiness : skip the constructor's conformance readiness gate
         (``refresh_readiness``) — async submits then raise
         ``ServerNotReadyError`` until the gate is run and passes. Used
@@ -251,10 +254,15 @@ class RetrievalServer:
                  deadline_s: Optional[float] = None,
                  ckpt_dir: Optional[str] = None, mesh=None,
                  seed: int = 0, block_c: int = 512,
-                 defer_readiness: bool = False):
+                 defer_readiness: bool = False,
+                 shape: Optional[str] = None):
         self.arch = get_arch(arch_name)
         assert self.arch.family == "seqrec", "serve.py serves seqrec archs"
-        self.cfg = self.arch.make_smoke_config()
+        if shape is None:
+            self.cfg = self.arch.make_smoke_config()
+        else:
+            self.arch.shape(shape)  # KeyError on an unregistered name
+            self.cfg = self.arch.make_config(shape)
         self.mesh = mesh
         self.router = BucketRouter(buckets)
         self.top_k = int(top_k)
@@ -312,7 +320,7 @@ class RetrievalServer:
 
     # -- params / compilation ---------------------------------------------
     def _ctx(self):
-        return set_mesh(self.mesh) if self.mesh is not None else (
+        return jax.set_mesh(self.mesh) if self.mesh is not None else (
             contextlib.nullcontext()
         )
 
@@ -568,7 +576,12 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None,
                     help="checkpoint/manager.py directory; omit for "
                          "random-init smoke params")
+    ap.add_argument("--shape",
+                    help="registered ShapeSpec name (e.g. serve_p99): "
+                         "serve the arch's full config instead of the "
+                         "smoke config")
     args = ap.parse_args()
+    enable_compile_cache()
 
     buckets = tuple(int(b) for b in args.buckets.split(","))
     server = RetrievalServer(
@@ -577,6 +590,7 @@ def main() -> None:
         deadline_s=(args.deadline_ms / 1e3
                     if args.deadline_ms is not None else None),
         ckpt_dir=args.ckpt_dir,
+        shape=args.shape,
     )
     health = server.health()
     n_canary = sum(v["n_pass"] + v["n_fail"] for v in health["conformance"])

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from repro.core.distributed_sce import round_up, sce_loss_sharded
 from repro.core.losses import ce_chunked, make_loss
 from repro.core.sce import SCEConfig, sce_loss
-from repro.dist import shard_map
 from repro.dist.collectives import distributed_topk, distributed_topk_from_local
 from repro.dist.sharding import batch_spec, catalog_spec, replicated_spec
 from repro.eval.streaming import streaming_topk
@@ -471,7 +470,7 @@ def make_seqrec_mips_serve_step(arch, cfg, mesh, *, top_k: int = 10,
                 vals_l, gids_l, top_k, "model"
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(batch_spec(mesh, 2), catalog_spec(mesh)),
@@ -532,7 +531,7 @@ def make_seqrec_serve_step(arch, cfg, mesh, *, top_k: int = 100,
             idx = idx.reshape(-1, top_k)[:b_l]
             return vals, idx
 
-        fn = shard_map(
+        fn = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(batch_spec(mesh, 2), catalog_spec(mesh)),
@@ -577,7 +576,7 @@ def make_seqrec_retrieval_step(arch, cfg, mesh, *, top_k: int = 100):
             vals, idx = jax.lax.top_k(scores, top_k)
             return vals, idx
 
-        fn = shard_map(
+        fn = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(

@@ -41,12 +41,17 @@ arrays. Production behavior:
     (step/loss/skipped/grad_norm) — the kill-drills diff these curves
     step-for-step across kill/restore boundaries.
 
-On this CPU container, ``--smoke`` selects each arch's reduced config so
-the loop actually trains; the full configs are exercised via dryrun.py.
+By default each arch's reduced smoke config trains (CPU-runnable);
+``--shape <ShapeSpec name>`` selects the arch's full config
+(``make_config``) at that registered shape's dims instead — e.g.
+sasrec-sce ``train_paper``: batch 128 × 200 over the 173,511-item
+Gowalla catalog.
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.train --arch sasrec-sce --smoke \
+  PYTHONPATH=src python -m repro.launch.train --arch sasrec-sce \
       --steps 50 --ckpt-dir /tmp/ckpt
+  PYTHONPATH=src python -m repro.launch.train --arch sasrec-sce \
+      --shape train_paper --steps 5
 """
 from __future__ import annotations
 
@@ -61,10 +66,10 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch
-from repro.dist import set_mesh
 from repro.data import (
     ClickDataConfig,
     ClickstreamDataset,
@@ -74,6 +79,7 @@ from repro.data import (
     ShardedCursor,
     batched_molecules,
 )
+from repro.dist.sharding import batch_spec, replicated_sharding
 from repro.kernels import guard as kguard
 from repro.launch import steps as steps_lib
 from repro.launch.elastic import (
@@ -82,6 +88,7 @@ from repro.launch.elastic import (
     PreemptionHandler,
     TrainState,
 )
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
@@ -94,34 +101,46 @@ class SmokeShape:
     dims: Dict[str, int]
 
 
-def _smoke_setup(arch, batch: int, seq_len: int):
-    """(model cfg, shape, data source) for a CPU-runnable training run."""
-    cfg = arch.make_smoke_config()
+def _setup(arch, batch: int, seq_len: int, shape_name: Optional[str] = None):
+    """(model cfg, shape, data source) for a training run: the reduced
+    smoke config at ``batch``/``seq_len``, or — with ``shape_name`` — the
+    arch's full config at that registered shape's dims."""
+    if shape_name is not None:
+        shape = arch.shape(shape_name)
+        cfg = arch.make_config(shape_name)
+        batch = shape.dims.get("global_batch", shape.dims.get("batch"))
+        seq_len = shape.dims.get("seq_len", seq_len)
+    else:
+        shape = None
+        cfg = arch.make_smoke_config()
     if arch.family == "lm":
-        shape = SmokeShape("train_smoke", "train",
-                           {"global_batch": batch, "seq_len": seq_len})
+        shape = shape or SmokeShape(
+            "train_smoke", "train",
+            {"global_batch": batch, "seq_len": seq_len},
+        )
         data = SequenceDataset(SeqDataConfig(
             n_items=cfg.vocab, seq_len=seq_len, batch_size=batch,
             min_len_frac=1.0,
         ))
         return cfg, shape, data
     if arch.family == "seqrec":
-        shape = SmokeShape("train_smoke", "train", {"batch": batch})
+        shape = shape or SmokeShape("train_smoke", "train", {"batch": batch})
         data = SequenceDataset(SeqDataConfig(
             n_items=cfg.n_items, seq_len=cfg.max_len, batch_size=batch,
         ))
         return cfg, shape, data
     if arch.family == "recsys":
-        shape = SmokeShape("train_smoke", "train", {"batch": batch})
+        shape = shape or SmokeShape("train_smoke", "train", {"batch": batch})
         data = ClickstreamDataset(ClickDataConfig(
             vocab_sizes=cfg.vocab_sizes, batch_size=batch,
             n_dense=getattr(cfg, "n_dense", 1),
         ))
         return cfg, shape, data
     # gnn (molecule regime for smoke)
-    shape = SmokeShape("molecule", "train",
-                       {"batch": batch, "n_nodes": 10, "n_edges": 20,
-                        "d_feat": cfg.d_feat})
+    shape = shape or SmokeShape(
+        "molecule", "train",
+        {"batch": batch, "n_nodes": 10, "n_edges": 20, "d_feat": cfg.d_feat},
+    )
     return cfg, shape, None
 
 
@@ -224,8 +243,13 @@ def train(
     metrics_file: Optional[str] = None,
     chaos_nan_at: Optional[int] = None,
     guard_policy: Optional[str] = None,
+    shape: Optional[str] = None,
 ) -> Dict[str, Any]:
-    """Run a real (smoke-scale) training loop; returns final metrics.
+    """Run a real training loop; returns final metrics.
+
+    ``shape`` names one of the arch's registered ShapeSpecs: the full
+    config at its dims replaces the smoke config and ``batch``/
+    ``seq_len``.
 
     ``chaos_nan_at`` is the fault-injection hook the divergence drill
     uses: at that host step the params are multiplied by NaN *once*,
@@ -244,20 +268,26 @@ def train(
     if n_hosts > 1 and arch.family == "gnn":
         raise ValueError("--n-hosts emulation needs a sharded dataset; "
                          "the gnn molecule stream has none")
+    cfg, shape, data = _setup(arch, batch, seq_len, shape)
+    batch = shape.dims.get("global_batch", shape.dims.get("batch"))
+    seq_len = shape.dims.get("seq_len", seq_len)
     if n_hosts > 1 and batch % n_hosts:
         raise ValueError(f"batch {batch} not divisible by n_hosts {n_hosts}")
     mesh = make_host_mesh(max_data=batch)
-    cfg, shape, data = _smoke_setup(arch, batch, seq_len)
     step_fn, (opt_init, _) = _make_step(
         arch, cfg, mesh, shape, sce_mode, grad_compression
     )
     jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
 
+    # Params and optimizer state live replicated on the mesh from the
+    # start: the step's outputs come back that way, so step 0 compiles
+    # the same program every later step runs.
+    on_mesh = lambda tree: jax.device_put(tree, replicated_sharding(mesh))
     key = jax.random.PRNGKey(seed)
-    params = _init_params(arch, cfg, key)
+    params = on_mesh(_init_params(arch, cfg, key))
     state = TrainState(
         params=params,
-        opt_state=opt_init(params),
+        opt_state=on_mesh(opt_init(params)),
         key=key,
         cursor=Cursor(seed=seed),
         step=-1,
@@ -281,6 +311,8 @@ def train(
         restored = TrainState.from_ckpt(
             tree, opt_template=opt_init(state.params)
         )
+        restored.params = on_mesh(restored.params)
+        restored.opt_state = on_mesh(restored.opt_state)
         print(f"[restore] resumed from step {last}")
         return restored, last
 
@@ -347,11 +379,19 @@ def train(
             blocking=blocking,
         )
 
+    def place(a):
+        """Host array → device, batch rows split over the data axes
+        (the gnn batch is a flat segment layout and stays whole)."""
+        a = np.asarray(a)
+        if arch.family == "gnn" or a.ndim == 0:
+            return jnp.asarray(a)
+        return jax.device_put(a, NamedSharding(mesh, batch_spec(mesh, a.ndim)))
+
     losses, times = [], []
     skipped_steps = 0
     preempted = False
     prev_batch = None
-    with set_mesh(mesh), PreemptionHandler() as preemption:
+    with jax.set_mesh(mesh), PreemptionHandler() as preemption:
         step = state.step + 1
         while step < steps:
             if preemption.preempted:
@@ -387,7 +427,7 @@ def train(
                 prev_batch = host_batch
 
             state.key, step_key = jax.random.split(state.key)
-            dev_batch = jax.tree.map(jnp.asarray, host_batch)
+            dev_batch = jax.tree.map(place, host_batch)
             dev_batch["loss_cap"] = jnp.float32(guard.loss_cap())
             state.params, state.opt_state, metrics = jit_step(
                 state.params, state.opt_state, dev_batch, step_key
@@ -479,6 +519,7 @@ def train(
     out: Dict[str, Any] = {
         "first_loss": losses[0] if losses else None,
         "final_loss": losses[-1] if losses else None,
+        "losses": losses,
         "steps": len(losses),
         "mean_step_s": statistics.mean(times) if times else None,
         "skipped_steps": skipped_steps,
@@ -540,9 +581,12 @@ def main() -> None:
     ap.add_argument("--eval-users", type=int, default=128,
                     help="held-out sequences per eval (lm: eval rows = "
                          "sequences x seq_len)")
-    ap.add_argument("--smoke", action="store_true",
-                    help="(default behaviour; flag kept for symmetry)")
+    ap.add_argument("--shape",
+                    help="registered ShapeSpec name (e.g. train_paper): "
+                         "train the arch's full config at that shape's "
+                         "dims instead of the smoke config")
     args = ap.parse_args()
+    enable_compile_cache()
     out = train(
         args.arch,
         steps=args.steps,
@@ -565,6 +609,7 @@ def main() -> None:
         log_every=args.log_every,
         eval_every=args.eval_every,
         eval_users=args.eval_users,
+        shape=args.shape,
     )
     print(out)
     if out.get("preempted"):

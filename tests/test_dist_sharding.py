@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.dist.sharding import (
     batch_spec,
     catalog_spec,
@@ -109,7 +109,8 @@ def test_transformer_specs_mirror_params_and_fsdp(mesh):
         assert _tree_struct(specs) == jax.tree.structure(params)
     # fsdp shards the complementary dim of the column-parallel matmuls
     specs = transformer_param_specs(cfg, mesh, fsdp=True)
-    assert specs["layers"]["wq"][1] == ("data",)
+    # (PartitionSpec normalizes a one-axis tuple entry to the bare name.)
+    assert specs["layers"]["wq"][1] == P(None, ("data",))[1]
     no_fsdp = transformer_param_specs(cfg, mesh, fsdp=False)
     assert no_fsdp["layers"]["wq"][1] is None
     # cache specs: one entry per k/v per pattern slot, 5-dim specs

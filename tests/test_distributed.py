@@ -8,8 +8,8 @@ candidate clip when bucket_size_y > C/m, distributed top-k, the seqrec
 serve/retrieval shard_map steps, and a miniature multi-mesh dry-run
 (lower + compile of a real train cell on (2,4) and (2,2,2) meshes).
 
-All mesh/shard_map/set_mesh spellings come from ``repro.dist`` (the
-compat bridge), so the same tests run on old and new JAX."""
+Meshes come from ``repro.launch.mesh.make_mesh`` (Auto axes);
+``shard_map``/``set_mesh`` are JAX's own."""
 import os
 import subprocess
 import sys
@@ -31,7 +31,7 @@ def _run(body: str):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.dist import make_mesh, set_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         mesh24 = make_mesh((2, 4), ("data", "model"))
         mesh42 = make_mesh((4, 2), ("data", "model"))
         mesh222 = make_mesh((2, 2, 2), ("pod", "data", "model"))
@@ -66,7 +66,7 @@ def test_distributed_sce_exact_and_union_match_oracles():
             def f_r(x, y):
                 return sce_loss_sharded_ref(x, y, t, key=key, cfg=cfg,
                                             dp_size=2, mode=mode, tp_size=4)
-            with set_mesh(mesh24):
+            with jax.set_mesh(mesh24):
                 l = jax.jit(f_d)(x, y)
                 g = jax.jit(jax.grad(f_d, argnums=(0, 1)))(x, y)
             lr = f_r(x, y)
@@ -98,7 +98,7 @@ def test_distributed_sce_dp4_tp2_mesh():
             def f_r(x, y):
                 return sce_loss_sharded_ref(x, y, t, key=key, cfg=cfg,
                                             dp_size=4, mode=mode, tp_size=2)
-            with set_mesh(mesh42):
+            with jax.set_mesh(mesh42):
                 l = jax.jit(f_d)(x, y)
                 g = jax.jit(jax.grad(f_d, argnums=(0, 1)))(x, y)
             np.testing.assert_allclose(l, f_r(x, y), rtol=1e-5)
@@ -126,7 +126,7 @@ def test_distributed_sce_bucket_larger_than_catalog_slice():
     for b_y in (128, 384):
         cfg = SCEConfig(8, 16, b_y, use_mix=True)
         for mode in ("exact", "union"):
-            with set_mesh(mesh24):
+            with jax.set_mesh(mesh24):
                 l = jax.jit(lambda x, y: sce_loss_sharded(
                     x, y, t, key=key, cfg=cfg, mesh=mesh24, mode=mode))(x, y)
             lr = sce_loss_sharded_ref(x, y, t, key=key, cfg=cfg,
@@ -145,7 +145,7 @@ def test_distributed_sce_multipod_mesh():
     y = jax.random.normal(jax.random.PRNGKey(2), (256, 32)) * 0.5
     t = jax.random.randint(jax.random.PRNGKey(3), (128,), 0, 256)
     cfg = SCEConfig(8, 16, 32, use_mix=True)
-    with set_mesh(mesh222):
+    with jax.set_mesh(mesh222):
         l = jax.jit(lambda x, y: sce_loss_sharded(
             x, y, t, key=key, cfg=cfg, mesh=mesh222))(x, y)
     # pod×data = 4 data shards on the multi-pod mesh
@@ -162,10 +162,10 @@ def test_distributed_topk_exact():
     def inner(s):
         vals, idx, src = distributed_topk(s, 7, "model")
         return vals, idx, src
-    fn = shard_map(inner, mesh=mesh24,
+    fn = jax.shard_map(inner, mesh=mesh24,
                    in_specs=P(None, "model"),
                    out_specs=(P(None), P(None), P(None)))
-    with set_mesh(mesh24):
+    with jax.set_mesh(mesh24):
         vals, idx, src = fn(scores)
     want_vals, want_idx = jax.lax.top_k(scores, 7)
     np.testing.assert_allclose(np.asarray(vals)[:, :7], want_vals, rtol=1e-6)
@@ -192,7 +192,7 @@ def test_seqrec_serve_and_retrieval_match_dense():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, cfg.max_len),
                                 1, cfg.n_items)
     serve = steps_lib.make_seqrec_serve_step(arch, cfg, mesh24, top_k=10)
-    with set_mesh(mesh24):
+    with jax.set_mesh(mesh24):
         vals, ids = jax.jit(serve)(params, tokens)
     # dense reference
     hidden = sasrec.forward(params, cfg, tokens)
@@ -203,7 +203,7 @@ def test_seqrec_serve_and_retrieval_match_dense():
 
     retr = steps_lib.make_seqrec_retrieval_step(arch, cfg, mesh24, top_k=10)
     cands = jnp.arange(1, 400)
-    with set_mesh(mesh24):
+    with jax.set_mesh(mesh24):
         rv, ri = jax.jit(retr)(params, tokens[:1], cands)
     sc = hidden[:1, -1] @ sasrec.item_embeddings(params, cfg)[cands].T  # noqa
     wv, wi = jax.lax.top_k(sc, 10)
@@ -276,7 +276,7 @@ def test_mips_serve_differential_restored_ckpt_all_meshes():
             shardings=seqrec_serve_shardings(cfg, mesh))
         serve = steps_lib.make_seqrec_mips_serve_step(
             arch, cfg, mesh, top_k=k)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             v, i = jax.jit(serve)(params_m, tokens)
         np.testing.assert_array_equal(np.asarray(i), want_ids)
         np.testing.assert_allclose(np.asarray(v), want_vals, rtol=1e-6)
@@ -379,12 +379,12 @@ def test_streaming_eval_sharded_matches_oracle():
         vals, gids = distributed_topk_from_local(vals_l, ids_l, k, "model")
         return vals, gids, gt, eq
 
-    fn = shard_map(inner, mesh=mesh42,
+    fn = jax.shard_map(inner, mesh=mesh42,
                    in_specs=(batch_spec(mesh42, 2), catalog_spec(mesh42),
                              batch_spec(mesh42, 1)),
                    out_specs=(batch_spec(mesh42, 2), batch_spec(mesh42, 2),
                               batch_spec(mesh42, 1), batch_spec(mesh42, 1)))
-    with set_mesh(mesh42):
+    with jax.set_mesh(mesh42):
         vals, gids, gt, eq = jax.jit(fn)(x, y, t)
     scores = np.array(x @ y.T)
     scores[:, 0] = -1e30
@@ -477,12 +477,12 @@ def test_fused_eval_sharded_scorer_with_lse_merge():
         lse = distributed_lse_from_local(m_l, s_l, "model")
         return vals, gids, gt, eq, tgt, lse
 
-    fn = shard_map(inner, mesh=mesh42,
+    fn = jax.shard_map(inner, mesh=mesh42,
                    in_specs=(batch_spec(mesh42, 2), catalog_spec(mesh42),
                              batch_spec(mesh42, 1)),
                    out_specs=(batch_spec(mesh42, 2), batch_spec(mesh42, 2))
                    + (batch_spec(mesh42, 1),) * 4)
-    with set_mesh(mesh42):
+    with jax.set_mesh(mesh42):
         vals, gids, gt, eq, tgt, lse = jax.jit(fn)(x, y, t)
     scores = np.array(x @ y.T)
     want_tgt = scores[np.arange(b), np.asarray(t)]
@@ -525,9 +525,9 @@ def test_sharded_mips_topk_stage1_matches_dense():
             b, y_l, min(k, c_local), block_c=20, id_offset=off)
         return distributed_topk_from_local(vals_l, gids_l, k, "model")
 
-    fn = shard_map(inner, mesh=mesh24, in_specs=catalog_spec(mesh24),
+    fn = jax.shard_map(inner, mesh=mesh24, in_specs=catalog_spec(mesh24),
                    out_specs=(replicated_spec(), replicated_spec()))
-    with set_mesh(mesh24):
+    with jax.set_mesh(mesh24):
         vals, gids = jax.jit(fn)(y)
     wv, wi = jax.lax.top_k(b @ y.T, k)
     np.testing.assert_array_equal(np.asarray(gids), np.asarray(wi))
@@ -551,9 +551,9 @@ def test_all_to_all_bucket_shuffle_routing():
         payload = x_l + 100 * src
         return all_to_all_bucket_shuffle(payload, "model")
     base = jnp.arange(n_b, dtype=jnp.float32)
-    fn = shard_map(inner, mesh=mesh24,
+    fn = jax.shard_map(inner, mesh=mesh24,
                    in_specs=P(), out_specs=P(None, "model"))
-    with set_mesh(mesh24):
+    with jax.set_mesh(mesh24):
         out = jax.jit(fn)(base)  # (m, n_b/m * m) over shards
     out = np.asarray(out).reshape(m, m, n_b // m)
     for owner in range(m):
@@ -576,8 +576,8 @@ def test_collective_bytes_parser():
     from repro.launch.dryrun import collective_bytes
     def f(x):
         return jax.lax.psum(x, "model")
-    fn = shard_map(f, mesh=mesh24, in_specs=P("model"), out_specs=P())
-    with set_mesh(mesh24):
+    fn = jax.shard_map(f, mesh=mesh24, in_specs=P("model"), out_specs=P())
+    with jax.set_mesh(mesh24):
         lowered = jax.jit(fn).lower(jnp.ones((64,)))
     hlo = lowered.compile().as_text()
     out = collective_bytes(hlo, 8)

@@ -20,6 +20,7 @@ from repro.eval import (
     streaming_rank_topk,
 )
 from repro.kernels import ops, ref
+from _exact import grid_normal
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
@@ -50,8 +51,8 @@ def _dense_oracle(x, y, targets, *, c_lo=1):
 def test_eval_topk_kernel_vs_ref_vs_dense(key, shape):
     b, c, d, k, bb, bc = shape
     kx, ky, kt = jax.random.split(key, 3)
-    x = jax.random.normal(kx, (b, d))
-    y = jax.random.normal(ky, (c, d))
+    x = grid_normal(kx, (b, d))
+    y = grid_normal(ky, (c, d))
     t = jax.random.randint(kt, (b,), 1, c)
 
     tgt_k = ops.eval_tgt_scores(x, y, t, block_b=bb, block_c=bc,
@@ -141,8 +142,8 @@ def test_eval_topk_fewer_valid_columns_than_k(key):
     keeps."""
     b, c, d, k = 6, 6, 8, 5
     kx, ky, kt = jax.random.split(key, 3)
-    x = jax.random.normal(kx, (b, d))
-    y = jax.random.normal(ky, (c, d))
+    x = grid_normal(kx, (b, d))
+    y = grid_normal(ky, (c, d))
     t = jax.random.randint(kt, (b,), 1, 4)
     # only ids [1, 4) valid → 3 valid columns < k, over 3 tiles of 2
     tgt = ops.eval_tgt_scores(x, y, t, block_c=2, interpret=True)

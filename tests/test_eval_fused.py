@@ -22,6 +22,7 @@ import pytest
 
 from repro.eval import ranks_from_counts, streaming_eval_scores
 from repro.kernels import ops, ref
+from _exact import grid_normal
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
 
@@ -201,8 +202,8 @@ def test_fused_edge_cases(key):
 
     b, c, d, k = 6, 6, 8, 5
     kx, kt = jax.random.split(key)
-    x = jax.random.normal(kx, (b, d))
-    y2 = jax.random.normal(ky, (c, d))
+    x = grid_normal(kx, (b, d))
+    y2 = grid_normal(ky, (c, d))
     t = jax.random.randint(kt, (b,), 1, 4)
     want = _two_pass(x, y2, t, k, block_c=2, c_lo=1, c_hi=4)
     got = ops.eval_fused(x, y2, t, k, block_c=2, c_lo=1, c_hi=4,
@@ -211,6 +212,34 @@ def test_fused_edge_cases(key):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
                                       err_msg=name)
     assert (np.asarray(got[1])[:, 3:] == np.iinfo(np.int32).max).all()
+
+
+@pytest.mark.parametrize("shape", [(6, 6, 8, 5, 2, 1, 4),
+                                   (33, 517, 16, 10, 128, 1, 517),
+                                   (12, 300, 64, 10, 128, 1, 290)])
+def test_fused_counts_match_float64_on_continuous_inputs(key, shape):
+    """Rank counts on continuous normals against float64: ``gt`` counts
+    the items scoring strictly above the target, never the target
+    itself. The kernel and ``eval_fused_ref`` both hold this (the
+    first shape is the case on which the deprecated two-pass XLA
+    oracle counted the target above itself in 3 of 6 rows: its sweep
+    scored the target's column one ulp above its own extracted target
+    score)."""
+    b, c, d, k, bc, c_lo, c_hi = shape
+    kx, ky, kt = jax.random.split(jax.random.fold_in(key, c), 3)
+    x = jax.random.normal(kx, (b, d))
+    y = jax.random.normal(ky, (c, d))
+    t = jax.random.randint(kt, (b,), c_lo, c_hi)
+    s64 = np.asarray(x, np.float64) @ np.asarray(y, np.float64).T
+    own = s64[np.arange(b), np.asarray(t)][:, None]
+    want_gt = np.sum(s64[:, c_lo:c_hi] > own, axis=1)
+    for got in (
+        ops.eval_fused(x, y, t, k, block_c=bc, c_lo=c_lo, c_hi=c_hi,
+                       interpret=True),
+        ref.eval_fused_ref(x, y, t, k, chunk=bc, c_lo=c_lo, c_hi=c_hi),
+    ):
+        np.testing.assert_array_equal(np.asarray(got[2]), want_gt)
+        np.testing.assert_array_equal(np.asarray(got[3]), np.ones(b))
 
 
 def test_streaming_front_end_impls_agree(key):

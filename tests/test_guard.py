@@ -307,6 +307,25 @@ def test_broken_kernel_strict_raises(monkeypatch, key):
     assert ei.value.failures
 
 
+def test_broken_kernel_on_tpu_raises_under_warn(monkeypatch, key):
+    """On TPU a failed canary never degrades to the XLA reference, even
+    under the default ``warn`` policy: the device path must run."""
+    import repro.kernels.mips_topk as mips_mod
+
+    monkeypatch.setattr(mips_mod, "mips_topk", _broken_kernel)
+    monkeypatch.setattr(conf, "_backend_name", lambda: "tpu")
+    guard.clear_verdicts("mips_topk")
+    assert guard.policy() == "warn"
+    q = jax.random.normal(key, (6, 8))
+    y = jax.random.normal(jax.random.PRNGKey(1), (10, 8))
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # and no DEGRADING warning either
+        with pytest.raises(guard.KernelConformanceError) as ei:
+            ops.mips_topk(q, y, 4)
+    assert ei.value.kernel == "mips_topk"
+    assert "tpu" in str(ei.value)
+
+
 def test_policy_off_is_legacy_passthrough(monkeypatch, key):
     import repro.kernels.mips_topk as mips_mod
 

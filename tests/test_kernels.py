@@ -267,6 +267,42 @@ def test_sce_gather_duplicate_rows_across_buckets(key):
     np.testing.assert_allclose(gk, gr, rtol=2e-4, atol=1e-5)
 
 
+def test_sce_gather_dy_summed_over_data_shards(key):
+    """Under ``shard_map`` with positions on ``data`` and the catalog on
+    ``model``, each data shard's backward kernel holds a partial dY.
+    The aliased zeros buffer must be typed as varying over ``data`` too,
+    so the cotangent is psummed over ``data`` (traced for Mosaic, not
+    run: the interpreter cannot run inside ``shard_map``)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels import sce_prefetch
+
+    x_b, y, idx, tgt, cand, _ = _gather_problem(key, 2, 8, 128, 8, 256)
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    seen = {}
+
+    def inner(x_b, y, idx, tgt, cand):
+        lse = jnp.zeros(tgt.shape, jnp.float32)
+        _, dy = sce_prefetch._gbwd(
+            x_b, y, idx, tgt, cand, lse, jnp.ones_like(lse),
+            block_bx=8, block_by=128, interpret=False,
+        )
+        seen["dy"] = jax.typeof(dy).vma
+        gy = jax.grad(lambda y: jnp.sum(sce_prefetch.sce_gather_plse(
+            x_b, y, idx, tgt, cand, 8, 128, False
+        )))(y)
+        seen["gy"] = jax.typeof(gy).vma
+        return jax.lax.psum(jnp.sum(gy), "model")
+
+    fn = jax.shard_map(inner, mesh=mesh, out_specs=P(), in_specs=(
+        P("data"), P("model"), P("data"), P("data"), P("data"),
+    ))
+    jaxpr = str(jax.make_jaxpr(fn)(x_b, y, idx, tgt, cand))
+    assert seen["dy"] == {"data", "model"}
+    assert seen["gy"] == {"model"}
+    assert "psum_invariant[axes=('data',)]" in jaxpr
+
+
 def test_negative_cand_ids_masked_everywhere(key):
     """The shared invalid-candidate rule (cand_id < 0): sce_bucket
     kernel, prefetch kernel and both refs must agree, and the masked

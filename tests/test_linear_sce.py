@@ -122,7 +122,7 @@ def _iter_var_sizes(jaxpr):
             aval = getattr(v, "aval", None)
             if aval is not None and hasattr(aval, "size"):
                 yield int(aval.size)
-        for val in jax.util.unzip2(sorted(eqn.params.items()))[1]:
+        for _, val in sorted(eqn.params.items()):
             yield from _iter_param_sizes(val)
 
 

@@ -16,6 +16,7 @@ import pytest
 from repro.core.sce import SCEConfig, make_bucket_centers, select_buckets
 from repro.kernels import ops, ref
 from repro.kernels.topk_merge import ID_PAD, merge_topk_tile
+from _exact import grid_normal
 
 NEG_INF = -1e30
 
@@ -31,8 +32,8 @@ SHAPES = [
 
 def _problem(key, n_q, c, d):
     kq, ky = jax.random.split(key)
-    q = jax.random.normal(kq, (n_q, d))
-    y = jax.random.normal(ky, (c, d))
+    q = grid_normal(kq, (n_q, d))
+    y = grid_normal(ky, (c, d))
     return q, y
 
 
@@ -49,6 +50,29 @@ def test_mips_topk_matches_dense(key, shape):
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
     np.testing.assert_array_equal(np.asarray(ref_v), np.asarray(want_v))
     np.testing.assert_array_equal(np.asarray(ref_i), np.asarray(want_i))
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_mips_topk_continuous_matches_float64(key, shape):
+    """Continuous normals, where summation order shows: kernel and
+    chunked reference keep the float64 top-k ids (no near-ties at these
+    seeds) and their values agree with float64 to float32 rounding — a
+    bfloat16 dot would miss by ~1e-3."""
+    n_q, c, d, k, bq, bc = shape
+    kq, ky = jax.random.split(key)
+    q = jax.random.normal(kq, (n_q, d))
+    y = jax.random.normal(ky, (c, d))
+    s64 = np.asarray(q, np.float64) @ np.asarray(y, np.float64).T
+    want_i = np.argsort(-s64, axis=1, kind="stable")[:, :k]
+    want_v = np.take_along_axis(s64, want_i, axis=1)
+    scale = np.abs(s64).max()
+    for got_v, got_i in (
+        ops.mips_topk(q, y, k, block_q=bq, block_c=bc, interpret=True),
+        ref.mips_topk_ref(q, y, k, chunk=bc),
+    ):
+        np.testing.assert_array_equal(np.asarray(got_i), want_i)
+        np.testing.assert_allclose(np.asarray(got_v), want_v, rtol=0,
+                                   atol=1e-6 * scale)
 
 
 def test_mips_topk_tie_order(key):
@@ -91,10 +115,19 @@ def test_mips_topk_valid_mask(key):
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
 
 
-def test_mips_topk_traced_offset_falls_back_to_ref(key):
-    """A traced id_offset (the sharded-catalog case) cannot drive static
-    block specs — ops.mips_topk must route to the chunked reference and
-    still produce globally-offset ids."""
+def test_mips_topk_traced_offset_falls_back_to_ref(key, monkeypatch):
+    """A traced id_offset (the sharded-catalog case) no longer falls
+    back to the chunked reference: the kernel selects local ids and
+    ops.mips_topk shifts the real ones by the offset — globally-offset
+    ids, same tie order."""
+    from repro.kernels import guard
+
+    guard.verdict_for("mips_topk")  # canaries use the reference; run them first
+
+    def no_ref(*a, **k):
+        raise AssertionError("traced id_offset routed to the reference")
+
+    monkeypatch.setattr(ref, "mips_topk_ref", no_ref)
     q, y = _problem(key, 4, 64, 8)
 
     def f(off):
@@ -139,7 +172,7 @@ def test_select_buckets_fused_equals_dense(key):
 def _property_problem(seed, c, d, tie_level, starve):
     """(q, y, valid) with controllable tie density / mask starvation.
 
-    tie_level 0: continuous normals (ties only by coincidence);
+    tie_level 0: normals on a 1/8 grid (ties only by coincidence);
     1: small-integer embeddings (exact-float scores, ties everywhere);
     2: integer embeddings + duplicated catalog rows (every score tied).
     starve > 0: valid mask keeps only ``starve`` columns (exercises the
@@ -148,8 +181,8 @@ def _property_problem(seed, c, d, tie_level, starve):
     kq, ky, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     n_q = 5
     if tie_level == 0:
-        q = jax.random.normal(kq, (n_q, d))
-        y = jax.random.normal(ky, (c, d))
+        q = grid_normal(kq, (n_q, d))
+        y = grid_normal(ky, (c, d))
     else:
         q = jax.random.randint(kq, (n_q, d), -3, 4).astype(jnp.float32)
         y = jax.random.randint(ky, (c, d), -2, 3).astype(jnp.float32)

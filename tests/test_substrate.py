@@ -75,7 +75,7 @@ def test_checkpoint_elastic_resharding(tmp_path):
     """Restore with target shardings (the elastic-restart path)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.dist import make_mesh
+    from repro.launch.mesh import make_mesh
 
     mgr = CheckpointManager(str(tmp_path))
     mgr.save(1, {"w": jnp.arange(16.0)})

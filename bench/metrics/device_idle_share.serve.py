@@ -1,0 +1,6 @@
+"""Share of the traced serve window with no operation on the device."""
+from harness import readers
+
+
+def read(r):
+    return readers.idle_share(r)

@@ -146,105 +146,118 @@ def _sce_inner(
     b_x = min(cfg.bucket_size_x, n_local)
     use_kernel = cfg.use_kernel
 
-    key_l = jax.random.fold_in(key, _data_shard_index(dp))
-    b = make_bucket_centers(
-        key_l, x_l, n_b, use_mix=cfg.use_mix, valid_mask=vm_l
-    )
-
-    # X side: ALL buckets on every shard (needed for the local partials).
-    xs = jax.lax.stop_gradient(x_l)
-    _, idx_x = _local_topk(
-        b, xs, b_x, use_kernel=use_kernel, valid=vm_l
-    )  # (n_b, b_x)
-
-    # Y side: per-shard stage-1 over the local catalog slice.
-    ys = jax.lax.stop_gradient(y_l)
-    if exact:
-        # Stage 1 clips per catalog SLICE, the merge per full catalog —
-        # mirroring sce_loss_sharded_ref's min(b_y, C) clip so the
-        # equality holds even when bucket_size_y > C/m (a shard then
-        # simply contributes its whole slice).
-        b_y_loc = min(cfg.bucket_size_y, c_local)
-        vals_l, idx_l = _local_topk(b, ys, b_y_loc, use_kernel=use_kernel)
-        gids_l = idx_l + tp_i * c_local
-        # ids-only exact merge, replicated over ``model`` (tie order =
-        # dense lax.top_k — same candidates as the single-device oracle).
-        _, cand_gids = distributed_topk_from_local(
-            vals_l, gids_l, cfg.bucket_size_y, tp
-        )  # (n_b, min(b_y, C))
-        local = cand_gids - tp_i * c_local
-        own = jnp.logical_and(local >= 0, local < c_local)
-        idx_y = jnp.clip(local, 0, c_local - 1)  # gather rows (clipped)
-        # Non-owned candidates are evaluated on their home shard; mask
-        # them here with the negative-id rule shared by kernels and refs.
-        gidx_y = jnp.where(own, cand_gids, -1)
-        k_cand = cand_gids.shape[-1]
-    else:
-        # Union mode: local top-(b_y/m) per bucket — no communication.
-        k_cand = max(1, min(cfg.bucket_size_y // m, c_local))
-        _, idx_y = _local_topk(b, ys, k_cand, use_kernel=use_kernel)
-        gidx_y = idx_y + tp_i * c_local
-
-    pos_logit_all = _positive_logits(x_l, y_l, t_l, tp, cfg.logit_softcap)
-
-    while n_b % bucket_chunks:
-        bucket_chunks -= 1
-    nb_c = n_b // bucket_chunks
-
-    def chunk_partials(chunk):
-        """One bucket chunk → partial LSE over locally-owned candidates.
-        Rematerialized so the backward never stacks the (n_b, b_x, d)
-        gathers. Kernel-backed on TPU: ops.sce_gather_plse prefetch-
-        gathers the candidate rows from the local catalog slice and
-        accumulates dY straight into (C_local, d)."""
-        idx_x_c, idx_y_c, gidx_c = chunk
-        x_b = jnp.take(x_l, idx_x_c, axis=0)  # (nb_c, b_x, d)
-        tgt_b = jnp.take(t_l, idx_x_c, axis=0)
-        if use_kernel:
-            from repro.kernels import ops as _kops
-
-            return _kops.sce_gather_plse(
-                x_b, y_l, idx_y_c, tgt_b, gidx_c,
-                logit_softcap=cfg.logit_softcap,
-            )
-        y_b = jnp.take(y_l, idx_y_c, axis=0)  # (nb_c, k_cand, d)
-        neg = apply_softcap(
-            jnp.einsum("nxd,nyd->nxy", x_b, y_b), cfg.logit_softcap
+    # Named scopes (profiler op_name metadata): ``select`` holds the Mix
+    # projection and both selections, ``select/positions`` (K = b_x) and
+    # ``select/catalog`` (K = b_y, with the cross-shard merge);
+    # ``gather_loss`` the positive logits, in-bucket partials and merge.
+    with jax.named_scope("select"):
+        key_l = jax.random.fold_in(key, _data_shard_index(dp))
+        b = make_bucket_centers(
+            key_l, x_l, n_b, use_mix=cfg.use_mix, valid_mask=vm_l
         )
-        collide = gidx_c[:, None, :] == tgt_b[:, :, None]
-        invalid = jnp.logical_or(collide, (gidx_c < 0)[:, None, :])
-        neg = jnp.where(invalid, NEG_INF, neg).astype(jnp.float32)
-        mx = jnp.max(neg, axis=-1)  # (nb_c, b_x)
-        sx = jnp.sum(jnp.exp(neg - mx[..., None]), axis=-1)
-        return mx + jnp.log(jnp.maximum(sx, 1e-30))
 
-    chunks = (
-        idx_x.reshape(bucket_chunks, nb_c, b_x),
-        idx_y.reshape(bucket_chunks, nb_c, k_cand),
-        gidx_y.reshape(bucket_chunks, nb_c, k_cand),
-    )
-    plse = jax.lax.map(
-        jax.checkpoint(chunk_partials, prevent_cse=False), chunks
-    ).reshape(n_b, b_x)
+        # X side: ALL buckets on every shard (needed for the local partials).
+        with jax.named_scope("positions"):
+            xs = jax.lax.stop_gradient(x_l)
+            _, idx_x = _local_topk(
+                b, xs, b_x, use_kernel=use_kernel, valid=vm_l
+            )  # (n_b, b_x)
 
-    # log-space merge across model shards: one pmax + one psum (~1 MB).
-    # pmax runs on a stopped-gradient copy — the max shift in a logsumexp
-    # is gradient-neutral, and pmax has no differentiation rule.
-    g_m = jax.lax.pmax(jax.lax.stop_gradient(plse), tp)
-    g_s = jax.lax.psum(jnp.exp(plse - g_m), tp)
-    pos_logit = jnp.take(pos_logit_all, idx_x, axis=0).astype(jnp.float32)
-    lse = jnp.logaddexp(g_m + jnp.log(jnp.maximum(g_s, 1e-30)), pos_logit)
-    losses = lse - pos_logit  # (n_b, b_x)
+        # Y side: per-shard stage-1 over the local catalog slice.
+        with jax.named_scope("catalog"):
+            ys = jax.lax.stop_gradient(y_l)
+            if exact:
+                # Stage 1 clips per catalog SLICE, the merge per full
+                # catalog — mirroring sce_loss_sharded_ref's min(b_y, C)
+                # clip so the equality holds even when bucket_size_y > C/m
+                # (a shard then simply contributes its whole slice).
+                b_y_loc = min(cfg.bucket_size_y, c_local)
+                vals_l, idx_l = _local_topk(
+                    b, ys, b_y_loc, use_kernel=use_kernel
+                )
+                gids_l = idx_l + tp_i * c_local
+                # ids-only exact merge, replicated over ``model`` (tie
+                # order = dense lax.top_k — same candidates as the
+                # single-device oracle).
+                _, cand_gids = distributed_topk_from_local(
+                    vals_l, gids_l, cfg.bucket_size_y, tp
+                )  # (n_b, min(b_y, C))
+                local = cand_gids - tp_i * c_local
+                own = jnp.logical_and(local >= 0, local < c_local)
+                idx_y = jnp.clip(local, 0, c_local - 1)  # rows (clipped)
+                # Non-owned candidates are evaluated on their home shard;
+                # mask them here with the negative-id rule shared by
+                # kernels and refs.
+                gidx_y = jnp.where(own, cand_gids, -1)
+                k_cand = cand_gids.shape[-1]
+            else:
+                # Union mode: local top-(b_y/m) per bucket — no
+                # communication.
+                k_cand = max(1, min(cfg.bucket_size_y // m, c_local))
+                _, idx_y = _local_topk(b, ys, k_cand, use_kernel=use_kernel)
+                gidx_y = idx_y + tp_i * c_local
 
-    per_pos = _aggregate(losses, idx_x, n_local, vm_l, dp)
-    covered = (per_pos > NEG_INF / 2) & vm_l
-    per_pos = jnp.where(covered, per_pos, 0.0)
+    with jax.named_scope("gather_loss"):
+        pos_logit_all = _positive_logits(x_l, y_l, t_l, tp, cfg.logit_softcap)
 
-    # The pmax/psum merge already made the losses model-invariant, so the
-    # final reduction runs over the data axes only.
-    num = jax.lax.psum(jnp.sum(per_pos), tuple(dp))
-    den = jax.lax.psum(jnp.sum(covered.astype(per_pos.dtype)), tuple(dp))
-    return num / jnp.maximum(den, 1.0)
+        while n_b % bucket_chunks:
+            bucket_chunks -= 1
+        nb_c = n_b // bucket_chunks
+
+        def chunk_partials(chunk):
+            """One bucket chunk → partial LSE over locally-owned candidates.
+            Rematerialized so the backward never stacks the (n_b, b_x, d)
+            gathers. Kernel-backed on TPU: ops.sce_gather_plse prefetch-
+            gathers the candidate rows from the local catalog slice and
+            accumulates dY straight into (C_local, d)."""
+            idx_x_c, idx_y_c, gidx_c = chunk
+            x_b = jnp.take(x_l, idx_x_c, axis=0)  # (nb_c, b_x, d)
+            tgt_b = jnp.take(t_l, idx_x_c, axis=0)
+            if use_kernel:
+                from repro.kernels import ops as _kops
+
+                return _kops.sce_gather_plse(
+                    x_b, y_l, idx_y_c, tgt_b, gidx_c,
+                    logit_softcap=cfg.logit_softcap,
+                )
+            y_b = jnp.take(y_l, idx_y_c, axis=0)  # (nb_c, k_cand, d)
+            neg = apply_softcap(
+                jnp.einsum("nxd,nyd->nxy", x_b, y_b), cfg.logit_softcap
+            )
+            collide = gidx_c[:, None, :] == tgt_b[:, :, None]
+            invalid = jnp.logical_or(collide, (gidx_c < 0)[:, None, :])
+            neg = jnp.where(invalid, NEG_INF, neg).astype(jnp.float32)
+            mx = jnp.max(neg, axis=-1)  # (nb_c, b_x)
+            sx = jnp.sum(jnp.exp(neg - mx[..., None]), axis=-1)
+            return mx + jnp.log(jnp.maximum(sx, 1e-30))
+
+        chunks = (
+            idx_x.reshape(bucket_chunks, nb_c, b_x),
+            idx_y.reshape(bucket_chunks, nb_c, k_cand),
+            gidx_y.reshape(bucket_chunks, nb_c, k_cand),
+        )
+        plse = jax.lax.map(
+            jax.checkpoint(chunk_partials, prevent_cse=False), chunks
+        ).reshape(n_b, b_x)
+
+        # log-space merge across model shards: one pmax + one psum (~1 MB).
+        # pmax runs on a stopped-gradient copy — the max shift in a logsumexp
+        # is gradient-neutral, and pmax has no differentiation rule.
+        g_m = jax.lax.pmax(jax.lax.stop_gradient(plse), tp)
+        g_s = jax.lax.psum(jnp.exp(plse - g_m), tp)
+        pos_logit = jnp.take(pos_logit_all, idx_x, axis=0).astype(jnp.float32)
+        lse = jnp.logaddexp(g_m + jnp.log(jnp.maximum(g_s, 1e-30)), pos_logit)
+        losses = lse - pos_logit  # (n_b, b_x)
+
+        per_pos = _aggregate(losses, idx_x, n_local, vm_l, dp)
+        covered = (per_pos > NEG_INF / 2) & vm_l
+        per_pos = jnp.where(covered, per_pos, 0.0)
+
+        # The pmax/psum merge already made the losses model-invariant, so the
+        # final reduction runs over the data axes only.
+        num = jax.lax.psum(jnp.sum(per_pos), tuple(dp))
+        den = jax.lax.psum(jnp.sum(covered.astype(per_pos.dtype)), tuple(dp))
+        return num / jnp.maximum(den, 1.0)
 
 
 def sce_loss_sharded(

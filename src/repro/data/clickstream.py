@@ -9,8 +9,10 @@ matching the skew that makes embedding-table sharding interesting.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Tuple
 
+import jax
 import numpy as np
 
 from repro.data.pipeline import Cursor, ShardedCursor
@@ -49,6 +51,10 @@ class ClickstreamDataset:
         cdf = np.cumsum(w) / w.sum()
         return np.searchsorted(cdf, u).astype(np.int32)
 
+    # A profiler span (a no-op unless a trace is being taken); the
+    # sharded path runs inside it too, through this method.
+    @functools.partial(jax.profiler.annotate_function,
+                       name="repro.data.next_batch")
     def next_batch(self, cursor: Cursor) -> Tuple[Dict[str, np.ndarray], Cursor]:
         cfg = self.cfg
         rng = cursor.rng(salt=2)

@@ -14,8 +14,10 @@ shardable by slicing the batch dimension.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.data.pipeline import Cursor, ShardedCursor
@@ -61,6 +63,10 @@ class SequenceDataset:
         items = 1 + clusters + rank * cfg.n_clusters  # interleaved layout
         return np.minimum(items, cfg.n_items - 1).astype(np.int32)
 
+    # A profiler span (a no-op unless a trace is being taken); the
+    # sharded path runs inside it too, through this method.
+    @functools.partial(jax.profiler.annotate_function,
+                       name="repro.data.next_batch")
     def next_batch(
         self, cursor: Cursor
     ) -> Tuple[Dict[str, np.ndarray], Cursor]:

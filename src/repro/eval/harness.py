@@ -176,6 +176,10 @@ def evaluate_streaming(
     dict — same keys (``hr@k`` / ``ndcg@k`` / ``cov@k``) and, on a
     single batch, the same values as the ``core.metrics.topk_metrics``
     oracle.
+
+    Profiler spans: ``repro.eval.encode`` (the encoder),
+    ``repro.eval.score`` (the catalog sweep) and ``repro.eval.fold``
+    (readback and the host metric fold).
     """
     if score_fn is None:
         score_fn = default_score_fn(cfg)
@@ -183,22 +187,25 @@ def evaluate_streaming(
     k = max(ks)
 
     if mesh is None:
-        states, catalog = score_fn(params, jnp.asarray(tokens))
-        vals, ids, gt, eq, _tgt, _m, _s = streaming_eval_scores(
-            states, catalog, jnp.asarray(targets), k,
-            block_b=block_b, block_c=block_c,
-            c_lo=1, c_hi=cfg.n_items,
-            impl=impl, interpret=interpret,
-        )
+        with jax.profiler.TraceAnnotation("repro.eval.encode"):
+            states, catalog = score_fn(params, jnp.asarray(tokens))
+        with jax.profiler.TraceAnnotation("repro.eval.score"):
+            vals, ids, gt, eq, _tgt, _m, _s = streaming_eval_scores(
+                states, catalog, jnp.asarray(targets), k,
+                block_b=block_b, block_c=block_c,
+                c_lo=1, c_hi=cfg.n_items,
+                impl=impl, interpret=interpret,
+            )
     else:
         vals, ids, gt, eq = _evaluate_sharded(
             params, cfg, tokens, targets, k,
             score_fn=score_fn, mesh=mesh, block_c=block_c,
         )
 
-    acc = accumulator or MetricAccumulator(ks, cfg.n_items)
-    acc.update(ranks_from_counts(gt, eq), np.asarray(ids))
-    return acc.result()
+    with jax.profiler.TraceAnnotation("repro.eval.fold"):
+        acc = accumulator or MetricAccumulator(ks, cfg.n_items)
+        acc.update(ranks_from_counts(gt, eq), np.asarray(ids))
+        return acc.result()
 
 
 # jitted sharded scorers, keyed on everything the closure bakes in —
@@ -296,11 +303,13 @@ def _evaluate_sharded(
     params, cfg, tokens, targets, k, *, score_fn, mesh, block_c
 ):
     """Leave-one-out sharded scoring: one eval row per kept sequence."""
-    states, catalog = score_fn(params, jnp.asarray(tokens))
-    vals, ids, gt, eq, _tgt = _rank_topk_sharded(
-        states, catalog, targets, k,
-        mesh=mesh, block_c=block_c, c_lo=1, c_hi=cfg.n_items,
-    )
+    with jax.profiler.TraceAnnotation("repro.eval.encode"):
+        states, catalog = score_fn(params, jnp.asarray(tokens))
+    with jax.profiler.TraceAnnotation("repro.eval.score"):
+        vals, ids, gt, eq, _tgt = _rank_topk_sharded(
+            states, catalog, targets, k,
+            mesh=mesh, block_c=block_c, c_lo=1, c_hi=cfg.n_items,
+        )
     return vals, ids, gt, eq
 
 

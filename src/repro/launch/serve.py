@@ -35,6 +35,15 @@ on a mesh) — a checkpoint written on any training mesh restores
 straight into the serving layout. Random-init params are only the
 documented ``ckpt_dir=None`` smoke path.
 
+Profiler spans (``jax.profiler``; no-ops unless a trace is being
+taken): ``repro.serve.idle`` while the worker waits on an empty queue,
+``repro.serve.batch`` per micro-batch with ``repro.serve.pad`` (stack
+and bucket padding), ``repro.serve.run`` (token transfer, the bucket's
+program, readback) and ``repro.serve.reply`` (slicing and waking the
+callers). ``health()`` adds the batching counters: ``batches``,
+``batch_rows`` (summed bucket sizes), ``queue_wait_s`` and
+``queue_wait_max_s`` (submit to pick-up by the worker).
+
 Exactness: server top-k (ids, values, tie order) is bit-identical to
 the dense masked ``lax.top_k`` oracle and to the fused eval scorer on
 the same restored params (``tests/test_serve.py`` /
@@ -52,6 +61,7 @@ import argparse
 import bisect
 import contextlib
 import dataclasses
+import functools
 import sys
 import threading
 import time
@@ -309,6 +319,13 @@ class RetrievalServer:
         self.served = 0
         self.degraded_served = 0
         self.rejected = 0
+        # Batching counters, under ``_cond``: programs run and their
+        # summed bucket rows (fill = served / batch_rows), and the time
+        # async requests waited from submit to pick-up by the worker.
+        self.batches = 0
+        self.batch_rows = 0
+        self.queue_wait_s = 0.0
+        self.queue_wait_max_s = 0.0
 
         # Conformance readiness gate (kernels/guard): async submits are
         # rejected with ServerNotReadyError until the serve kernel's
@@ -366,12 +383,16 @@ class RetrievalServer:
             self.cache_misses += 1
             self._compile_bucket(bucket)
             fn = self._compiled[bucket]
-        tokens = jnp.asarray(tokens_padded, jnp.int32)
-        if self._tok_sharding is not None:
-            tokens = jax.device_put(tokens, self._tok_sharding)
-        with self._ctx():
-            vals, ids = fn(self.params, tokens)
-        return np.asarray(vals), np.asarray(ids)
+        with self._cond:
+            self.batches += 1
+            self.batch_rows += bucket
+        with jax.profiler.TraceAnnotation("repro.serve.run"):
+            tokens = jnp.asarray(tokens_padded, jnp.int32)
+            if self._tok_sharding is not None:
+                tokens = jax.device_put(tokens, self._tok_sharding)
+            with self._ctx():
+                vals, ids = fn(self.params, tokens)
+            return np.asarray(vals), np.asarray(ids)
 
     # -- readiness / health -------------------------------------------------
     def refresh_readiness(self) -> bool:
@@ -416,6 +437,12 @@ class RetrievalServer:
                 self._worker is not None and self._worker.is_alive()
             )
             closed = self._closed
+            batching = {
+                "batches": self.batches,
+                "batch_rows": self.batch_rows,
+                "queue_wait_s": self.queue_wait_s,
+                "queue_wait_max_s": self.queue_wait_max_s,
+            }
         return {
             "ready": self._ready,
             "readiness_error": self.readiness_error,
@@ -429,6 +456,7 @@ class RetrievalServer:
             "rejected": self.rejected,
             "compile_count": self.compile_count,
             "cache_misses": self.cache_misses,
+            **batching,
             "conformance": guard.verdict_table(),
         }
 
@@ -500,14 +528,21 @@ class RetrievalServer:
     def _worker_loop(self) -> None:
         while True:
             with self._cond:
-                while not self._queue and not self._closed:
-                    self._cond.wait()
+                if not self._queue and not self._closed:
+                    with jax.profiler.TraceAnnotation("repro.serve.idle"):
+                        while not self._queue and not self._closed:
+                            self._cond.wait()
                 if not self._queue and self._closed:
                     return
                 batch: List[Request] = []
                 while self._queue and len(batch) < self.router.max_bucket:
                     batch.append(self._queue.popleft())
                 backlog = len(self._queue)
+                now = time.monotonic()
+                for r in batch:
+                    wait = now - r.t_submit
+                    self.queue_wait_s += wait
+                    self.queue_wait_max_s = max(self.queue_wait_max_s, wait)
             try:
                 self._serve_batch(
                     batch, overloaded=backlog >= self.degrade_depth
@@ -523,21 +558,27 @@ class RetrievalServer:
                         self.rejected += 1
                         r._fail(err)
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="repro.serve.batch")
     def _serve_batch(self, batch: List[Request], *, overloaded: bool) -> None:
         bucket = self.router.bucket_for(len(batch))
-        tokens = pad_to_bucket(np.stack([r.history for r in batch]), bucket)
+        with jax.profiler.TraceAnnotation("repro.serve.pad"):
+            tokens = pad_to_bucket(
+                np.stack([r.history for r in batch]), bucket
+            )
         vals, ids = self._run(bucket, tokens)
-        now = time.monotonic()
-        for i, req in enumerate(batch):
-            expired = req.deadline is not None and now > req.deadline
-            degraded = overloaded or expired
-            k = self.degraded_top_k if degraded else self.top_k
-            self.served += 1
-            self.degraded_served += int(degraded)
-            req._finish(ServeResult(
-                ids=ids[i, :k].copy(), vals=vals[i, :k].copy(),
-                degraded=degraded, k=k,
-            ))
+        with jax.profiler.TraceAnnotation("repro.serve.reply"):
+            now = time.monotonic()
+            for i, req in enumerate(batch):
+                expired = req.deadline is not None and now > req.deadline
+                degraded = overloaded or expired
+                k = self.degraded_top_k if degraded else self.top_k
+                self.served += 1
+                self.degraded_served += int(degraded)
+                req._finish(ServeResult(
+                    ids=ids[i, :k].copy(), vals=vals[i, :k].copy(),
+                    degraded=degraded, k=k,
+                ))
 
     def close(self, timeout: float = 10.0) -> None:
         """Stop serving: pending (not-yet-batched) requests are rejected

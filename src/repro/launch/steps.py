@@ -18,6 +18,12 @@ Training steps implement (DESIGN.md §4):
   * optional int8 error-feedback gradient compression inside the wrapped
     optimizer (shrinks the cross-pod DCI payload);
   * MoE aux load-balance loss folded in.
+
+The seqrec and LM train steps name their phases for the profiler
+(``jax.named_scope``, HLO ``op_name`` metadata only): ``backbone``
+(embedding lookup and encoder, forward and backward), ``select`` and
+``gather_loss`` (inside ``core/distributed_sce``) and ``optim`` (the
+guarded optimizer update).
 """
 from __future__ import annotations
 
@@ -75,18 +81,19 @@ def _apply_update_guarded(opt_update, loss, grads, params, opt_state,
     divergence guard keys on ``skipped`` rather than re-deriving
     finiteness from a float round trip, and on a strike the sentinels
     name WHICH kernel went non-finite (kernels/guard/sentinels.py)."""
-    gnorm = global_norm(grads)
-    ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-    if loss_cap is not None:
-        ok &= loss <= loss_cap
-    new_params, new_opt = opt_update(grads, opt_state, params)
-    keep = lambda new, old: jax.tree.map(
-        lambda n, o: jnp.where(ok, n, o), new, old
-    )
-    metrics = {"loss": loss, "skipped": ~ok, "grad_norm": gnorm}
-    if sentinels:
-        metrics["sentinels"] = dict(sentinels)
-    return keep(new_params, params), keep(new_opt, opt_state), metrics
+    with jax.named_scope("optim"):
+        gnorm = global_norm(grads)
+        ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+        if loss_cap is not None:
+            ok &= loss <= loss_cap
+        new_params, new_opt = opt_update(grads, opt_state, params)
+        keep = lambda new, old: jax.tree.map(
+            lambda n, o: jnp.where(ok, n, o), new, old
+        )
+        metrics = {"loss": loss, "skipped": ~ok, "grad_norm": gnorm}
+        if sentinels:
+            metrics["sentinels"] = dict(sentinels)
+        return keep(new_params, params), keep(new_opt, opt_state), metrics
 
 
 def build_sce_config(
@@ -181,9 +188,11 @@ def _vocab_loss(
         return loss, {}
     sentinels = aux.get("sentinels")
     if sentinels is None:
-        sentinels = guard.loss_sentinels(
-            _SENTINEL_KERNEL.get(loss_name, loss_name), loss
-        )
+        # the guard's finiteness counters, profiled with its update gate
+        with jax.named_scope("optim"):
+            sentinels = guard.loss_sentinels(
+                _SENTINEL_KERNEL.get(loss_name, loss_name), loss
+            )
     return loss, sentinels
 
 
@@ -289,9 +298,11 @@ def make_lm_train_step(
 
     def loss_and_grad(params, mb, key):
         def loss_fn(p):
-            hidden, aux = tf_lib.forward(p, cfg, mb["tokens"])
-            x = hidden.reshape(-1, hidden.shape[-1])
-            y = tf_lib.output_embedding(p, cfg)  # padded rows = phantom negs
+            with jax.named_scope("backbone"):
+                hidden, aux = tf_lib.forward(p, cfg, mb["tokens"])
+                x = hidden.reshape(-1, hidden.shape[-1])
+                # padded rows act as phantom negatives
+                y = tf_lib.output_embedding(p, cfg)
             loss, sentinels = _vocab_loss(
                 x,
                 y,
@@ -380,19 +391,20 @@ def make_seqrec_train_step(
 
         def loss_fn(p):
             tokens = mb["tokens"]
-            if bidirectional:
-                masked, is_masked = b4r_lib.apply_cloze_mask(
-                    k_mask, tokens, cfg
-                )
-                hidden = b4r_lib.forward(p, cfg, masked)
-                targets = tokens.reshape(-1)
-                valid = is_masked.reshape(-1)
-            else:
-                hidden = sasrec_lib.forward(p, cfg, tokens)
-                targets = mb["targets"].reshape(-1)
-                valid = mb["valid"].reshape(-1)
-            x = hidden.reshape(-1, hidden.shape[-1])
-            y = sasrec_lib.loss_catalog(p, cfg)  # shard-even slice
+            with jax.named_scope("backbone"):
+                if bidirectional:
+                    masked, is_masked = b4r_lib.apply_cloze_mask(
+                        k_mask, tokens, cfg
+                    )
+                    hidden = b4r_lib.forward(p, cfg, masked)
+                    targets = tokens.reshape(-1)
+                    valid = is_masked.reshape(-1)
+                else:
+                    hidden = sasrec_lib.forward(p, cfg, tokens)
+                    targets = mb["targets"].reshape(-1)
+                    valid = mb["valid"].reshape(-1)
+                x = hidden.reshape(-1, hidden.shape[-1])
+                y = sasrec_lib.loss_catalog(p, cfg)  # shard-even slice
             return _vocab_loss(
                 x, y, targets, valid, k_loss,
                 loss_name=arch.train_loss,

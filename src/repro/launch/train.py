@@ -40,6 +40,12 @@ arrays. Production behavior:
   * ``--metrics-file`` appends one JSON line per completed step
     (step/loss/skipped/grad_norm) — the kill-drills diff these curves
     step-for-step across kill/restore boundaries.
+  * **profiler spans**: each loop iteration is a
+    ``StepTraceAnnotation("train")``, with host spans
+    ``repro.train.place`` (batch to device) and ``repro.train.readback``
+    (the loss sync) beside the data generator's ``repro.data.next_batch``;
+    the step's device ops carry the ``backbone`` / ``select`` /
+    ``gather_loss`` / ``optim`` scopes (``launch/steps.py``).
 
 By default each arch's reduced smoke config trains (CPU-runnable);
 ``--shape <ShapeSpec name>`` selects the arch's full config
@@ -397,112 +403,115 @@ def train(
             if preemption.preempted:
                 preempted = True
                 break
-            if chaos_nan_at is not None and step == chaos_nan_at \
-                    and not chaos_fired:
-                chaos_fired = True
-                print(f"[chaos] step {step}: poisoning params with NaN")
-                state.params = jax.tree.map(
-                    lambda p: (p * jnp.nan).astype(p.dtype)
-                    if jnp.issubdtype(p.dtype, jnp.floating) else p,
-                    state.params,
-                )
-            t0 = time.time()
-            host_batch, new_cursor = _host_batch(
-                arch, data, state.cursor, shape, cfg, n_hosts
-            )
-            t_data = time.time() - t0
-            # Straggler mitigation: if data loading stalls, reuse the
-            # previous batch (bounded staleness) instead of blocking.
-            if (
-                skip_stragglers
-                and prev_batch is not None
-                and times
-                and t_data > watchdog * statistics.median(times)
-            ):
-                host_batch = prev_batch
-                print(f"[watchdog] step {step}: slow input shard "
-                      f"({t_data:.2f}s) — reusing previous batch")
-                new_cursor = state.cursor
-            else:
-                prev_batch = host_batch
-
-            state.key, step_key = jax.random.split(state.key)
-            dev_batch = jax.tree.map(place, host_batch)
-            dev_batch["loss_cap"] = jnp.float32(guard.loss_cap())
-            state.params, state.opt_state, metrics = jit_step(
-                state.params, state.opt_state, dev_batch, step_key
-            )
-            loss = float(metrics["loss"])
-            skipped = bool(metrics.get("skipped", False))
-            grad_norm = float(metrics.get("grad_norm", np.nan))
-            # Tripped numerics sentinels (kernels/guard): nonzero
-            # per-kernel counters naming what went non-finite on-device.
-            tripped = {
-                k: int(v)
-                for k, v in metrics.get("sentinels", {}).items()
-                if int(v)
-            }
-            state.cursor = new_cursor
-            state.step = step
-            dt = time.time() - t0
-            losses.append(loss)
-            times.append(dt)
-            record(step, loss, skipped, grad_norm, tripped)
-
-            verdict = guard.observe(loss, skipped=skipped)
-            if verdict != "ok":
-                skipped_steps += 1
-                blame = (
-                    f" (sentinels: {kguard.describe_sentinels(tripped)})"
-                    if tripped else ""
-                )
-                print(f"[guard] step {step}: loss {loss:.4g} "
-                      f"grad_norm {grad_norm:.4g} — update skipped "
-                      f"(strike {guard.strikes or guard.max_strikes}"
-                      f"/{guard.max_strikes}){blame}")
-            if verdict == "rollback":
-                if mgr is None:
-                    raise RuntimeError(
-                        f"diverged for {guard.max_strikes} consecutive "
-                        f"steps at step {step} and no --ckpt-dir to roll "
-                        f"back to"
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                if chaos_nan_at is not None and step == chaos_nan_at \
+                        and not chaos_fired:
+                    chaos_fired = True
+                    print(f"[chaos] step {step}: poisoning params with NaN")
+                    state.params = jax.tree.map(
+                        lambda p: (p * jnp.nan).astype(p.dtype)
+                        if jnp.issubdtype(p.dtype, jnp.floating) else p,
+                        state.params,
                     )
-                mgr.wait()  # an in-flight async save must land first
-                rolled, last = _restore_or(
-                    dataclasses.replace(state)
+                t0 = time.perf_counter()
+                host_batch, new_cursor = _host_batch(
+                    arch, data, state.cursor, shape, cfg, n_hosts
                 )
-                if last is None:
-                    raise RuntimeError(
-                        "diverged and no intact checkpoint to roll "
-                        "back to"
-                    )
-                state = rolled
-                state.cursor = guard.reseed(state.cursor)
-                print(f"[guard] rolled back to verified step {last} "
-                      f"(rollback #{guard.rollbacks}, data offset "
-                      f"+{guard.reseed_stride * guard.rollbacks})")
-                step = state.step + 1
-                continue
-
-            if times and dt > watchdog * statistics.median(times):
-                print(f"[watchdog] step {step} took {dt:.2f}s "
-                      f"(median {statistics.median(times):.2f}s)")
-            if step % log_every == 0:
-                print(f"step {step:5d}  loss {loss:.4f}  {dt*1e3:.0f} ms")
-            if do_eval and (step + 1) % eval_every == 0:
-                if protocol == "token-rank":
-                    eval_metrics = evaluate_streaming_lm(
-                        state.params, cfg, eval_batch, mesh=eval_mesh
-                    )
+                t_data = time.perf_counter() - t0
+                # Straggler mitigation: if data loading stalls, reuse the
+                # previous batch (bounded staleness) instead of blocking.
+                if (
+                    skip_stragglers
+                    and prev_batch is not None
+                    and times
+                    and t_data > watchdog * statistics.median(times)
+                ):
+                    host_batch = prev_batch
+                    print(f"[watchdog] step {step}: slow input shard "
+                          f"({t_data:.2f}s) — reusing previous batch")
+                    new_cursor = state.cursor
                 else:
-                    eval_metrics = evaluate_streaming(
-                        state.params, cfg, eval_batch, mesh=eval_mesh
+                    prev_batch = host_batch
+
+                state.key, step_key = jax.random.split(state.key)
+                with jax.profiler.TraceAnnotation("repro.train.place"):
+                    dev_batch = jax.tree.map(place, host_batch)
+                    dev_batch["loss_cap"] = jnp.float32(guard.loss_cap())
+                state.params, state.opt_state, metrics = jit_step(
+                    state.params, state.opt_state, dev_batch, step_key
+                )
+                with jax.profiler.TraceAnnotation("repro.train.readback"):
+                    loss = float(metrics["loss"])
+                    skipped = bool(metrics.get("skipped", False))
+                    grad_norm = float(metrics.get("grad_norm", np.nan))
+                    # Tripped numerics sentinels (kernels/guard): nonzero
+                    # per-kernel counters naming what went non-finite.
+                    tripped = {
+                        k: int(v)
+                        for k, v in metrics.get("sentinels", {}).items()
+                        if int(v)
+                    }
+                state.cursor = new_cursor
+                state.step = step
+                dt = time.perf_counter() - t0
+                losses.append(loss)
+                times.append(dt)
+                record(step, loss, skipped, grad_norm, tripped)
+
+                verdict = guard.observe(loss, skipped=skipped)
+                if verdict != "ok":
+                    skipped_steps += 1
+                    blame = (
+                        f" (sentinels: {kguard.describe_sentinels(tripped)})"
+                        if tripped else ""
                     )
-                shown = {k: round(v, 4) for k, v in eval_metrics.items()}
-                print(f"[eval] step {step}: {shown}")
-            if mgr is not None and mgr.should_save(step):
-                save_state(blocking=False)
-            step += 1
+                    print(f"[guard] step {step}: loss {loss:.4g} "
+                          f"grad_norm {grad_norm:.4g} — update skipped "
+                          f"(strike {guard.strikes or guard.max_strikes}"
+                          f"/{guard.max_strikes}){blame}")
+                if verdict == "rollback":
+                    if mgr is None:
+                        raise RuntimeError(
+                            f"diverged for {guard.max_strikes} consecutive "
+                            f"steps at step {step} and no --ckpt-dir to roll "
+                            f"back to"
+                        )
+                    mgr.wait()  # an in-flight async save must land first
+                    rolled, last = _restore_or(
+                        dataclasses.replace(state)
+                    )
+                    if last is None:
+                        raise RuntimeError(
+                            "diverged and no intact checkpoint to roll "
+                            "back to"
+                        )
+                    state = rolled
+                    state.cursor = guard.reseed(state.cursor)
+                    print(f"[guard] rolled back to verified step {last} "
+                          f"(rollback #{guard.rollbacks}, data offset "
+                          f"+{guard.reseed_stride * guard.rollbacks})")
+                    step = state.step + 1
+                    continue
+
+                if times and dt > watchdog * statistics.median(times):
+                    print(f"[watchdog] step {step} took {dt:.2f}s "
+                          f"(median {statistics.median(times):.2f}s)")
+                if step % log_every == 0:
+                    print(f"step {step:5d}  loss {loss:.4f}  {dt*1e3:.0f} ms")
+                if do_eval and (step + 1) % eval_every == 0:
+                    if protocol == "token-rank":
+                        eval_metrics = evaluate_streaming_lm(
+                            state.params, cfg, eval_batch, mesh=eval_mesh
+                        )
+                    else:
+                        eval_metrics = evaluate_streaming(
+                            state.params, cfg, eval_batch, mesh=eval_mesh
+                        )
+                    shown = {k: round(v, 4) for k, v in eval_metrics.items()}
+                    print(f"[eval] step {step}: {shown}")
+                if mgr is not None and mgr.should_save(step):
+                    save_state(blocking=False)
+                step += 1
         if preemption.preempted and not preempted:
             preempted = True  # signal arrived during the final step
 

@@ -172,3 +172,34 @@ def test_linear_ce_compiles(one_chip, grad):
     fn = jax.grad(loss, argnums=(0, 1)) if grad else loss
     text = _compile(fn, one_chip, ((N, D), F32), ((C, D), F32), ((N,), I32))
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+def test_train_step_scopes(one_chip, topo, monkeypatch):
+    """The sasrec-sce smoke train step with its Pallas kernels, compiled
+    for one v5e: each ``mips_topk`` call sits under ``select/positions``
+    or ``select/catalog``, each ``sce_gather`` call under
+    ``gather_loss``, and every other op the profiler can name under one
+    of the four scopes (``_scopes.unscoped``)."""
+    from jax.sharding import AxisType
+
+    from _scopes import instructions, scope, seqrec_step_text, unscoped
+    from repro.kernels import guard, ops
+
+    # the kernels' TPU path, as on the chip; its canaries need a chip
+    monkeypatch.setattr(ops, "_default_backend", lambda: "tpu")
+    monkeypatch.setattr(guard, "kernel_enabled", lambda *a, **k: True)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=topo.devices[:1])
+    text = seqrec_step_text(batch=8, seq_len=32, compile_for=mesh)
+    kernels = {}
+    for name, kind, _, _, op in instructions(text):
+        if kind == "custom-call" and op and "pallas_call" in op:
+            kernels.setdefault(name.split(".")[0], set()).add(
+                "/".join(scope(op).split("/")[:2]))
+    assert kernels == {
+        "mips_topk": {"select/positions", "select/catalog"},
+        "sce_gather_fwd": {"gather_loss/while"},
+        "sce_gather_bwd": {"gather_loss/while"},
+    }
+    assert unscoped(text) == []

@@ -12,8 +12,7 @@ duplicated sweep is pure FLOP/HBM waste. Here one matmul per
 ``(block_c, d)`` tile feeds **four accumulators**:
 
   * ``(topk_vals, topk_ids)`` — the ``(block_b, K)`` merge buffer
-    (shared ``kernels/topk_merge.py`` recurrence, dense-``lax.top_k``
-    tie order);
+    (``topk_merge.insert_topk_tile``, dense-``lax.top_k`` tie order);
   * ``(gt, eq)`` — rank counts vs the target score (raw logits: ranks
     are softcap-invariant);
   * an optional f32 online-LSE ``(m, s)`` carry over the *softcapped*
@@ -66,7 +65,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import dot, pad_to, sds
 from repro.kernels.topk_merge import ID_PAD as _ID_PAD
-from repro.kernels.topk_merge import merge_topk_tile
+from repro.kernels.topk_merge import buffer_lanes, insert_topk_tile
 
 NEG_INF = -1e30
 
@@ -160,9 +159,9 @@ def _fused_kernel(
         axis=-1, keepdims=True,
     )
 
-    # Shared first-occurrence-argmax merge — raw logits, dense tie rule.
-    vals_scr[...], ids_scr[...] = merge_topk_tile(
-        vals_scr[...], ids_scr[...], s, col, k
+    # The shared insertion merge — raw logits, dense tie rule.
+    vals_scr[...], ids_scr[...] = insert_topk_tile(
+        vals_scr[...], ids_scr[...], s, j * block_c, k
     )
 
     if with_lse:
@@ -182,8 +181,8 @@ def _fused_kernel(
 
     @pl.when(j == n_c_tiles - 1)
     def _finalize():
-        vals_ref[...] = vals_scr[...].astype(vals_ref.dtype)
-        ids_ref[...] = ids_scr[...]
+        vals_ref[...] = vals_scr[:, :k].astype(vals_ref.dtype)
+        ids_ref[...] = ids_scr[:, :k]
         gt_ref[...] = gt_scr[...]
         eq_ref[...] = eq_scr[...]
         if with_lse:
@@ -378,8 +377,8 @@ def eval_fused(
         sds((n_p, k), jnp.int32, *operands),
     ] + [sds((n_p, 1), dt, *operands) for dt in row_dtypes]
     scratch = [
-        pltpu.VMEM((block_b, k), jnp.float32),
-        pltpu.VMEM((block_b, k), jnp.int32),
+        pltpu.VMEM((block_b, buffer_lanes(k)), jnp.float32),
+        pltpu.VMEM((block_b, buffer_lanes(k)), jnp.int32),
     ] + [pltpu.VMEM((block_b, 1), dt) for dt in row_dtypes]
 
     outs = pl.pallas_call(

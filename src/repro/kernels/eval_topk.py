@@ -11,10 +11,10 @@ This kernel streams the catalog embedding table through VMEM in
 ``(block_c, d)`` tiles and keeps only per-row running accumulators:
 
   * ``(topk_vals, topk_ids)`` — a ``(block_b, K)`` merge buffer updated
-    per tile by the shared first-occurrence-argmax recurrence of
-    ``kernels/topk_merge.py`` (max/min/where only — no sort,
-    Mosaic-friendly; the same implementation drives the MIPS
-    candidate-selection kernel ``kernels/mips_topk.py``);
+    per tile by the shared insertion merge of ``kernels/topk_merge.py``
+    (max/min/where and a lane shift only — no sort, Mosaic-friendly;
+    the same implementation drives the MIPS candidate-selection kernel
+    ``kernels/mips_topk.py``);
   * ``(gt, eq)`` — counts of catalog scores strictly greater than /
     exactly equal to the target score, from which the caller derives the
     pessimistic-tie rank ``gt + max(eq - 1, 0)`` (see
@@ -24,8 +24,8 @@ Peak live elements are ``O(B·(K + block_c))`` instead of ``O(B·C)``.
 
 Tie order matches a dense ``jax.lax.top_k`` exactly: tiles arrive in
 ascending-id order, the merge buffer keeps equal values in
-ascending-global-id order (first-occurrence extraction preserves it by
-induction), so ties always resolve toward the lower catalog id.
+ascending-global-id order (each entry goes after the equal values
+already buffered), so ties always resolve toward the lower catalog id.
 
 The target score is an INPUT. A gather-einsum (the ``fused_ce``
 positive-term trick) is the cheap way to produce it, but measured on CPU
@@ -50,7 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import dot, pad_to, sds
 from repro.kernels.topk_merge import ID_PAD as _ID_PAD
-from repro.kernels.topk_merge import merge_topk_tile
+from repro.kernels.topk_merge import buffer_lanes, insert_topk_tile
 
 NEG_INF = -1e30
 
@@ -63,8 +63,8 @@ def _eval_kernel(
     ids_ref,  # (block_b, k) i32 out
     gt_ref,  # (block_b,) i32 out
     eq_ref,  # (block_b,) i32 out
-    vals_scr,  # (block_b, k) f32
-    ids_scr,  # (block_b, k) i32
+    vals_scr,  # (block_b, k rounded up to 128 lanes) f32
+    ids_scr,  # (block_b, k rounded up to 128 lanes) i32
     gt_scr,  # (block_b,) i32
     eq_scr,  # (block_b,) i32
     *,
@@ -102,17 +102,17 @@ def _eval_kernel(
     gt_scr[...] += jnp.sum((s > tgt).astype(jnp.int32), axis=-1)
     eq_scr[...] += jnp.sum((s == tgt).astype(jnp.int32), axis=-1)
 
-    # Merge the running top-k buffer with this tile's scores — the shared
-    # first-occurrence-argmax recurrence (ties → earliest concat position
-    # → lowest global id, the dense lax.top_k rule; see topk_merge.py).
-    vals_scr[...], ids_scr[...] = merge_topk_tile(
-        vals_scr[...], ids_scr[...], s, col, k
+    # Merge this tile's scores into the running top-k buffer — the
+    # shared insertion merge (ties → lowest global id, the dense
+    # lax.top_k rule; see topk_merge.py).
+    vals_scr[...], ids_scr[...] = insert_topk_tile(
+        vals_scr[...], ids_scr[...], s, id_offset + j * block_c, k
     )
 
     @pl.when(j == n_c_tiles - 1)
     def _finalize():
-        vals_ref[...] = vals_scr[...].astype(vals_ref.dtype)
-        ids_ref[...] = ids_scr[...]
+        vals_ref[...] = vals_scr[:, :k].astype(vals_ref.dtype)
+        ids_ref[...] = ids_scr[:, :k]
         gt_ref[...] = gt_scr[...]
         eq_ref[...] = eq_scr[...]
 
@@ -239,8 +239,8 @@ def eval_topk(
             sds((n_p,), jnp.int32, tp, xp, yp),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_b, k), jnp.float32),
-            pltpu.VMEM((block_b, k), jnp.int32),
+            pltpu.VMEM((block_b, buffer_lanes(k)), jnp.float32),
+            pltpu.VMEM((block_b, buffer_lanes(k)), jnp.int32),
             pltpu.VMEM((block_b,), jnp.int32),
             pltpu.VMEM((block_b,), jnp.int32),
         ],

@@ -115,10 +115,11 @@ class PreflightResult:
 
 
 def _merge_elements(block_rows: int, block_cols: int, k: int) -> int:
-    """The streaming top-k merge: (vals, ids) buffers plus the live
-    (value, id, position) working set over the (k + tile)-wide
-    concatenation (``kernels/topk_merge.py``)."""
-    return 2 * block_rows * k + 3 * block_rows * (k + block_cols)
+    """The streaming top-k merge (``topk_merge.insert_topk_tile``): the
+    lane-aligned (vals, ids) scratch and their loop carry, beside the
+    tile's scores and the candidates left of them."""
+    return (4 * block_rows * _round_up(k, LANE)
+            + 2 * block_rows * block_cols)
 
 
 def _scratch_elements(kernel: str, block_rows: int, block_cols: int,

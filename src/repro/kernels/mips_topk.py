@@ -12,11 +12,12 @@ becomes the peak.
 
 This kernel is the selection twin of ``kernels/eval_topk.py``: it
 streams the catalog through VMEM in ``(block_c, d)`` tiles and carries
-only the ``(block_q, K)`` top-k merge buffer per bucket row — the
-shared first-occurrence-argmax recurrence of ``kernels/topk_merge.py``,
-so tie order is bit-identical to a dense ``lax.top_k`` (lowest index
-wins). Peak live score elements drop from ``O(n_b·C)`` to
-``O(n_b·(K + block_c))``.
+only the ``(block_q, K)`` top-k merge buffer per bucket row. Each tile
+is merged by ``topk_merge.insert_topk_tile``: only the tile entries
+that beat the buffer's k-th value are inserted, so the merge's rounds
+follow the data rather than ``K``. Tie order is bit-identical to a
+dense ``lax.top_k`` (lowest index wins). Peak live score elements drop
+from ``O(n_b·C)`` to ``O(n_b·(K + block_c))``.
 
 One kernel covers both selection sides:
 
@@ -40,18 +41,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import dot, pad_to, sds
-from repro.kernels.topk_merge import (
-    ID_PAD,
-    merge_topk_tile,
-    merge_topk_tile_bitonic,
-)
+from repro.kernels.topk_merge import ID_PAD, buffer_lanes, insert_topk_tile
 
 NEG_INF = -1e30
-
-_MERGE_IMPLS = {
-    "rounds": merge_topk_tile,
-    "bitonic": merge_topk_tile_bitonic,
-}
 
 
 def _mips_kernel(
@@ -60,39 +52,33 @@ def _mips_kernel(
     valid_ref,  # (1, block_c) i32 — 1 on selectable rows, 0 on the tail
     vals_ref,  # (block_q, k) f32 out
     ids_ref,  # (block_q, k) i32 out
-    vals_scr,  # (block_q, k) f32
-    ids_scr,  # (block_q, k) i32
+    vals_scr,  # (block_q, k rounded up to 128 lanes) f32
+    ids_scr,  # (block_q, k rounded up to 128 lanes) i32
     *,
     k: int,
     n_c_tiles: int,
     block_c: int,
-    merge_impl: str,
 ):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         vals_scr[...] = jnp.full_like(vals_scr, NEG_INF)
-        ids_scr[...] = jnp.full_like(
-            ids_scr, jnp.iinfo(jnp.int32).max
-        )
+        ids_scr[...] = jnp.full_like(ids_scr, ID_PAD)
 
     scores = dot(q_ref[...], y_ref[...].T)
-    idx = j * block_c + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 1
-    )
     # Mask caller-invalidated rows (padding positions on the X side) and
     # the padded tail, whose validity pads with 0.
     s = jnp.where(valid_ref[...] > 0, scores, NEG_INF)
 
-    vals_scr[...], ids_scr[...] = _MERGE_IMPLS[merge_impl](
-        vals_scr[...], ids_scr[...], s, idx, k
+    vals_scr[...], ids_scr[...] = insert_topk_tile(
+        vals_scr[...], ids_scr[...], s, j * block_c, k
     )
 
     @pl.when(j == n_c_tiles - 1)
     def _finalize():
-        vals_ref[...] = vals_scr[...]
-        ids_ref[...] = ids_scr[...]
+        vals_ref[...] = vals_scr[:, :k]
+        ids_ref[...] = ids_scr[:, :k]
 
 
 def mips_topk(
@@ -104,7 +90,6 @@ def mips_topk(
     block_q: int = 128,
     block_c: int = 512,
     id_offset: int = 0,
-    merge_impl: str = "rounds",
     interpret: bool = False,
 ):
     """Streaming per-row top-``k`` of ``q @ yᵀ`` without the ``(n_q, C)``
@@ -126,10 +111,6 @@ def mips_topk(
         be traced. The kernel selects local ids and the offset is added
         to every real id afterwards — a constant shift, so the tie order
         (lower id wins) is unchanged.
-    merge_impl : ``"rounds"`` (default — the shared K-round
-        first-occurrence-argmax) or ``"bitonic"`` (the prototype
-        partial sort for selection-sized ``K = b_y``; identical
-        outputs, see ``topk_merge.merge_topk_tile_bitonic``).
 
     Returns
     -------
@@ -155,11 +136,7 @@ def mips_topk(
     n_i, n_j = nq_p // block_q, c_p // block_c
 
     kernel = functools.partial(
-        _mips_kernel,
-        k=k,
-        n_c_tiles=n_j,
-        block_c=block_c,
-        merge_impl=merge_impl,
+        _mips_kernel, k=k, n_c_tiles=n_j, block_c=block_c
     )
     vals, ids = pl.pallas_call(
         kernel,
@@ -178,8 +155,8 @@ def mips_topk(
             sds((nq_p, k), jnp.int32, qp, yp, vp),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, k), jnp.float32),
-            pltpu.VMEM((block_q, k), jnp.int32),
+            pltpu.VMEM((block_q, buffer_lanes(k)), jnp.float32),
+            pltpu.VMEM((block_q, buffer_lanes(k)), jnp.int32),
         ],
         interpret=interpret,
         name="mips_topk",

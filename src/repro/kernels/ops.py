@@ -179,19 +179,13 @@ def mips_topk(
     block_q: int = 128,
     block_c: int = 512,
     id_offset: int = 0,
-    merge_impl: str = "rounds",
     interpret: bool | None = None,
 ):
     """Streaming per-row MIPS top-k of ``q @ yᵀ`` →
     ``(vals (n_q, k), ids (n_q, k))`` without the ``(n_q, C)`` score
     matrix. See kernels/mips_topk.py; inside ``shard_map`` in interpret
     mode the chunked pure-jnp reference runs instead — same outputs and
-    ``lax.top_k`` tie rule. ``merge_impl`` selects the
-    per-tile merge: ``"rounds"`` (default, the K-round
-    first-occurrence-argmax) or ``"bitonic"`` (the prototype partial
-    sort for selection-sized ``K = b_y`` — see
-    ``kernels/topk_merge.py``; identical outputs, differential-tested,
-    no default flip)."""
+    ``lax.top_k`` tie rule."""
     if interpret is None:
         interpret = _interpret_default()
     if interpret and _inside_shard_map(q, y):
@@ -209,7 +203,7 @@ def mips_topk(
     return _mips_topk.mips_topk(
         q, y, k,
         valid=valid, block_q=block_q, block_c=block_c,
-        id_offset=id_offset, merge_impl=merge_impl, interpret=interpret,
+        id_offset=id_offset, interpret=interpret,
     )
 
 

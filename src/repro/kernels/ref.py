@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from repro.kernels.common import vary_as
 
 NEG_INF = -1e30
+ID_PAD = jnp.iinfo(jnp.int32).max
 
 
 def _masked_neg_logits(x_b, y_b, tgt_b, cand_ids, logit_softcap=None):
@@ -106,7 +107,7 @@ def mips_topk_ref(
     q32 = q.astype(f32)
 
     vals0 = jnp.full((n_q, k), NEG_INF, f32)
-    ids0 = jnp.full((n_q, k), jnp.iinfo(jnp.int32).max, jnp.int32)
+    ids0 = jnp.full((n_q, k), ID_PAD, jnp.int32)
 
     def body(carry, jc):
         vals, ids = carry
@@ -126,6 +127,53 @@ def mips_topk_ref(
     carry0 = vary_as((vals0, ids0), q, y, valid, id_offset)
     (vals, ids), _ = jax.lax.scan(body, carry0, jnp.arange(n_chunks))
     return vals, ids
+
+
+def merge_topk_tile(vals, ids, tile_vals, tile_ids, k: int):
+    """``K`` first-occurrence-argmax rounds over the concatenation of a
+    running top-k buffer and one tile — the fixed-cost merge
+    ``topk_merge.insert_topk_tile`` replaced in the kernels, kept as
+    its oracle. Each round takes the row max, locates its earliest
+    concatenation position (buffer before tile, so the lower id wins a
+    tie), emits ``(val, id)`` and knocks the position out.
+
+    Parameters
+    ----------
+    vals : (rows, k) f32 running top-k values, descending; ``NEG_INF``
+        in unfilled slots.
+    ids : (rows, k) i32 matching ids; ``ID_PAD`` in unfilled slots.
+    tile_vals : (rows, t) f32 masked tile scores.
+    tile_ids : (rows, t) i32 global ids of the tile columns, ascending.
+    k : buffer width (static).
+
+    Returns
+    -------
+    (vals', ids') : the merged ``(rows, k)`` buffer, same invariants.
+    """
+    cat_v = jnp.concatenate([vals, tile_vals], axis=-1)
+    cat_i = jnp.concatenate([ids, tile_ids], axis=-1)
+    width = k + tile_vals.shape[-1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, cat_v.shape, 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+
+    def round_(r, carry):
+        cat_v, out_v, out_i = carry
+        m = jnp.max(cat_v, axis=-1, keepdims=True)
+        first = jnp.min(
+            jnp.where(cat_v == m, pos, width), axis=-1, keepdims=True
+        )
+        sel = pos == first
+        sel_id = jnp.sum(jnp.where(sel, cat_i, 0), axis=-1, keepdims=True)
+        sel_id = jnp.where(m == NEG_INF, ID_PAD, sel_id)
+        out_v = jnp.where(slot == r, m, out_v)
+        out_i = jnp.where(slot == r, sel_id, out_i)
+        return jnp.where(sel, NEG_INF, cat_v), out_v, out_i
+
+    _, out_v, out_i = jax.lax.fori_loop(
+        0, k, round_,
+        (cat_v, jnp.full_like(vals, NEG_INF), jnp.full_like(ids, ID_PAD)),
+    )
+    return out_v, out_i
 
 
 def eval_topk_ref(
@@ -166,7 +214,7 @@ def eval_topk_ref(
     tgt = tgt_scores.astype(f32)[:, None]
 
     vals0 = jnp.full((b, k), NEG_INF, f32)
-    ids0 = jnp.full((b, k), jnp.iinfo(jnp.int32).max, jnp.int32)
+    ids0 = jnp.full((b, k), ID_PAD, jnp.int32)
     cnt0 = jnp.zeros((b,), jnp.int32)
 
     def body(carry, jc):
@@ -330,7 +378,7 @@ def eval_fused_ref(
     tid = targets.astype(jnp.int32)[:, None]
 
     vals0 = jnp.full((b, k), NEG_INF, f32)
-    ids0 = jnp.full((b, k), jnp.iinfo(jnp.int32).max, jnp.int32)
+    ids0 = jnp.full((b, k), ID_PAD, jnp.int32)
     cnt0 = jnp.zeros((b,), jnp.int32)
     carry0 = (vals0, ids0, cnt0, cnt0)
     if with_lse:

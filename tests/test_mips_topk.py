@@ -12,10 +12,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core.sce import SCEConfig, make_bucket_centers, select_buckets
+from repro.kernels import eval_fused as ef
+from repro.kernels import eval_topk as et
+from repro.kernels import mips_topk as mk
 from repro.kernels import ops, ref
-from repro.kernels.topk_merge import ID_PAD, merge_topk_tile
+from repro.kernels.topk_merge import ID_PAD, buffer_lanes, insert_topk_tile
 from _exact import grid_normal
 
 NEG_INF = -1e30
@@ -278,7 +282,7 @@ def test_mips_topk_kernel_property_differential(
 )
 @hypothesis.settings(max_examples=8, deadline=None)
 def test_merge_topk_tile_property(seed, k, n_tiles, tile, tie_level):
-    """The shared merge recurrence in isolation: folding tiles one at a
+    """The K-round merge oracle in isolation: folding tiles one at a
     time equals one dense lax.top_k over the whole concatenation —
     values, ids, tie order, placeholder slots."""
     rng = np.random.default_rng(seed)
@@ -298,7 +302,7 @@ def test_merge_topk_tile_property(seed, k, n_tiles, tile, tie_level):
             t * tile + jnp.arange(tile, dtype=jnp.int32)[None, :],
             tile_scores.shape,
         )
-        vals, ids = merge_topk_tile(vals, ids, tile_scores, tile_ids, k)
+        vals, ids = ref.merge_topk_tile(vals, ids, tile_scores, tile_ids, k)
 
     want_v, want_i = jax.lax.top_k(jnp.asarray(scores), min(k, width))
     pad = k - min(k, width)
@@ -323,85 +327,181 @@ def test_mips_topk_kernel_selection_sized_k():
     _assert_topk_matches(ref_v, ref_i, want_v, want_i, None, k)
 
 
-@hypothesis.given(
-    seed=st.integers(0, 2**31 - 1),
-    k=st.integers(1, 64),
-    n_tiles=st.integers(1, 5),
-    tile=st.integers(1, 48),
-    tie_level=st.integers(0, 1),
-)
-@hypothesis.settings(max_examples=10, deadline=None)
-def test_merge_bitonic_equals_rounds_property(
-    seed, k, n_tiles, tile, tie_level
-):
-    """ISSUE 5 satellite: the bitonic partial-sort merge prototype must
-    be output-identical to the K-round merge — values, ids, tie order,
-    ID_PAD exhausted slots — across randomized buffer/tile widths
-    (incl. non-power-of-two), tie densities and NEG_INF holes, folding
-    tile-by-tile exactly like the kernels do."""
-    from repro.kernels.topk_merge import merge_topk_tile_bitonic
+# ---------------------------------------------------------------------------
+# The insertion merge (topk_merge.insert_topk_tile) that the streaming
+# top-k kernels run: differential against the K-round oracle
+# (ref.merge_topk_tile) and dense lax.top_k.
+# ---------------------------------------------------------------------------
+def _insert_fold(scores, k, tile):
+    """Fold ``(rows, width)`` scores tile by tile through
+    ``insert_topk_tile`` in an interpret-mode Pallas kernel (the merge's
+    lane shift is a Pallas TPU primitive), with the buffer at a multiple
+    of 128 lanes as the kernels keep it. Returns the first ``k``
+    slots."""
+    rows, width = scores.shape
+    n_t = -(-width // tile)
+    lanes = buffer_lanes(k)
+    sp = np.full((rows, n_t * tile), NEG_INF, np.float32)
+    sp[:, :width] = scores
 
-    rng = np.random.default_rng(seed)
-    rows, width = 4, n_tiles * tile
-    if tie_level:
-        scores = rng.integers(-3, 4, size=(rows, width)).astype(np.float32)
-    else:
-        scores = rng.normal(size=(rows, width)).astype(np.float32)
-    scores[rng.random((rows, width)) < 0.2] = NEG_INF
+    def kernel(s_ref, v_ref, i_ref):
+        j = pl.program_id(0)
 
-    v_r = v_b = jnp.full((rows, k), NEG_INF, jnp.float32)
-    i_r = i_b = jnp.full((rows, k), ID_PAD, jnp.int32)
-    for t in range(n_tiles):
-        tv = jnp.asarray(scores[:, t * tile:(t + 1) * tile])
-        ti = jnp.broadcast_to(
-            t * tile + jnp.arange(tile, dtype=jnp.int32)[None, :],
-            tv.shape,
+        @pl.when(j == 0)
+        def _init():
+            v_ref[...] = jnp.full_like(v_ref, NEG_INF)
+            i_ref[...] = jnp.full_like(i_ref, ID_PAD)
+
+        v_ref[...], i_ref[...] = insert_topk_tile(
+            v_ref[...], i_ref[...], s_ref[...], j * tile, k
         )
-        v_r, i_r = merge_topk_tile(v_r, i_r, tv, ti, k)
-        v_b, i_b = merge_topk_tile_bitonic(v_b, i_b, tv, ti, k)
-        np.testing.assert_array_equal(np.asarray(v_b), np.asarray(v_r))
-        np.testing.assert_array_equal(np.asarray(i_b), np.asarray(i_r))
+
+    vals, ids = pl.pallas_call(
+        kernel,
+        grid=(n_t,),
+        in_specs=[pl.BlockSpec((rows, tile), lambda j: (0, j))],
+        out_specs=[
+            pl.BlockSpec((rows, lanes), lambda j: (0, 0)),
+            pl.BlockSpec((rows, lanes), lambda j: (0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
+        ],
+        interpret=True,
+    )(jnp.asarray(sp))
+    return vals[:, :k], ids[:, :k]
 
 
-def test_mips_topk_bitonic_flag_matches_rounds(key):
-    """The ``merge_impl="bitonic"`` gate on the kernel: identical
-    outputs to the default K-round merge (and the dense oracle) on a
-    tie-heavy C % block != 0 case — no default flip, the flag is
-    opt-in."""
-    from repro.kernels import mips_topk as mk
+def _rounds_fold(scores, k, tile):
+    """The same fold through the K-round oracle ``ref.merge_topk_tile``."""
+    rows, width = scores.shape
+    vals = jnp.full((rows, k), NEG_INF, jnp.float32)
+    ids = jnp.full((rows, k), ID_PAD, jnp.int32)
+    for t0 in range(0, width, tile):
+        tv = jnp.asarray(scores[:, t0:t0 + tile])
+        ti = jnp.broadcast_to(
+            t0 + jnp.arange(tv.shape[1], dtype=jnp.int32)[None, :], tv.shape
+        )
+        vals, ids = ref.merge_topk_tile(vals, ids, tv, ti, k)
+    return vals, ids
 
-    kq, ky = jax.random.split(key)
-    q = jax.random.randint(kq, (9, 8), -3, 4).astype(jnp.float32)
-    y = jax.random.randint(ky, (90, 8), -2, 3).astype(jnp.float32)
-    y = y.at[45:].set(y[:45])
-    want_v, want_i = _dense_masked_topk(q, y, None, 20)
+
+def _merge_scores(seed, rows, width):
+    """Scores on a 1/8 grid (dense ties), 20% ``NEG_INF`` holes, and two
+    valid-starved rows that keep only a handful of scores. Adding 0
+    turns rounding's ``-0.0`` into ``0.0``: ``lax.top_k`` orders the
+    two zeros, the merges' comparisons do not."""
+    rng = np.random.default_rng(seed)
+    scores = (np.round(rng.normal(size=(rows, width)) * 8) / 8).astype(
+        np.float32) + np.float32(0)
+    scores[rng.random((rows, width)) < 0.2] = NEG_INF
+    scores[1, 3:] = NEG_INF
+    scores[-1, rng.random(width) < 0.95] = NEG_INF
+    return scores
+
+
+@pytest.mark.parametrize("k,tile,n_tiles", [
+    (10, 37, 5),
+    (64, 48, 4),
+    (256, 128, 4),
+    (320, 256, 3),
+    (64, 16, 7),   # K > tile: several tiles before the buffer is full
+])
+def test_insert_merge_equals_rounds_merge(k, tile, n_tiles):
+    """The insertion merge leaves exactly the K-round merge's buffer —
+    values, ids, tie order, ``ID_PAD`` exhausted slots — and both equal
+    a dense ``lax.top_k``, across tie-dense grid scores, ``NEG_INF``
+    holes and valid-starved rows."""
+    width = tile * n_tiles - tile // 3  # a short last tile
+    scores = _merge_scores(k * 1000 + tile, 6, width)
+    got_v, got_i = _insert_fold(scores, k, tile)
+    want_v, want_i = _rounds_fold(scores, k, tile)
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    dense_v, dense_i = jax.lax.top_k(jnp.asarray(scores), min(k, width))
+    _assert_topk_matches(got_v, got_i, dense_v, dense_i, None, k)
+
+
+@pytest.mark.parametrize("k,c,block_c,starve", [
+    (10, 700, 128, 0),
+    (64, 600, 128, 40),    # valid-starved: 40 selectable rows < k
+    (256, 900, 128, 0),
+    (320, 1100, 256, 0),
+    (100, 300, 32, 0),     # K > block_c
+])
+def test_mips_topk_insert_kernel_matches_dense(k, c, block_c, starve):
+    """The kernel at eval-, selection- and position-sized K, K above the
+    tile width and valid-starved catalogs: values, ids and tie order
+    equal the dense masked ``lax.top_k`` and the chunked reference."""
+    q, y, valid = _property_problem(k + c, c, 8, tie_level=1, starve=starve)
+    want_v, want_i = _dense_masked_topk(q, y, valid, k)
     got_v, got_i = mk.mips_topk(
-        q, y, 20, block_c=28, merge_impl="bitonic", interpret=True
+        q, y, k, valid=valid, block_c=block_c, interpret=True
     )
-    _assert_topk_matches(got_v, got_i, want_v, want_i, None, 20)
-    import inspect
-
-    # the gate must not flip by default
-    assert inspect.signature(ops.mips_topk).parameters[
-        "merge_impl"
-    ].default == "rounds"
+    _assert_topk_matches(got_v, got_i, want_v, want_i, valid, k)
+    ref_v, ref_i = ref.mips_topk_ref(q, y, k, valid=valid, chunk=block_c)
+    _assert_topk_matches(ref_v, ref_i, want_v, want_i, valid, k)
 
 
-@pytest.mark.slow
-def test_mips_topk_bitonic_selection_sized_k():
-    """The regime the prototype exists for — selection-sized
-    K = b_y = 256 (the K-round merge's named scaling concern,
-    KERNELS.md §mips_topk): bitonic and rounds kernels must agree
-    exactly with the dense oracle at production bucket size."""
-    from repro.kernels import mips_topk as mk
+def _monotone_problem(c, d, ascending):
+    """A catalog whose scores rise (or fall) with the item id for every
+    query row: ``y_i = t_i · u`` on a 1/8 grid with every ``q · u > 0``."""
+    t = np.arange(1, c + 1, dtype=np.float32) / 8
+    if not ascending:
+        t = t[::-1].copy()
+    y = np.zeros((c, d), np.float32)
+    y[:, 0] = t
+    rng = np.random.default_rng(c)
+    q = (np.round(rng.normal(size=(6, d)) * 8) / 8).astype(np.float32)
+    q[:, 0] = np.asarray([1, 2, 0.5, 3, 0.25, 1.5], np.float32)
+    return jnp.asarray(q), jnp.asarray(y)
 
-    k, c, d, block_c = 256, 600, 8, 128
-    q, y, _ = _property_problem(7, c, d, tie_level=1, starve=0)
-    want_v, want_i = _dense_masked_topk(q, y, None, k)
-    got_v, got_i = mk.mips_topk(
-        q, y, k, block_c=block_c, merge_impl="bitonic", interpret=True
-    )
-    _assert_topk_matches(got_v, got_i, want_v, want_i, None, k)
+
+@pytest.mark.parametrize("ascending", [True, False])
+def test_mips_topk_sorted_catalog(ascending):
+    """A catalog sorted by score ascending is the merge's worst case —
+    every tile beats the whole buffer, so each inserts ``min(K, tile)``
+    entries; sorted descending, no tile inserts any once the buffer is
+    full (during the first tile when K <= tile, the second when K >
+    tile). Both stay exact."""
+    c = 640
+    q, y = _monotone_problem(c, 8, ascending)
+    for k, block_c in ((96, 64), (40, 128)):
+        want_v, want_i = _dense_masked_topk(q, y, None, k)
+        got_v, got_i = mk.mips_topk(q, y, k, block_c=block_c,
+                                    interpret=True)
+        _assert_topk_matches(got_v, got_i, want_v, want_i, None, k)
+
+
+@pytest.mark.parametrize("kernel", ["eval_fused", "eval_topk"])
+@pytest.mark.parametrize("ascending", [True, False])
+def test_eval_kernels_sorted_catalog(kernel, ascending):
+    """The eval kernels run the same insertion merge: on a catalog
+    sorted by score ascending (every tile inserts ``K``) and descending
+    (none does once the buffer is full), at eval's ``K = 10`` with
+    ``K`` above the tile width too, their top-k equals the dense
+    ``lax.top_k`` and their rank counts the dense counts."""
+    c, block_c = 640, 64
+    q, y = _monotone_problem(c, 8, ascending)
+    targets = jnp.arange(q.shape[0], dtype=jnp.int32) * 97
+    scores = np.asarray(q @ y.T)
+    tgt = scores[np.arange(q.shape[0]), np.asarray(targets)]
+    for k in (10, 96):
+        want_v, want_i = _dense_masked_topk(q, y, None, k)
+        if kernel == "eval_fused":
+            got_v, got_i, gt, eq, *_ = ef.eval_fused(
+                q, y, targets, k, block_c=block_c, interpret=True
+            )
+        else:
+            got_v, got_i, gt, eq = et.eval_topk(
+                q, y, jnp.asarray(tgt), k, block_c=block_c, interpret=True
+            )
+        _assert_topk_matches(got_v, got_i, want_v, want_i, None, k)
+        np.testing.assert_array_equal(
+            np.asarray(gt), (scores > tgt[:, None]).sum(1))
+        np.testing.assert_array_equal(
+            np.asarray(eq), (scores == tgt[:, None]).sum(1))
 
 
 def test_mips_topk_exhausted_rows_use_placeholder(key):

@@ -27,6 +27,7 @@ C, D = 173_511, 64
 N = 128 * 200
 N_B, B_X, B_Y = 320, 320, 256
 EVAL_USERS, TOP_K = 512, 10
+SERVE_ROWS = 8  # the serve path's smallest request bucket
 
 
 @pytest.fixture(scope="module")
@@ -90,6 +91,17 @@ def test_mips_topk_compiles(one_chip, k, rows, with_valid):
         def fn(q, y):
             return mips_topk.mips_topk(q, y, k, block_q=bq, block_c=bc)
         shapes = [((N_B, D), F32), ((rows, D), F32)]
+    assert "tpu_custom_call" in _compile(fn, one_chip, *shapes)
+
+
+def test_mips_topk_serve_compiles(one_chip):
+    """Serve's top-10 of a small batch over the whole catalog."""
+    bq, bc = _blocks("mips_topk", SERVE_ROWS, C, k=TOP_K)
+
+    def fn(q, y, v):
+        return mips_topk.mips_topk(q, y, TOP_K, valid=v, block_q=bq,
+                                   block_c=bc)
+    shapes = [((SERVE_ROWS, D), F32), ((C, D), F32), ((C,), BOOL)]
     assert "tpu_custom_call" in _compile(fn, one_chip, *shapes)
 
 

@@ -33,6 +33,9 @@ class ArchSpec:
     make_smoke_config: Callable[[], Any]  # reduced config for CPU tests
     shapes: Tuple[ShapeSpec, ...]
     optimizer: str = "adamw"
+    # the optimizer's learning rate and settings (make_optimizer kwargs)
+    optimizer_args: Mapping[str, float] = dataclasses.field(
+        default_factory=lambda: {"lr": 3e-4})
     train_loss: str = "sce"  # lm/seqrec only
     dtype: str = "float32"
     fsdp: bool = False
@@ -77,6 +80,7 @@ _ARCH_MODULES = [
     "bert4rec",
     "xdeepfm",
     "sasrec_sce",
+    "moonlight",
 ]
 
 

@@ -3,8 +3,8 @@
 
 32L, d_model=1536, 24 heads (GQA kv=8, head_dim=64), per-expert d_ff=512,
 40 experts top-8, vocab=49155. ~3B total / ~0.8B active.
-Full attention → ``long_500k`` skip. 40 experts over a 16-way model axis
-shard unevenly — GSPMD pads to 48; noted in DESIGN.md §4.
+Full attention → ``long_500k`` skip. Softmax top-8 routing, computed
+dropless (``models/moe.py``).
 """
 from repro.configs.common import ArchSpec, lm_shapes, register
 from repro.models.moe import MoEConfig
@@ -22,9 +22,7 @@ def make_config(shape_name: str = "train_4k") -> TransformerConfig:
         d_ff=512,
         rope_theta=10000.0,
         tie_embeddings=True,
-        moe=MoEConfig(
-            n_experts=40, top_k=8, d_ff=512, capacity_factor=1.25
-        ),
+        moe=MoEConfig(n_experts=40, top_k=8, d_ff=512),
         dtype="bfloat16",
         remat=True,
     )

@@ -250,8 +250,6 @@ def transformer_param_specs(
     del inference  # layout is fsdp-driven; kwarg kept for call-site intent
     dp = data_axes(mesh) if fsdp else None
     d = cfg.d_model
-    hqd = cfg.n_heads_padded * cfg.head_dim
-    hkvd = cfg.n_kv_heads * cfg.head_dim
 
     def tp(dim):
         return _fit(mesh, dim, MODEL_AXIS)
@@ -259,47 +257,76 @@ def transformer_param_specs(
     def fs(dim):
         return _fit(mesh, dim, dp)
 
-    layers: Dict[str, Any] = {
-        "wq": P(None, fs(d), tp(hqd)),
-        "wk": P(None, fs(d), tp(hkvd)),
-        "wv": P(None, fs(d), tp(hkvd)),
-        "wo": P(None, tp(hqd), fs(d)),
-        "norm_attn": P(None, None),
-        "norm_mlp": P(None, None),
-    }
-    if cfg.use_post_norm:
-        layers["norm_attn_post"] = P(None, None)
-        layers["norm_mlp_post"] = P(None, None)
-    if cfg.moe is not None:
-        e = cfg.moe.n_experts_padded
-        f = cfg.moe.d_ff
-        moe: Dict[str, Any] = {
-            "router": P(None, None, None),  # tiny; replicated for routing
-            "w_gate": P(None, tp(e), fs(d), None),
-            "w_up": P(None, tp(e), fs(d), None),
-            "w_down": P(None, tp(e), None, fs(d)),
-        }
-        if cfg.moe.n_shared_experts:
-            fshared = f * cfg.moe.n_shared_experts
-            moe["shared"] = {
-                "w_gate": P(None, fs(d), tp(fshared)),
-                "w_up": P(None, fs(d), tp(fshared)),
-                "w_down": P(None, tp(fshared), fs(d)),
+    def attn() -> Dict[str, Any]:
+        h, m = cfg.n_heads, cfg.mla
+        if m is None:
+            hqd = h * cfg.head_dim
+            hkvd = cfg.n_kv_heads * cfg.head_dim
+            return {
+                "wq": P(None, fs(d), tp(hqd)),
+                "wk": P(None, fs(d), tp(hkvd)),
+                "wv": P(None, fs(d), tp(hkvd)),
+                "wo": P(None, tp(hqd), fs(d)),
             }
-        layers["moe"] = moe
-    else:
-        ff = cfg.d_ff
-        layers["mlp"] = {
-            "w_gate": P(None, fs(d), tp(ff)),
-            "w_up": P(None, fs(d), tp(ff)),
-            "w_down": P(None, tp(ff), fs(d)),
+        # latent attention: the latent projections are small and stay
+        # replicated; the per-head up-projections shard by heads
+        out = {
+            "wkv_a": P(None, fs(d), None),
+            "kv_norm": P(None, None),
+            "wkv_b": P(None, None,
+                       tp(h * (m.qk_nope_head_dim + m.v_head_dim))),
+            "wo": P(None, tp(h * m.v_head_dim), fs(d)),
         }
+        if m.q_lora_rank:
+            out["wq_a"] = P(None, fs(d), None)
+            out["q_norm"] = P(None, None)
+            out["wq_b"] = P(None, None, tp(h * m.qk_head_dim))
+        else:
+            out["wq"] = P(None, fs(d), tp(h * m.qk_head_dim))
+        return out
+
+    def layer_specs(moe: bool) -> Dict[str, Any]:
+        layers = attn()
+        layers["norm_attn"] = P(None, None)
+        layers["norm_mlp"] = P(None, None)
+        if cfg.use_post_norm:
+            layers["norm_attn_post"] = P(None, None)
+            layers["norm_mlp_post"] = P(None, None)
+        if moe:
+            e = cfg.moe.held
+            f = cfg.moe.d_ff
+            spec: Dict[str, Any] = {
+                "router": P(None, None, None),  # tiny; replicated for routing
+                "w_gate": P(None, tp(e), fs(d), None),
+                "w_up": P(None, tp(e), fs(d), None),
+                "w_down": P(None, tp(e), None, fs(d)),
+            }
+            if cfg.moe.n_shared_experts:
+                fshared = f * cfg.moe.n_shared_experts
+                spec["shared"] = {
+                    "w_gate": P(None, fs(d), tp(fshared)),
+                    "w_up": P(None, fs(d), tp(fshared)),
+                    "w_down": P(None, tp(fshared), fs(d)),
+                }
+            layers["moe"] = spec
+        else:
+            ff = cfg.d_ff
+            layers["mlp"] = {
+                "w_gate": P(None, fs(d), tp(ff)),
+                "w_up": P(None, fs(d), tp(ff)),
+                "w_down": P(None, tp(ff), fs(d)),
+            }
+        return layers
 
     specs: Dict[str, Any] = {
         "embed": P(tp(cfg.vocab_padded), fs(d)),
         "norm_final": P(None),
-        "layers": layers,
+        "layers": layer_specs(cfg.moe is not None),
     }
+    if cfg.n_dense_layers:
+        specs["dense_layers"] = layer_specs(False)
+    if cfg.moe is not None and cfg.moe.has_bias:
+        specs["router_bias"] = P(None, None)
     if not cfg.tie_embeddings:
         specs["unembed"] = P(tp(cfg.vocab_padded), fs(d))
     return specs

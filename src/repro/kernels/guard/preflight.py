@@ -130,12 +130,19 @@ def _scratch_elements(kernel: str, block_rows: int, block_cols: int,
     if kernel == "sce_bucket":
         return 2 * block_rows  # (m, s) online-LSE carries
     if kernel == "sce_gather":
-        # Rows are DMA'd at whole 128-lane width: the gathered tile, the
-        # dY RMW rows and their accumulator (block_cols each), and the
-        # per-bucket dX accumulator plus its double-buffered output
-        # block (rows each), plus the (m, s) carries.
+        # The backward kernel, at whole 128-lane rows: the gathered
+        # tile, the dY RMW rows and their accumulator, and the tile's
+        # values in flight (the tile, its dY part, the same-row sum):
+        # 6 × block_cols rows; the per-bucket dX accumulator and its
+        # double-buffered output block, whose rows are the bucket's
+        # padded to block_rows: 3 × that; the x block and the dX part in
+        # flight: 2 × block_rows; plus the (m, s) carries. (Held to
+        # Mosaic's accounting by tests/test_tpu_compile.py at d = 64 and
+        # d = 2,048.)
         dl = _round_up(d, LANE)
-        return 3 * (block_cols + rows) * dl + 2 * block_rows
+        rows_p = _round_up(rows, block_rows)
+        return (dl * (2 * block_rows + 3 * rows_p + 4 * block_cols)
+                + 2 * block_rows)
     if kernel == "mips_topk":
         return _merge_elements(block_rows, block_cols, kk)
     if kernel == "fused_ce":

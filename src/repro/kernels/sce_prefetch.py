@@ -14,7 +14,10 @@ before the first wait, so the row DMAs overlap each other.
 On TPU a row copy must span whole 128-lane rows of the HBM tiling, so
 the wrappers zero-pad ``d`` to a multiple of 128 for Mosaic (exact: the
 padded lanes add 0 to every dot product) and slice the gradients back;
-interpret mode copies the rows at their own width.
+interpret mode copies the rows at their own width. A table wider than
+128 lanes is tiled eight rows deep in HBM, where one row is no legal
+slice, so it is viewed as ``(C, 1, d)`` — one row per tile — and the
+row tiles in VMEM likewise (``_wide``).
 
 Forward grid ``(n_b, n_bx, n_by)``: one gathered ``(block_by, d)`` tile
 per step feeds one ``(block_bx × block_by)`` MXU matmul and the carried
@@ -63,6 +66,17 @@ from repro.kernels.common import dot, pad_to, psum_to, sds, vary_as
 
 NEG_INF = -1e30
 LANE = 128
+
+
+def _wide(dp: int) -> bool:
+    """Whether rows ``dp`` wide travel as ``(1, dp)`` tiles of a
+    ``(rows, 1, dp)`` view: wider than one 128-lane tile row."""
+    return dp > LANE
+
+
+def _tile(ref):
+    """The ``(rows, dp)`` value of a row-tile scratch of either layout."""
+    return ref[:, 0, :] if len(ref.shape) == 3 else ref[...]
 
 
 def _tile_mask(cand_row, tgt_col):
@@ -135,7 +149,7 @@ def _gfwd_kernel(
 
     _row_copies(idx_ref, n, jt * block_by, block_by, y_hbm, gather_scr,
                 sem, hbm_is_src=True)
-    logits = dot(x_ref[0], gather_scr[...].T)
+    logits = dot(x_ref[0], _tile(gather_scr).T)
     # Softcap INSIDE the tile, before the invalid mask (CE is not
     # cap-invariant; cap(NEG_INF) would be −cap). The folded positive is
     # pre-capped by the caller, so the m = pos init is consistent.
@@ -177,7 +191,7 @@ def _gbwd_kernel(
     lse_ref,  # (1, bx_t, 1) f32
     g_ref,  # (1, bx_t, 1) upstream cotangent
     x_ref,  # (1, bx_t, d)
-    y_hbm,  # (C, d) in HBM
+    y_hbm,  # (C, d) in HBM ((C, 1, d) when wide, as the three below)
     dyz_hbm,  # (C, d) zeros, aliased to dy_hbm
     dx_ref,  # (1, bx_p, d) out — written once per bucket
     dy_hbm,  # (C, d) out in HBM
@@ -210,7 +224,7 @@ def _gbwd_kernel(
         dy_acc[...] = jnp.zeros_like(dy_acc)
 
     x = x_ref[0]
-    tile = gather_scr[...]
+    tile = _tile(gather_scr)
     logits = dot(x, tile.T)
     capped = _capped(logits, logit_softcap)
     invalid = _tile_mask(cand_ref[0], tgt_ref[0])
@@ -235,7 +249,10 @@ def _gbwd_kernel(
         summed = dot(same_row, dy_acc[...])
         _row_copies(idx_ref, n, base, block_by, dy_hbm, rmw_scr, sem,
                     hbm_is_src=True, when=owned)
-        rmw_scr[...] += summed.astype(rmw_scr.dtype)
+        if len(rmw_scr.shape) == 3:
+            rmw_scr[:, 0, :] += summed.astype(rmw_scr.dtype)
+        else:
+            rmw_scr[...] += summed.astype(rmw_scr.dtype)
         _row_copies(idx_ref, n, base, block_by, rmw_scr, dy_hbm, sem,
                     hbm_is_src=False, when=owned)
 
@@ -258,6 +275,8 @@ def _prep(x_b, y, idx_y, tgt_b, cand_ids, block_bx, block_by, interpret):
     lane = 1 if interpret else LANE
     xp = pad_to(pad_to(x_b, 1, block_bx), 2, lane)
     yp = pad_to(y, 1, lane)
+    if _wide(yp.shape[1]):
+        yp = yp.reshape(c, 1, yp.shape[1])
     tp = pad_to(tgt_b[..., None], 1, block_bx, value=-2)
     # Padded gather slots repeat the bucket's LAST real row (edge pad):
     # an in-range row for the masked forward. The cand-id pad of -1
@@ -292,6 +311,7 @@ def _gfwd(x_b, y, idx_y, tgt_b, cand_ids, pos_logit, *, block_bx, block_by,
     )
     block_bx, block_by = s["block_bx"], s["block_by"]
     dp = xp.shape[-1]
+    row_tile = (block_by,) + yp.shape[1:]
 
     kernel = functools.partial(
         _gfwd_kernel,
@@ -324,7 +344,7 @@ def _gfwd(x_b, y, idx_y, tgt_b, cand_ids, pos_logit, *, block_bx, block_by,
             in_specs=in_specs,
             out_specs=[col] * len(out_dtypes),
             scratch_shapes=[
-                pltpu.VMEM((block_by, dp), y.dtype),
+                pltpu.VMEM(row_tile, y.dtype),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.VMEM((block_bx, 1), jnp.float32),
                 pltpu.VMEM((block_bx, 1), jnp.float32),
@@ -348,6 +368,7 @@ def _gbwd(x_b, y, idx_y, tgt_b, cand_ids, lse, g, *, block_bx, block_by,
     )
     block_bx, block_by = s["block_bx"], s["block_by"]
     d, dp, bx_p = s["d"], xp.shape[-1], s["bx_p"]
+    row_tile = (block_by,) + yp.shape[1:]
     lp = pad_to(lse[..., None], 1, block_bx)
     gp = pad_to(g[..., None], 1, block_bx)  # zero cotangent on padded rows
     col = _col_spec(block_bx, "nji")
@@ -393,8 +414,8 @@ def _gbwd(x_b, y, idx_y, tgt_b, cand_ids, lse, g, *, block_bx, block_by,
                 hbm,
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_by, dp), y.dtype),
-                pltpu.VMEM((block_by, dp), y.dtype),
+                pltpu.VMEM(row_tile, y.dtype),
+                pltpu.VMEM(row_tile, y.dtype),
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.VMEM((bx_p, dp), jnp.float32),
                 pltpu.VMEM((block_by, dp), jnp.float32),
@@ -410,7 +431,7 @@ def _gbwd(x_b, y, idx_y, tgt_b, cand_ids, lse, g, *, block_bx, block_by,
         name="sce_gather_bwd",
     )(ip, cp, *operands)
 
-    return dx[:, : s["b_x"], :d], dy[:, :d]
+    return dx[:, : s["b_x"], :d], dy.reshape(dy.shape[0], dp)[:, :d]
 
 
 # ---------------------------------------------------------------------------

@@ -41,6 +41,7 @@ from repro.dist.sharding import batch_spec, catalog_spec, replicated_spec
 from repro.eval.streaming import streaming_topk
 from repro.launch.mesh import dp_size
 from repro.models import bert4rec as b4r_lib
+from repro.models import moe as moe_lib
 from repro.models import recsys as recsys_lib
 from repro.models import sasrec as sasrec_lib
 from repro.models import schnet as schnet_lib
@@ -206,8 +207,9 @@ def _accumulate_microbatches(
     ``with_aux=False`` (legacy): the fn returns ``(loss, grads)``.
     ``with_aux=True``: the fn returns ``(loss, aux, grads)`` where
     ``aux`` is a dict of on-device counters (the guard's numerics
-    sentinels) summed across microbatches; the result is
-    ``(loss, aux, grads)``."""
+    sentinels) summed across microbatches, except ``aux["rows"]``:
+    arrays with one entry per row of the microbatch, concatenated in
+    the batch's order; the result is ``(loss, aux, grads)``."""
 
     def call(mb, mb_key):
         out = loss_and_grad_fn(params, mb, mb_key)
@@ -246,7 +248,11 @@ def _accumulate_microbatches(
     )
     grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
     if with_aux:
+        rows = auxs.pop("rows", None)
         aux = jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
+        if rows is not None:
+            aux["rows"] = jax.tree.map(
+                lambda a: a.reshape((-1,) + a.shape[2:]), rows)
         return loss, aux, grads
     return loss, grads
 
@@ -265,7 +271,17 @@ def make_lm_train_step(
     n_micro_override: Optional[int] = None,
     bucket_size_y: Optional[int] = None,
 ):
-    opt_init, opt_update = make_optimizer(arch.optimizer, 3e-4)
+    """The LM train step. The optimizer updates ``tf_lib.trainable``
+    leaves; a biased router's ``router_bias`` is state the step carries
+    beside them: after each step that is not skipped, each MoE layer's
+    bias moves by ``γ·sign(mean load − load_i)`` (DeepSeek-V3 §2.1.2).
+    An MoE model's metrics add ``moe_routed`` (the assignments to held
+    experts, summed over layers), ``moe_max_load`` (each layer's
+    largest held-expert load, summed over layers) and ``moe_experts``
+    (each token's chosen experts in each MoE layer, ``(B·L,
+    n_moe_layers, top_k)``)."""
+    opt_init, opt_update = make_optimizer(arch.optimizer,
+                                          **arch.optimizer_args)
     if grad_compression == "int8":
         from repro.optim import with_error_feedback_compression
 
@@ -295,11 +311,13 @@ def make_lm_train_step(
         tp=tp,
         logit_softcap=cfg.final_softcap,
     )
+    moe = cfg.moe
 
-    def loss_and_grad(params, mb, key):
+    def loss_and_grad(train_p, bias, mb, key):
         def loss_fn(p):
             with jax.named_scope("backbone"):
-                hidden, aux = tf_lib.forward(p, cfg, mb["tokens"])
+                full = p if bias is None else dict(p, router_bias=bias)
+                hidden, aux = tf_lib.forward(full, cfg, mb["tokens"])
                 x = hidden.reshape(-1, hidden.shape[-1])
                 # padded rows act as phantom negatives
                 y = tf_lib.output_embedding(p, cfg)
@@ -315,28 +333,49 @@ def make_lm_train_step(
                 mesh=mesh,
                 logit_softcap=cfg.final_softcap,
             )
-            return loss + aux, sentinels
-        (loss, sentinels), grads = jax.value_and_grad(
+            counts = {"sentinels": sentinels}
+            if "load" in aux:
+                counts["load"] = aux["load"]
+                counts["rows"] = {
+                    "experts": jnp.swapaxes(aux["experts"], 0, 1)}
+            return loss + aux["balance"], counts
+        (loss, counts), grads = jax.value_and_grad(
             loss_fn, has_aux=True
-        )(params)
-        return loss, sentinels, grads
+        )(train_p)
+        return loss, counts, grads
 
     accum_dtype = jnp.dtype(arch.accum_dtype)
 
     def train_step(params, opt_state, batch, key):
         batch, loss_cap = _pop_loss_cap(batch)
-        loss, sentinels, grads = _accumulate_microbatches(
-            loss_and_grad, params, batch, key, n_micro, accum_dtype,
-            with_aux=True,
+        train_p = tf_lib.trainable(params)
+        bias = params.get("router_bias")
+        loss, counts, grads = _accumulate_microbatches(
+            lambda p, mb, k: loss_and_grad(p, bias, mb, k),
+            train_p, batch, key, n_micro, accum_dtype, with_aux=True,
         )
         # (int8 error-feedback compression, if enabled, lives inside the
         # wrapped optimizer — see optim.with_error_feedback_compression)
-        return _apply_update_guarded(
-            opt_update, loss, grads, params, opt_state, loss_cap,
-            sentinels=sentinels,
+        new_p, new_opt, metrics = _apply_update_guarded(
+            opt_update, loss, grads, train_p, opt_state, loss_cap,
+            sentinels=counts["sentinels"],
         )
+        if "load" in counts:
+            load = counts["load"]
+            held = load[:, :moe.held]
+            metrics["moe_routed"] = jnp.sum(held)
+            metrics["moe_max_load"] = jnp.sum(jnp.max(held, axis=-1))
+            metrics["moe_experts"] = counts["rows"]["experts"]
+        if bias is not None:
+            step = moe_lib.BIAS_UPDATE_RATE * jnp.sign(
+                jnp.mean(load, axis=-1, keepdims=True) - load)
+            new_p["router_bias"] = jnp.where(metrics["skipped"], bias,
+                                             bias + step)
+        return new_p, new_opt, metrics
 
-    return train_step, (opt_init, opt_update), sce_cfg
+    return (train_step,
+            (lambda params: opt_init(tf_lib.trainable(params)), opt_update),
+            sce_cfg)
 
 
 def make_lm_prefill_step(cfg, *, act_spec=None):

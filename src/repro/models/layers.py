@@ -28,7 +28,9 @@ Weight-layout conventions (DESIGN.md §2; built ONLY by
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -78,15 +80,53 @@ def layer_norm(x, gamma, beta, eps: float = 1e-6):
 # ---------------------------------------------------------------------------
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
-def rope_frequencies(head_dim: int, theta: float = 10000.0):
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """A ``rope_scaling`` of ``type`` yarn, as a model's config states it."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+
+def rope_frequencies(head_dim: int, theta: float = 10000.0,
+                     yarn: Optional[YarnScaling] = None):
+    """Rotary frequencies ``(head_dim/2,)``. ``yarn`` blends the
+    interpolated ``f/factor`` into the low frequencies by YaRN's linear
+    ramp (DeepSeek-V3's ``yarn_find_correction_range`` and
+    ``yarn_linear_ramp_mask``)."""
     exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta**exponents)  # (head_dim/2,)
+    freqs = 1.0 / (theta**exponents)  # (head_dim/2,)
+    if yarn is None:
+        return freqs
+
+    def correction_dim(rotations):
+        orig = yarn.original_max_position_embeddings
+        return (head_dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1·mscale·ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta: float = 10000.0,
+               yarn: Optional[YarnScaling] = None):
     """x: (..., L, H, dh); positions: broadcastable to (..., L)."""
     dh = x.shape[-1]
-    freqs = rope_frequencies(dh, theta)
+    freqs = rope_frequencies(dh, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., L, dh/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., L, 1, dh/2)
     sin = jnp.sin(angles)[..., None, :]
@@ -115,8 +155,8 @@ def _attn_one_chunk(
     window: Optional[int],
     softcap: Optional[float],
     kv_valid: Optional[jax.Array],  # (B, Lk) bool, e.g. decode cache fill
+    scale: float,
 ):
-    scale = q_c.shape[-1] ** -0.5
     scores = jnp.einsum(
         "bchgd,blhd->bchgl", q_c, k, preferred_element_type=jnp.float32
     ) * scale
@@ -152,12 +192,12 @@ def _attn_one_chunk_flat(
     window: Optional[int],
     softcap: Optional[float],
     kv_valid: Optional[jax.Array],
+    scale: float,
 ):
     """Long-sequence path with ONE head dim. The grouped (Hkv, G) split
     cannot be sharded over a 16-way model axis when Hq = 8·7 etc., which
     makes GSPMD replicate q (an involuntary-remat all-gather of the whole
     activation); a flat 56-head dim shards (with padding) just fine."""
-    scale = q_c.shape[-1] ** -0.5
     scores = jnp.einsum(
         "bchd,blhd->bchl", q_c, k, preferred_element_type=jnp.float32
     ) * scale
@@ -191,8 +231,11 @@ def attention(
     q_offset=0,  # global position of q[0] (decode: cache length so far)
     kv_valid: Optional[jax.Array] = None,
     q_chunk: int = 1024,
+    scale: Optional[float] = None,
 ):
-    """Grouped-query attention, computed in query chunks.
+    """Grouped-query attention, computed in query chunks. ``v`` may be
+    narrower than ``q``/``k`` (latent attention: 128 against 192);
+    ``scale`` defaults to ``1/sqrt(dh)`` of ``q``.
 
     Peak score memory is ``B × q_chunk × Hq × Lk`` instead of
     ``B × Lq × Hq × Lk``. Backward under ``jax.checkpoint`` recomputes
@@ -201,8 +244,11 @@ def attention(
     dry-runs and CPU tests.)
     """
     b, lq, hq, dh = q.shape
+    dv = v.shape[-1]
     hkv = k.shape[2]
     g = hq // hkv
+    if scale is None:
+        scale = dh**-0.5
     kv_pos = jnp.arange(k.shape[1])
     q_pos = q_offset + jnp.arange(lq)
 
@@ -213,8 +259,9 @@ def attention(
         out = _attn_one_chunk(
             qg, k, v, q_pos, kv_pos,
             causal=causal, window=window, softcap=softcap, kv_valid=kv_valid,
+            scale=scale,
         )
-        return out.reshape(b, lq, hq, dh).astype(q.dtype)
+        return out.reshape(b, lq, hq, dv).astype(q.dtype)
 
     assert lq % q_chunk == 0, (lq, q_chunk)
     n_chunks = lq // q_chunk
@@ -233,6 +280,7 @@ def attention(
         functools.partial(
             _attn_one_chunk_flat,
             causal=causal, window=window, softcap=softcap, kv_valid=kv_valid,
+            scale=scale,
         ),
         prevent_cse=False,
     )
@@ -247,8 +295,8 @@ def attention(
 
     _, outs = jax.lax.scan(
         step, None, jnp.arange(n_chunks)
-    )  # (n_chunks, B, c, Hq, dh)
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, lq, hq, dh)
+    )  # (n_chunks, B, c, Hq, dv)
+    out = jnp.moveaxis(outs, 0, 1).reshape(b, lq, hq, dv)
     return out.astype(q.dtype)
 
 

@@ -120,6 +120,24 @@ def test_transformer_specs_mirror_params_and_fsdp(mesh):
     }
 
 
+@pytest.mark.parametrize("arch", ["moonlight-16b-a3b", "kimi-k2-1t-a32b",
+                                  "granite-moe-3b-a800m"])
+def test_transformer_specs_mirror_latent_and_moe_params(mesh, arch):
+    """Latent attention (with and without a q LoRA), leading dense
+    layers, held experts and the router's selection bias."""
+    from repro.configs import get_arch
+    from repro.models import transformer
+
+    cfg = get_arch(arch).make_smoke_config()
+    params = jax.eval_shape(
+        lambda k: transformer.init_params(k, cfg),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+    )
+    for fsdp in (False, True):
+        specs = transformer_param_specs(cfg, mesh, fsdp=fsdp)
+        assert _tree_struct(specs) == jax.tree.structure(params)
+
+
 def test_opt_state_specs_adamw_and_sgd(mesh):
     from repro.optim import adamw, sgd_momentum
 

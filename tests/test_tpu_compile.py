@@ -2,13 +2,16 @@
 
 Each test lowers one kernel at sasrec-sce's real widths (catalog
 C = 173,511, d = 64, train_paper's N = 128·200 positions, SCE
-n_b = b_x = 320, b_y = 256, eval/serve top-10) for a described — not
+n_b = b_x = 320, b_y = 256, eval/serve top-10), or at the LM head of
+moonlight-16b-a3b's cell (a 20,480-row vocabulary slice, d = 2,048,
+N = 8,192 positions, n_b = b_x = 181, b_y = 256), for a described — not
 attached — v5e chip and compiles it with the TPU compiler, which refuses
 what Mosaic cannot lower (block tiling, layouts, VMEM). The block pair
 each test compiles is the one the guard's preflight accepts for that
 call on a TPU backend, so the preflight's legality and VMEM model are
-held to the real compiler. Nothing runs: these say nothing about
-results or speed.
+held to the real compiler. One test compiles moonlight's whole train
+step at the cell's sizes and holds its memory to the chip's. Nothing
+runs: these say nothing about results or speed.
 
 The topology is described inside a module-scoped fixture (never at
 import), and the tests skip when it cannot be described.
@@ -63,9 +66,14 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _blocks(kernel, rows, cols, k=None, block_rows=128, block_cols=512):
+LM_C, LM_D, LM_N = 20_480, 2_048, 8_192
+LM_NB, LM_BX, LM_BY = 181, 181, 256
+
+
+def _blocks(kernel, rows, cols, k=None, block_rows=128, block_cols=512,
+            d=D):
     return preflight(
-        kernel, rows=rows, cols=cols, d=D, block_rows=block_rows,
+        kernel, rows=rows, cols=cols, d=d, block_rows=block_rows,
         block_cols=block_cols, k=k, backend="tpu",
     ).blocks
 
@@ -215,3 +223,107 @@ def test_train_step_scopes(one_chip, topo, monkeypatch):
         "sce_gather_bwd": {"gather_loss/while"},
     }
     assert unscoped(text) == []
+
+
+@pytest.mark.parametrize("k,rows,with_valid", [
+    (LM_BY, LM_C, False),  # the vocabulary slice
+    (LM_BX, LM_N, True),   # the sequence's positions
+])
+def test_mips_topk_compiles_lm_head(one_chip, k, rows, with_valid):
+    bq, bc = _blocks("mips_topk", LM_NB, rows, k=k, d=LM_D)
+    assert (bq, bc) == (128, 512)
+
+    def fn(q, y, *v):
+        return mips_topk.mips_topk(q, y, k, valid=v[0] if v else None,
+                                   block_q=bq, block_c=bc)
+    shapes = [((LM_NB, LM_D), F32), ((rows, LM_D), F32)]
+    if with_valid:
+        shapes.append(((rows,), BOOL))
+    assert "tpu_custom_call" in _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("flavour", ["loss", "plse"])
+def test_sce_gather_compiles_lm_head(one_chip, flavour):
+    """Rows of 2,048 lanes, gathered through the (C, 1, d) view, with
+    the blocks the preflight picks at that width (the Gowalla cell keeps
+    its 128 × 256)."""
+    bx, by = _blocks("sce_gather", LM_BX, LM_BY, block_cols=256, d=LM_D)
+    assert _blocks("sce_gather", B_X, B_Y, block_cols=256) == (128, 256)
+    shapes = [((LM_NB, LM_BX, LM_D), F32), ((LM_C, LM_D), F32),
+              ((LM_NB, LM_BY), I32), ((LM_NB, LM_BX), I32),
+              ((LM_NB, LM_BY), I32)]
+    if flavour == "loss":
+        shapes.append(((LM_NB, LM_BX), F32))
+
+        def loss(x_b, y, idx, tgt, cand, pos):
+            return sce_prefetch.sce_gather_loss(
+                x_b, y, idx, tgt, cand, pos, bx, by).sum()
+        diff = (0, 1, 5)
+    else:
+        def loss(x_b, y, idx, tgt, cand):
+            return sce_prefetch.sce_gather_plse(
+                x_b, y, idx, tgt, cand, bx, by).sum()
+        diff = (0, 1)
+    text = _compile(jax.grad(loss, argnums=diff), one_chip, *shapes)
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_moonlight_train_step_fits_one_chip(one_chip, topo, monkeypatch):
+    """moonlight-16b-a3b's train step at the cell's sizes (its one-chip
+    config, one 8,192-token sequence, SCE exact on a 1×1 mesh), with its
+    Pallas kernels at the blocks the preflight picks on a TPU: it
+    compiles, its arguments (weights and AdamW state) and temporaries
+    fit the chip's 16 GB, and the layers' scopes are in its ops."""
+    import functools
+
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec
+
+    from _scopes import instructions, scope
+    from repro.configs import get_arch
+    from repro.kernels import guard, ops
+    from repro.launch import steps as steps_lib
+    from repro.models import transformer as tf
+
+    monkeypatch.setattr(ops, "_default_backend", lambda: "tpu")
+    monkeypatch.setattr(guard, "kernel_enabled", lambda *a, **k: True)
+    monkeypatch.setattr(guard, "preflight",
+                        functools.partial(guard.preflight, backend="tpu"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=topo.devices[:1])
+    arch = get_arch("moonlight-16b-a3b")
+    shape = arch.shape("train_8k")
+    cfg = arch.make_config(shape.name)
+    step, (opt_init, _), _ = steps_lib.make_lm_train_step(
+        arch, cfg, mesh, shape, sce_mode="exact")
+    rep = NamedSharding(mesh, PartitionSpec())
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), tree)
+
+    params = jax.eval_shape(
+        lambda: tf.init_params(jax.random.PRNGKey(0), cfg))
+    n = shape.dims["seq_len"]
+    batch = {k: jax.ShapeDtypeStruct((1, n), dt, sharding=rep)
+             for k, dt in (("tokens", I32), ("targets", I32),
+                           ("valid", BOOL))}
+    batch["loss_cap"] = jax.ShapeDtypeStruct((), F32, sharding=rep)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+            sds(params), sds(jax.eval_shape(opt_init, params)), batch,
+            key).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    paths = {scope(op) for *_, op in instructions(compiled.as_text()) if op}
+    def under(path, parts):  # ``parts`` in order among path's parts
+        it = iter(path.split("/"))
+        return all(p in it for p in parts)
+
+    for parts in (("mla",), ("dense_ffn",), ("moe", "route"),
+                  ("moe", "experts"), ("moe", "shared")):
+        assert any(under(path, parts) for path in paths), parts
+    kernels = {name.split(".")[0] for name, kind, *_ in
+               instructions(compiled.as_text()) if kind == "custom-call"}
+    assert {"mips_topk", "sce_gather_fwd", "sce_gather_bwd"} <= kernels

@@ -56,7 +56,6 @@ def make_config(shape_name: str = "train_8k") -> TransformerConfig:
         ),
         dtype="float32",
         remat=True,
-        q_chunk=256,
     )
 
 

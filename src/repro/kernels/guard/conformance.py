@@ -768,3 +768,40 @@ def _eval_topk_f32_canary(interpret: bool):
     _assert_ids("f32_two_pass_ids", got[1], want[1])
     _assert_rel("f32_two_pass_vals", got[0], want[0])
     _assert_ids("f32_two_pass_gt", got[2], _f64_counts(x, y, tgt, 0, 300))
+
+
+@_canary("flash_attention", "causal_gqa_fwd_bwd")
+def _flash_attention_canary(interpret: bool):
+    """Latent attention's widths (q/k 192, v 128) over two 128-blocks a
+    side — a skipped, a full and two diagonal tiles — with two query
+    heads on one key/value head; ``o`` and the three gradients against
+    the float32 reference, at the MXU precision the dispatch would use
+    (one bfloat16 pass leaves ~4e-3 relative error; a wrong mask or
+    block index, order one)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops, ref
+
+    r = _rng(67)
+    q, k = _normals(r, (1, 256, 2, 192)), _normals(r, (1, 256, 1, 192))
+    v, w = _normals(r, (1, 256, 1, 128)), _normals(r, (1, 256, 2, 128))
+    mxu = ops._mxu_dtype(interpret)
+    tol = _F32_TOL if mxu == jnp.float32 else 2e-2
+
+    def k_out(*qkv):
+        return _mod("flash_attention").flash_attention(
+            *qkv, scale=192**-0.5, block_q=128, block_kv=128,
+            mxu_dtype=mxu, interpret=interpret)
+
+    def r_out(*qkv):
+        return ref.causal_attention_ref(*qkv, 192**-0.5)
+
+    got = k_out(q, k, v), jax.grad(
+        lambda *a: jnp.sum(k_out(*a) * w), (0, 1, 2))(q, k, v)
+    with _highest():
+        want = r_out(q, k, v), jax.grad(
+            lambda *a: jnp.sum(r_out(*a) * w), (0, 1, 2))(q, k, v)
+    for name, g, x in zip(("o", "dq", "dk", "dv"), (got[0], *got[1]),
+                          (want[0], *want[1])):
+        _assert_rel(f"flash_{name}", g, x, tol)

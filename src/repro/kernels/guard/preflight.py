@@ -156,6 +156,21 @@ def _scratch_elements(kernel: str, block_rows: int, block_cols: int,
         return _merge_elements(block_rows, block_cols, kk) + 5 * block_rows
     if kernel == "eval_topk":
         return _merge_elements(block_rows, block_cols, kk) + 2 * block_rows
+    if kernel == "flash_attention":
+        # Rows are query blocks, columns key blocks, d the q/k width (v's
+        # is no wider). Beyond the q and k tiles and one score tile, the
+        # largest of the three kernels: forward — the v tile, the o and
+        # log-sum-exp blocks (double-buffered; a (rows, 1) column takes
+        # 128 lanes), the (m, l) columns and the accumulator; dq — the v
+        # and dO tiles, the log-sum-exp and D columns, the dq block and
+        # its accumulator; dk/dv — the v and dO tiles, the log-sum-exp
+        # and D rows (8 sublanes), the dk and dv blocks and their
+        # accumulators.
+        br, bc = block_rows, block_cols
+        fwd = 2 * bc * d + 3 * br * d + 4 * br * LANE
+        dq = 2 * bc * d + 5 * br * d + 4 * br * LANE
+        dkv = 2 * (br + bc) * d + 6 * bc * d + 4 * SUBLANE * br
+        return max(fwd, dq, dkv)
     raise KernelPreflightError(kernel, "unknown_kernel",
                                f"no VMEM model registered for {kernel!r}")
 
@@ -164,7 +179,7 @@ def _scratch_elements(kernel: str, block_rows: int, block_cols: int,
 # deprecated two-pass entry points (eval_topk / eval_tgt_scores).
 KNOWN_KERNELS = (
     "sce_bucket", "sce_gather", "mips_topk", "fused_ce", "linear_sce",
-    "eval_fused", "eval_topk",
+    "eval_fused", "eval_topk", "flash_attention",
 )
 
 PREFLIGHT_RULES = (

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.kernels import eval_fused as _eval_fused
 from repro.kernels import eval_topk as _eval_topk
+from repro.kernels import flash_attention as _flash_attention
 from repro.kernels import fused_ce as _fused_ce
 from repro.kernels import guard as _guard
 from repro.kernels import linear_sce as _linear_sce
@@ -96,6 +97,47 @@ def _inside_shard_map(*arrays) -> bool:
         except (AttributeError, TypeError):
             pass
     return False
+
+
+def _mxu_dtype(interpret: bool):
+    """The flash kernels' MXU operand dtype: on a TPU, one bfloat16
+    pass where the process's matmul precision is XLA's default (what the
+    jnp einsums beside the kernel get), float32 at HIGHEST where it asks
+    for more; in interpret mode float32, as XLA's CPU default is."""
+    one_pass = jax.config.jax_default_matmul_precision in (
+        None, "default", "bfloat16", "fastest")
+    return jnp.bfloat16 if one_pass and not interpret else jnp.float32
+
+
+def causal_attention(
+    q,
+    k,
+    v,
+    *,
+    scale: float,
+    interpret: bool | None = None,
+):
+    """Causal self-attention over whole sequences through the flash
+    kernels: ``q (B, L, Hq, dqk)``, ``k (B, L, Hkv, dqk)``, ``v (B, L,
+    Hkv, dv)`` → ``(B, L, Hq, dv)``; ``L`` a multiple of both of the
+    kernel's blocks. See kernels/flash_attention.py. Inside
+    ``shard_map`` in interpret mode the float32 reference runs
+    instead."""
+    if interpret is None:
+        interpret = _interpret_default()
+    if interpret and _inside_shard_map(q, k, v):
+        return _ref.causal_attention_ref(q, k, v, scale)
+    block_q, block_kv = _guard.checked_blocks(
+        "flash_attention", rows=q.shape[1], cols=k.shape[1],
+        d=q.shape[-1], block_rows=_flash_attention.BLOCK_Q,
+        block_cols=_flash_attention.BLOCK_KV, dtype=q.dtype,
+    )
+    if not _guard.kernel_enabled("flash_attention", interpret=interpret):
+        return _ref.causal_attention_ref(q, k, v, scale)
+    return _flash_attention.flash_attention(
+        q, k, v, scale=scale, block_q=block_q, block_kv=block_kv,
+        mxu_dtype=_mxu_dtype(interpret), interpret=interpret,
+    )
 
 
 def sce_bucket_loss(

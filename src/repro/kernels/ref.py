@@ -508,3 +508,18 @@ def fused_ce_loss_ref(x: jax.Array, y: jax.Array, targets: jax.Array) -> jax.Arr
         jnp.take(y, targets, axis=0).astype(jnp.float32),
     )
     return (lse.astype(jnp.float32) - pos).astype(x.dtype)
+
+
+def causal_attention_ref(q, k, v, scale: float) -> jax.Array:
+    """Causal self-attention with the whole ``(B, Hq, L, L)`` float32
+    score tensor: ``q (B, L, Hq, dqk)``, ``k (B, L, Hkv, dqk)``, ``v (B,
+    L, Hkv, dv)`` → ``(B, L, Hq, dv)``; a key/value head serves ``Hq /
+    Hkv`` query heads."""
+    f32 = jnp.float32
+    seq, g = q.shape[1], q.shape[2] // k.shape[2]
+    k = jnp.repeat(k.astype(f32), g, axis=2)
+    v = jnp.repeat(v.astype(f32), g, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(f32), k) * scale
+    s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, NEG_INF)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    return out.astype(q.dtype)

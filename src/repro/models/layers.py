@@ -5,8 +5,10 @@ Conventions:
     pure ``apply`` that takes the params dict first;
   * weights are stored in named dicts so sharding-spec trees can mirror the
     structure 1:1 (see repro/dist/sharding.py);
-  * attention is computed in query chunks with an explicit mask per chunk —
-    the (B, Lq, H, Lk) score tensor is never materialized beyond one chunk,
+  * causal self-attention over whole long sequences runs the flash
+    kernels (``kernels/flash_attention.py``); every other attention is
+    computed in query chunks with an explicit mask per chunk — the
+    (B, Lq, H, Lk) score tensor is never materialized beyond one chunk,
     which is what lets 32k-token prefill compile inside a 16 GB HBM budget.
 
 Weight-layout conventions (DESIGN.md §2; built ONLY by
@@ -35,6 +37,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import flash_attention as flash
+from repro.kernels import ops
 
 NEG_INF = -1e30
 
@@ -233,22 +238,41 @@ def attention(
     q_chunk: int = 1024,
     scale: Optional[float] = None,
 ):
-    """Grouped-query attention, computed in query chunks. ``v`` may be
-    narrower than ``q``/``k`` (latent attention: 128 against 192);
-    ``scale`` defaults to ``1/sqrt(dh)`` of ``q``.
+    """Grouped-query attention. ``v`` may be narrower than ``q``/``k``
+    (latent attention: 128 against 192); ``scale`` defaults to
+    ``1/sqrt(dh)`` of ``q``.
 
-    Peak score memory is ``B × q_chunk × Hq × Lk`` instead of
-    ``B × Lq × Hq × Lk``. Backward under ``jax.checkpoint`` recomputes
-    chunks. (On real TPU the Pallas flash kernel would slot in here; the
-    chunked form is the XLA-lowering-friendly equivalent used for AOT
-    dry-runs and CPU tests.)
+    Causal self-attention over a whole sequence — no ``window``,
+    ``softcap`` or ``kv_valid``, ``q_offset`` 0, ``Lq == Lk``, at least
+    two flash blocks long and a multiple of them — runs the flash
+    kernels (``ops.causal_attention``): no score leaves VMEM and tiles
+    above the diagonal are skipped. Everything else (short sequences,
+    bidirectional, sliding windows, softcaps, prefill and decode against
+    a cache) runs :func:`chunked_attention`.
     """
+    lq = q.shape[1]
+    if scale is None:
+        scale = q.shape[-1]**-0.5
+    block = max(flash.BLOCK_Q, flash.BLOCK_KV)
+    if (causal and window is None and softcap is None and kv_valid is None
+            and isinstance(q_offset, int) and q_offset == 0
+            and lq == k.shape[1] and lq >= 2 * block
+            and lq % flash.BLOCK_Q == 0 and lq % flash.BLOCK_KV == 0):
+        return ops.causal_attention(q, k, v, scale=scale).astype(q.dtype)
+    return chunked_attention(
+        q, k, v, causal=causal, window=window, softcap=softcap,
+        q_offset=q_offset, kv_valid=kv_valid, q_chunk=q_chunk, scale=scale)
+
+
+def chunked_attention(q, k, v, *, causal, window, softcap, q_offset,
+                      kv_valid, q_chunk, scale):
+    """:func:`attention` in jnp, computed in query chunks: peak score
+    memory is ``B × q_chunk × Hq × Lk`` instead of ``B × Lq × Hq × Lk``,
+    and the backward under ``jax.checkpoint`` recomputes chunks."""
     b, lq, hq, dh = q.shape
     dv = v.shape[-1]
     hkv = k.shape[2]
     g = hq // hkv
-    if scale is None:
-        scale = dh**-0.5
     kv_pos = jnp.arange(k.shape[1])
     q_pos = q_offset + jnp.arange(lq)
 

@@ -6,7 +6,8 @@ Features required by the assigned architecture pool:
     queries (optionally through a low-rank ``q_lora``), a shared
     compressed KV latent ``c_kv`` with one decoupled RoPE key ``k_pe``
     for all heads, keys and values reconstructed per head, run through
-    the same q-chunked attention; YaRN rope scaling (``rope_scaling``);
+    the same ``layers.attention`` (the flash kernels at long training
+    lengths); YaRN rope scaling (``rope_scaling``);
   * gemma-2: alternating local(sliding-window)/global attention layers,
     attention + final logit soft-capping, post-block norms, tied embeddings,
     embedding scaling by sqrt(d_model);
@@ -395,7 +396,10 @@ def forward(params, cfg: TransformerConfig, tokens, positions=None):
 
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
-        dense_body = jax.checkpoint(dense_body, prevent_cse=False)
+        # XLA inlines a scan of one layer, where the recomputation would
+        # be merged with the forward and every activation kept
+        dense_body = jax.checkpoint(
+            dense_body, prevent_cse=cfg.n_dense_layers == 1)
     if cfg.n_dense_layers:
         x, _ = jax.lax.scan(dense_body, x, params["dense_layers"])
     bias = params.get("router_bias")

@@ -384,7 +384,7 @@ def test_guard_json_schema(tmp_path):
     }
     assert set(kernel_rows) == {
         "sce_bucket", "sce_gather", "mips_topk", "fused_ce",
-        "linear_sce", "eval_fused", "eval_topk",
+        "linear_sce", "eval_fused", "eval_topk", "flash_attention",
     }
     spec = {
         "label": str,

@@ -229,7 +229,7 @@ def test_preflight_property_repair_or_structured_error(
 def test_all_canaries_pass_on_this_backend():
     verdicts = guard.run_conformance()
     assert set(verdicts) == set(conf.kernels())
-    assert len(verdicts) == 7
+    assert len(verdicts) == 8
     for name, v in verdicts.items():
         assert v.passed, f"{name}: {v.failures}"
         assert v.n_pass >= 1 and v.n_fail == 0

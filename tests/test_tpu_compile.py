@@ -268,52 +268,61 @@ def test_sce_gather_compiles_lm_head(one_chip, flavour):
     assert text.count("tpu_custom_call") >= 2
 
 
-def test_moonlight_train_step_fits_one_chip(one_chip, topo, monkeypatch):
+@pytest.fixture(scope="module")
+def moonlight_step(one_chip, topo):
     """moonlight-16b-a3b's train step at the cell's sizes (its one-chip
     config, one 8,192-token sequence, SCE exact on a 1×1 mesh), with its
-    Pallas kernels at the blocks the preflight picks on a TPU: it
-    compiles, its arguments (weights and AdamW state) and temporaries
-    fit the chip's 16 GB, and the layers' scopes are in its ops."""
+    Pallas kernels at the blocks the preflight picks on a TPU, compiled
+    for one v5e."""
     import functools
 
     from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
-    from _scopes import instructions, scope
     from repro.configs import get_arch
     from repro.kernels import guard, ops
     from repro.launch import steps as steps_lib
     from repro.models import transformer as tf
 
-    monkeypatch.setattr(ops, "_default_backend", lambda: "tpu")
-    monkeypatch.setattr(guard, "kernel_enabled", lambda *a, **k: True)
-    monkeypatch.setattr(guard, "preflight",
-                        functools.partial(guard.preflight, backend="tpu"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2,
-                         devices=topo.devices[:1])
-    arch = get_arch("moonlight-16b-a3b")
-    shape = arch.shape("train_8k")
-    cfg = arch.make_config(shape.name)
-    step, (opt_init, _), _ = steps_lib.make_lm_train_step(
-        arch, cfg, mesh, shape, sce_mode="exact")
-    rep = NamedSharding(mesh, PartitionSpec())
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        monkeypatch.setattr(ops, "_default_backend", lambda: "tpu")
+        monkeypatch.setattr(guard, "kernel_enabled", lambda *a, **k: True)
+        monkeypatch.setattr(guard, "preflight", functools.partial(
+            guard.preflight, backend="tpu"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2,
+                             devices=topo.devices[:1])
+        arch = get_arch("moonlight-16b-a3b")
+        shape = arch.shape("train_8k")
+        cfg = arch.make_config(shape.name)
+        step, (opt_init, _), _ = steps_lib.make_lm_train_step(
+            arch, cfg, mesh, shape, sce_mode="exact")
+        rep = NamedSharding(mesh, PartitionSpec())
 
-    def sds(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=rep), tree)
+        def sds(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=rep), tree)
 
-    params = jax.eval_shape(
-        lambda: tf.init_params(jax.random.PRNGKey(0), cfg))
-    n = shape.dims["seq_len"]
-    batch = {k: jax.ShapeDtypeStruct((1, n), dt, sharding=rep)
-             for k, dt in (("tokens", I32), ("targets", I32),
-                           ("valid", BOOL))}
-    batch["loss_cap"] = jax.ShapeDtypeStruct((), F32, sharding=rep)
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
-    with jax.set_mesh(mesh):
-        compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-            sds(params), sds(jax.eval_shape(opt_init, params)), batch,
-            key).compile()
+        params = jax.eval_shape(
+            lambda: tf.init_params(jax.random.PRNGKey(0), cfg))
+        n = shape.dims["seq_len"]
+        batch = {k: jax.ShapeDtypeStruct((1, n), dt, sharding=rep)
+                 for k, dt in (("tokens", I32), ("targets", I32),
+                               ("valid", BOOL))}
+        batch["loss_cap"] = jax.ShapeDtypeStruct((), F32, sharding=rep)
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+        with jax.set_mesh(mesh):
+            return jax.jit(step, donate_argnums=(0, 1)).lower(
+                sds(params), sds(jax.eval_shape(opt_init, params)), batch,
+                key).compile()
+
+
+def test_moonlight_train_step_fits_one_chip(moonlight_step):
+    """moonlight-16b-a3b's train step at the cell's sizes compiles, its
+    arguments (weights and AdamW state) and temporaries fit the chip's
+    16 GB, and the layers' scopes are in its ops."""
+    from _scopes import instructions, scope
+
+    compiled = moonlight_step
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     paths = {scope(op) for *_, op in instructions(compiled.as_text()) if op}
@@ -327,3 +336,48 @@ def test_moonlight_train_step_fits_one_chip(one_chip, topo, monkeypatch):
     kernels = {name.split(".")[0] for name, kind, *_ in
                instructions(compiled.as_text()) if kind == "custom-call"}
     assert {"mips_topk", "sce_gather_fwd", "sce_gather_bwd"} <= kernels
+
+
+def test_moonlight_step_flash_kernels_under_mla(moonlight_step):
+    """Latent attention in the cell's step runs the three flash kernels,
+    forward and backward, each under the ``mla`` scope that
+    ``mla_ms_per_step`` reads, found as the benchmark finds it: the
+    instructions' ``op_name``s by ``scopes.op_names_from_hlo``."""
+    from _scopes import scope
+    from harness.scopes import op_names_from_hlo
+
+    scopes = {}
+    _, op_names = op_names_from_hlo(moonlight_step.as_text())
+    for name, op in op_names.items():
+        if name.startswith("mla_flash"):
+            scopes.setdefault(name.split(".")[0], set()).add(
+                "mla" in scope(op).split("/"))
+    assert scopes == {"mla_flash_fwd": {True}, "mla_flash_dq": {True},
+                      "mla_flash_dkv": {True}}
+
+
+@pytest.mark.parametrize("hq,hkv,dqk,dv", [
+    (16, 16, 192, 128),  # moonlight's latent attention
+    (32, 4, 128, 128),   # grouped-query attention (yi-6b's heads)
+])
+def test_flash_attention_compiles(one_chip, hq, hkv, dqk, dv):
+    """The flash kernels, forward and backward, over one 8,192-token
+    sequence with the blocks the preflight accepts on a TPU, at one bf16
+    MXU pass."""
+    from repro.kernels import flash_attention as fa
+
+    bq, bk = preflight(
+        "flash_attention", rows=LM_N, cols=LM_N, d=dqk,
+        block_rows=fa.BLOCK_Q, block_cols=fa.BLOCK_KV, backend="tpu",
+    ).blocks
+    assert (bq, bk) == (fa.BLOCK_Q, fa.BLOCK_KV)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, scale=dqk**-0.5, block_q=bq,
+                                  block_kv=bk, mxu_dtype=jnp.bfloat16).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    ((1, LM_N, hq, dqk), F32), ((1, LM_N, hkv, dqk), F32),
+                    ((1, LM_N, hkv, dv), F32))
+    for name in ("mla_flash_fwd", "mla_flash_dq", "mla_flash_dkv"):
+        assert name in text, name
